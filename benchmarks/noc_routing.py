@@ -108,4 +108,6 @@ if __name__ == "__main__":
     ap.add_argument("--pods", type=int, default=2)
     ap.add_argument("--scale", type=int, default=11)
     a = ap.parse_args()
+    from repro.core.compat import use_compile_cache
+    use_compile_cache()
     main(scale=a.scale, n_dev=a.devices, n_pods=a.pods)

@@ -134,7 +134,7 @@ def _bench_round_cell(n: int, s: int, reps: int) -> Dict:
         if mode == "lockstep":
             xb, (slot_b,), _, nd = bucket(
                 vals[:, None], dest, active, [slot_ids], s, cap, impl=impl)
-            upd = reduce_received(slot_b, xb[:, 0], n_local, "min", impl=impl)
+            upd = reduce_received(slot_b, xb[:, 0], n_local, "min")
         else:
             upd, nd = local_route_reduce(
                 vals, slot_ids, dest, active, s, cap, n_local, "min",

@@ -20,44 +20,40 @@ def main() -> None:
     args = ap.parse_args()
     scale = 12 if args.quick else args.scale
 
-    from . import (fig4_topology, fig5_sram, fig6_pus, fig7_freq, fig8_hbm,
-                   fig10_queues, fig11_scaling, moe_dispatch, roofline_table,
-                   route_bench)
+    # every figure runs in a child process and this parent never imports
+    # jax: a process that has touched jax holds the accelerator, and a
+    # child would then fail to get it
+    def child(mod: str, call: str):
+        code = (f"from repro.core.compat import use_compile_cache; "
+                f"use_compile_cache(); from benchmarks.{mod} import main; "
+                f"main({call})")
+        return [sys.executable, "-c", code]
 
-    figs = [
-        ("fig4_topology", lambda: fig4_topology.main(scale)),
-        ("fig5_sram", lambda: fig5_sram.main(scale)),
-        ("fig6_pus", lambda: fig6_pus.main(scale)),
-        ("fig7_freq", lambda: fig7_freq.main(scale)),
-        ("fig8_hbm", lambda: fig8_hbm.main(scale)),
-        ("fig10_queues", lambda: fig10_queues.main(scale)),
-        ("fig11_scaling", lambda: fig11_scaling.main(scale)),
-        ("moe_dispatch", moe_dispatch.main),
+    figs = [(name, child(name, str(scale)))
+            for name in ("fig4_topology", "fig5_sram", "fig6_pus",
+                         "fig7_freq", "fig8_hbm", "fig10_queues",
+                         "fig11_scaling")]
+    figs += [
+        ("moe_dispatch", child("moe_dispatch", "")),
         # wall-clock routing hot path -> BENCH_route.json (the committed
         # baseline is the --quick grid; see repro.dse.route_compare)
-        ("route_bench", lambda: route_bench.main(
-            ["--quick"] if args.quick else [])),
-        # subprocess: needs its own 8-fake-device jax, must not retopologize
-        # the sibling benchmarks in this process
-        ("noc_routing", lambda: subprocess.run(
-            [sys.executable, "-m", "benchmarks.noc_routing",
-             "--scale", str(min(scale, 11))], check=True)),
-        # subprocess for the same reason: the resident serving bench
-        # wants its own fake-device topology
-        ("serve_bench", lambda: subprocess.run(
-            [sys.executable, "-m", "benchmarks.serve_bench"]
-            + (["--smoke", "--devices", "4"] if args.quick else []),
-            check=True)),
-        ("roofline_table", roofline_table.main),
+        ("route_bench", child("route_bench",
+                              "['--quick']" if args.quick else "[]")),
+        # run as __main__: these two pick their own fake-device topology
+        # before jax is imported
+        ("noc_routing", [sys.executable, "-m", "benchmarks.noc_routing",
+                         "--scale", str(min(scale, 11))]),
+        ("serve_bench", [sys.executable, "-m", "benchmarks.serve_bench"]
+         + (["--smoke", "--devices", "4"] if args.quick else [])),
+        ("roofline_table", child("roofline_table", "")),
     ]
     failures = []
-    for name, fn in figs:
+    for name, cmd in figs:
         t = time.time()
         print(f"== {name} ==", flush=True)
-        try:
-            fn()
-        except Exception as e:  # keep the suite running, but gate at exit
-            print(f"{name},ERROR,{type(e).__name__}: {e}", file=sys.stderr)
+        rc = subprocess.run(cmd).returncode
+        if rc:                  # keep the suite running, but gate at exit
+            print(f"{name},ERROR,exit code {rc}", file=sys.stderr)
             failures.append(name)
         print(f"# {name} took {time.time() - t:.1f}s", flush=True)
     if failures:

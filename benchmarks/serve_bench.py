@@ -343,4 +343,6 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.core.compat import use_compile_cache
+    use_compile_cache()
     main()

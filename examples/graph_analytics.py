@@ -4,10 +4,10 @@ TEPS/W, TEPS/$) and the design-space comparison the paper advocates
 (SRAM-only vs HBM packaging).
 
 ``--distributed`` additionally runs every app on the REAL distributed
-shard_map path (8 fake host devices) as a TaskProgram through the shared
-owner-routed NoC layer in ``repro.core.routing``, validating each against
-its numpy oracle and printing per-app rounds / routed messages / IQ
-drops.
+shard_map path — over every device JAX finds (8 fake host devices under
+``JAX_PLATFORMS=cpu``) — as a TaskProgram through the shared owner-routed
+NoC layer in ``repro.core.routing``, validating each against its numpy
+oracle and printing per-app rounds / routed messages / IQ drops.
 
   PYTHONPATH=src python examples/graph_analytics.py [--scale 12]
       [--distributed]
@@ -17,6 +17,7 @@ import os
 import sys
 
 if (any(a.startswith("--dist") for a in sys.argv)  # argparse abbreviations
+        and os.environ.get("JAX_PLATFORMS") == "cpu"
         and "host_platform_device_count" not in os.environ.get("XLA_FLAGS",
                                                                "")):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
@@ -37,16 +38,19 @@ from benchmarks.common import config_cost, evaluate, APPS  # noqa: E402
 def run_distributed(g, scale):
     """All seven apps on the shard_map path; oracle-checked, stats
     printed."""
-    from repro.core.compat import make_mesh
+    import jax
+    from repro.core.fabric import Fabric
     from repro.sparse.jax_apps import (dcra_bfs, dcra_histogram,
                                        dcra_kcore, dcra_pagerank,
                                        dcra_spmv, dcra_sssp, dcra_wcc)
-    mesh = make_mesh((8,), ("data",))
+    n_dev = len(jax.devices())
+    mesh = Fabric.single((n_dev,), ("data",))
     x = np.random.default_rng(0).random(g.n)
     els = datasets.histogram_data(1 << 14, 256)
     hdr = f"{'app':10s} {'rounds':>7s} {'messages':>10s} {'drops':>7s} " \
           f"{'max_err':>10s}"
-    print("distributed path (8 devices, owner-routed rounds)")
+    print(f"distributed path ({n_dev} {jax.devices()[0].platform} devices, "
+          f"owner-routed rounds)")
     print(hdr)
     print("-" * len(hdr))
 
@@ -94,6 +98,8 @@ def main():
     ap.add_argument("--distributed", action="store_true",
                     help="also run the six apps on the shard_map path")
     args = ap.parse_args()
+    from repro.core.compat import use_compile_cache
+    use_compile_cache()
 
     g = datasets.rmat(args.scale, edge_factor=16)
     print(f"RMAT-{args.scale}: V={g.n} E={g.nnz} "

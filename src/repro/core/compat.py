@@ -1,38 +1,34 @@
-"""jax version compatibility — the repo targets the pinned jax 0.4.37
-toolchain (see requirements-dev.txt) while staying source-compatible with
-the >= 0.7 API surface it was originally sketched against.
+"""Thin wrappers over the jax 0.9 API surface (see requirements-dev.txt)
+that every launch path shares:
 
-Three seams moved between those versions:
-
-* ``shard_map``: ``jax.experimental.shard_map`` -> ``jax.shard_map``
-  (and the ``check_rep`` kwarg was renamed ``check_vma``);
-* mesh construction: ``jax.make_mesh(..., axis_types=...)`` did not exist /
-  lacks ``axis_types`` on 0.4.x — we build ``jax.sharding.Mesh`` directly,
-  which also allows meshes over a *subset* of devices (the routing property
+* ``shard_map_unchecked`` — ``jax.shard_map`` with the VMA checker off;
+* ``make_mesh`` — a ``jax.sharding.Mesh`` over the *first* devices, so a
+  mesh may cover a subset of the process's devices (the routing property
   tier runs 1/2/4/8-device meshes inside one 8-device process);
-* mesh scoping: ``jax.set_mesh`` -> ``Mesh`` as a context manager.
+* ``set_mesh`` — ``jax.set_mesh``;
+* ``cost_analysis`` — ``compiled.cost_analysis()`` as a dict;
+* ``use_compile_cache`` — where entry points keep JAX's persistent
+  compilation cache.
 """
 from __future__ import annotations
 
-import inspect
+import os
+from pathlib import Path
 
 import jax
 import numpy as np
 
-try:                                     # jax >= 0.7
-    shard_map = jax.shard_map
-except AttributeError:                   # pragma: no cover - version dep
-    from jax.experimental.shard_map import shard_map
-
-_SM_PARAMS = inspect.signature(shard_map).parameters
-_CHECK_KW = "check_vma" if "check_vma" in _SM_PARAMS else "check_rep"
+#: the persistent compile cache's home when ``JAX_COMPILATION_CACHE_DIR``
+#: is unset: a fixed path inside the checkout (the path is part of the
+#: cache key, so it must not move between runs)
+REPO_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def shard_map_unchecked(fn, mesh, in_specs, out_specs):
-    """shard_map with replication/VMA checking off (collective-heavy
-    kernels trip the static checker on both API generations)."""
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **{_CHECK_KW: False})
+    """shard_map with VMA checking off (collective-heavy kernels trip the
+    static checker)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(axis_shapes, axis_names, devices=None):
@@ -48,14 +44,23 @@ def make_mesh(axis_shapes, axis_names, devices=None):
 
 def set_mesh(mesh):
     """Context manager scoping ``mesh`` as the ambient mesh."""
-    if hasattr(jax, "set_mesh"):                  # jax >= 0.7
-        return jax.set_mesh(mesh)
-    return mesh                                   # Mesh is a context manager
+    return jax.set_mesh(mesh)
 
 
 def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a dict (0.4.x returns [dict])."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, list):
-        cost = cost[0] if cost else {}
-    return cost
+    """``compiled.cost_analysis()`` (empty dict when XLA reports none)."""
+    return compiled.cost_analysis() or {}
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins: JAX reads it itself and
+    nothing else is configured here. Otherwise the cache goes to
+    :data:`REPO_CACHE_DIR`. Call before the first compile.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(REPO_CACHE_DIR))
+    return str(REPO_CACHE_DIR)

@@ -138,10 +138,8 @@ class Fabric:
         CPU; a no-op elsewhere).
         """
         import jax
-        try:   # must precede backend init; harmless if unavailable
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except (AttributeError, ValueError):  # pragma: no cover - version
-            pass
+        # must precede backend init; a no-op off the CPU backend
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         if coordinator_address is not None:
             try:
                 jax.distributed.initialize(

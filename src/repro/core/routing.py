@@ -7,8 +7,8 @@ vertex-owning tiles (:mod:`repro.sparse.jax_apps`) — is the same motion:
   1. *bucket*: tasks are grouped by destination shard into capacity-bounded
      buckets (the paper's input queue; overflow is dropped and counted);
   2. *deliver*: ONE ``all_to_all`` per NoC round carries a *fused payload* —
-     int32 metadata columns are bitcast (bytes reinterpreted, never
-     converted) to f32 and packed next to the value columns, so index+value
+     value columns are bitcast (bytes reinterpreted, never converted) to
+     int32 and packed next to the int32 metadata columns, so index+value
      travel in a single collective instead of two;
   3. optionally *hierarchical*: when shards span pods, stage 1 routes over
      the intra-pod axis to the destination's "portal" (the device in the
@@ -34,14 +34,12 @@ import jax.numpy as jnp
 # Capacity helpers live with the queue-sizing source of truth; re-exported
 # here because every routing call site thinks in lane-aligned bucket sizes.
 from .queues import round8  # noqa: F401
-# The routing hot path has a kernel tier: `impl="pallas"` ranks/scatters
+# The routing hot path has a kernel tier: `impl="pallas"` ranks
 # through repro.kernels.route (Mosaic on TPU, the same tiled algorithm in
 # plain XLA off-TPU); "sort" is the argsort fallback below; "onehot" is
 # the legacy O(N*S) rank. Re-exported so call sites resolve the knob once.
-from ..kernels.route import (_on_tpu, bucket_rank,  # noqa: F401
-                             bucket_scatter_pallas, bucket_sort_gather,
-                             fused_kernels_enabled, onehot_rank,
-                             reduce_received_pallas, resolve_route_impl)
+from ..kernels.route import (bucket_rank, bucket_sort_gather,  # noqa: F401
+                             onehot_rank, resolve_route_impl)
 
 
 # ---------------------------------------------------------------------------
@@ -164,15 +162,6 @@ def bucket(x_tasks, dest, valid, aux_ints, n_buckets, cap, impl=None):
     matter which impl a launch resolves.
     """
     impl = resolve_route_impl(impl)
-    if impl == "pallas" and _on_tpu() and fused_kernels_enabled():
-        # fused Mosaic kernel: rank + capacity test + scatter in one pass
-        # (opt-in until TPU-validated — see fused_kernels_enabled)
-        x2 = x_tasks[:, None] if x_tasks.ndim == 1 else x_tasks
-        xb, ints, task_slot, n_drop = bucket_scatter_pallas(
-            x2, dest, valid, aux_ints, n_buckets, cap, interpret=False)
-        if x_tasks.ndim == 1:
-            xb = xb[:, 0]
-        return xb, ints, task_slot, n_drop
     if impl == "sort":
         # the argsort already groups each bucket contiguously: build xb by
         # gathering the first `cap` of each run instead of paying a second
@@ -209,13 +198,16 @@ def noc_all_to_all(x, axis):
 
 def pack_wire(vals: Optional[jax.Array], int_cols: Sequence[jax.Array]
               ) -> Tuple[jax.Array, tuple]:
-    """Pack value + int32 metadata columns into one f32 wire array.
+    """Pack value + int32 metadata columns into one int32 wire array.
 
-    Ints are *bitcast* to f32 — bytes reinterpreted, never converted.
-    Half-width payloads (bf16/f16) are packed two per f32 wire lane
-    (bitcast, not upcast), so fusing never inflates the wire bytes: the
-    packed array has exactly ``ceil(D/2) + len(int_cols)`` columns for a
-    half payload, ``D + len(int_cols)`` otherwise. Returns
+    Values are *bitcast* to int32 — bytes reinterpreted, never converted.
+    The wire is integer because the TPU compiler may lower a float
+    concatenate as pad + ``maximum``, which rewrites every NaN bit pattern
+    to the canonical NaN: an int such as the -1 "empty" sentinel, bitcast
+    to f32, is such a pattern. Half-width payloads (bf16/f16) are packed
+    two per wire lane (bitcast, not upcast), so fusing never inflates the
+    wire bytes: the packed array has exactly ``ceil(D/2) + len(int_cols)``
+    columns for a half payload, ``D + len(int_cols)`` otherwise. Returns
     ``(packed, meta)``; feed ``meta`` to :func:`unpack_wire` for the
     exact round-trip (tested in tests/test_routing.py).
     """
@@ -236,15 +228,11 @@ def pack_wire(vals: Optional[jax.Array], int_cols: Sequence[jax.Array]
         if half:
             if d_vals % 2:
                 v2 = jnp.concatenate([v2, jnp.zeros_like(v2[:, :1])], axis=1)
-            wire = jax.lax.bitcast_convert_type(
-                v2.reshape(v2.shape[0], -1, 2), jnp.float32)
+            v2 = v2.reshape(v2.shape[0], -1, 2)
         else:
-            wire = v2.astype(jnp.float32)
-        cols.append(wire)
-    for c in int_cols:
-        packed_i = jax.lax.bitcast_convert_type(c.astype(jnp.int32),
-                                                jnp.float32)
-        cols.append(packed_i[:, None])
+            v2 = v2.astype(jnp.float32)
+        cols.append(jax.lax.bitcast_convert_type(v2, jnp.int32))
+    cols += [c.astype(jnp.int32)[:, None] for c in int_cols]
     packed = cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=1)
     return packed, (dtype, d_vals, half, squeeze, len(int_cols))
 
@@ -253,19 +241,16 @@ def unpack_wire(recv: jax.Array, meta: tuple
                 ) -> Tuple[Optional[jax.Array], List[jax.Array]]:
     """Exact inverse of :func:`pack_wire` (bitcast round-trip)."""
     dtype, d_vals, half, squeeze, n_int = meta
-    ints_out = []
-    if n_int:
-        tail = recv[:, recv.shape[1] - n_int:]
-        ints_out = [jax.lax.bitcast_convert_type(tail[:, i], jnp.int32)
-                    for i in range(n_int)]
+    n_v = recv.shape[1] - n_int
+    ints_out = [recv[:, n_v + i] for i in range(n_int)]
     if dtype is None:
         return None, ints_out
-    v_wire = recv[:, :recv.shape[1] - n_int]
     if half:
-        v_out = jax.lax.bitcast_convert_type(v_wire, dtype)
+        v_out = jax.lax.bitcast_convert_type(recv[:, :n_v], dtype)
         v_out = v_out.reshape(v_out.shape[0], -1)[:, :d_vals]
     else:
-        v_out = v_wire.astype(dtype)
+        v_out = jax.lax.bitcast_convert_type(recv[:, :n_v],
+                                             jnp.float32).astype(dtype)
     if squeeze:
         v_out = v_out[:, 0]
     return v_out, ints_out
@@ -277,8 +262,9 @@ def fused_all_to_all(vals: Optional[jax.Array], int_cols: Sequence[jax.Array],
 
     ``vals`` [N, D] (or [N], or None) float payload; ``int_cols`` are [N]
     int32 arrays (slot ids, expert ids, ...). The columns are packed into
-    a single f32 wire array (:func:`pack_wire` — ints bitcast, half-width
-    payloads two per lane, never inflating the collective bytes), so each
+    a single int32 wire array (:func:`pack_wire` — values bitcast,
+    half-width payloads two per lane, never inflating the collective
+    bytes), so each
     NoC round issues a single collective; the round-trip is exact.
     """
     packed, meta = pack_wire(vals, int_cols)
@@ -350,8 +336,8 @@ def _a2a_with_signal(packed, n_blocks, signal, axis):
     """Tiled all_to_all of a packed wire array [n_blocks*rows, C] with one
     broadcast signal row appended per destination block.
 
-    Every peer receives the sender's int32 ``signal`` (bitcast into
-    column 0 of the extra row); the task rows' bytes are untouched — the
+    Every peer receives the sender's int32 ``signal`` (in column 0 of
+    the extra row); the task rows' bytes are untouched — the
     exchanged blocks are simply [rows+1, C] instead of [rows, C], so the
     stripped receive buffer is value-identical to the plain collective.
     Returns ``(recv [n_blocks*rows, C], gsignal)`` where ``gsignal`` is
@@ -360,14 +346,12 @@ def _a2a_with_signal(packed, n_blocks, signal, axis):
     """
     total, c = packed.shape
     rows = total // n_blocks
-    sig = jax.lax.bitcast_convert_type(
-        jnp.asarray(signal, jnp.int32), jnp.float32)
-    sig_row = jnp.zeros((n_blocks, 1, c), packed.dtype).at[:, 0, 0].set(sig)
+    sig_row = jnp.zeros((n_blocks, 1, c), packed.dtype).at[:, 0, 0].set(
+        jnp.asarray(signal, packed.dtype))
     wire = jnp.concatenate([packed.reshape(n_blocks, rows, c), sig_row],
                            axis=1).reshape(n_blocks * (rows + 1), c)
     recv = noc_all_to_all(wire, axis).reshape(n_blocks, rows + 1, c)
-    gsignal = jnp.sum(jax.lax.bitcast_convert_type(recv[:, rows, 0],
-                                                   jnp.int32))
+    gsignal = jnp.sum(recv[:, rows, 0])
     return recv[:, :rows].reshape(n_blocks * rows, c), gsignal
 
 
@@ -456,7 +440,7 @@ def local_route_reduce(vals, slot_ids, dest, valid, n_buckets, cap, n_local,
     return y, n_drop
 
 
-def reduce_received(recv_slot, recv_val, n_local, op, impl=None):
+def reduce_received(recv_slot, recv_val, n_local, op):
     """Apply received tasks at the owner: segment add/min/store into local
     slots.
 
@@ -465,15 +449,8 @@ def reduce_received(recv_slot, recv_val, n_local, op, impl=None):
     independent of bucket/slot arrival order, and by construction the same
     winner the analytic ``TaskEngine._reduce(op='store')`` picks for the
     same task stream (differential-tested in tests/test_core_engine.py).
-    Slots that received no task read as 0. ``impl="pallas"`` on TPU runs
-    the fused receive-reduce kernel (opt-in until TPU-validated — see
-    :func:`repro.kernels.route.fused_kernels_enabled`); elsewhere the
-    segment ops below are already the fastest XLA rendering.
+    Slots that received no task read as 0.
     """
-    if (resolve_route_impl(impl) == "pallas" and _on_tpu()
-            and fused_kernels_enabled()):
-        return reduce_received_pallas(recv_slot, recv_val, n_local, op,
-                                      interpret=False)
     valid = recv_slot >= 0
     seg = jnp.where(valid, recv_slot, n_local)
     if op == "add":

@@ -1,12 +1,15 @@
 """Histogram Pallas TPU kernel — the paper's Histogram app, TPU-native.
 
 Hardware adaptation (DESIGN.md §2): DCRA scatters (bin, +1) messages to the
-bin's owner tile. A TPU has no scatter unit — the MXU-native rendering is
-one-hot compare + matmul-reduce: each element block is compared against the
-bin-id lane vector (VPU), and the resulting one-hot matrix is summed down
-the element axis. Bins are tiled over the grid's second axis so arbitrarily
-many bins stream through VMEM; elements tile over the first axis and
-accumulate into the output block (revisited across steps).
+bin's owner tile. A TPU has no scatter unit — the vector-unit rendering is
+a compare + accumulate: elements stream lane-dense as [rows, 128] tiles,
+each row of 128 elements is compared against a [bins, 128] block of bin
+ids, and the hits accumulate into per-lane partial counts that the wrapper
+sums across lanes. Bins are tiled over the grid's first axis so
+arbitrarily many bins stream through VMEM; element tiles run over the
+second (innermost) axis and accumulate into the output block. The
+accumulation axis must be innermost: a TPU output block is written back
+when its index changes and is not read back on a later visit.
 """
 from __future__ import annotations
 
@@ -16,23 +19,28 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-ELEM_TILE = 1024
-BIN_TILE = 256
+LANES = 128      # elements per tile row
+ROW_TILE = 64    # rows per grid step
+BIN_TILE = 256   # bins per grid step
 
 
 def _hist_kernel(elems_ref, out_ref, *, bin_tile):
-    i = pl.program_id(0)       # element tile
-    j = pl.program_id(1)       # bin tile
-
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    elems = elems_ref[...]                                  # [ET]
-    base = j * bin_tile
-    bins = base + jax.lax.broadcasted_iota(jnp.int32, (1, bin_tile), 1)
-    onehot = (elems[:, None] == bins).astype(jnp.float32)   # [ET, BT]
-    out_ref[...] += jnp.sum(onehot, axis=0).astype(out_ref.dtype)
+    bins = (pl.program_id(0) * bin_tile
+            + jax.lax.broadcasted_iota(jnp.int32, (bin_tile, LANES), 0))
+
+    def slab(k, acc):
+        rows = elems_ref[pl.ds(pl.multiple_of(k * 8, 8), 8), :]   # [8, 128]
+        for r in range(8):
+            acc += (bins == rows[r:r + 1, :]).astype(jnp.int32)
+        return acc
+
+    out_ref[...] += jax.lax.fori_loop(
+        0, elems_ref.shape[0] // 8, slab,
+        jnp.zeros((bin_tile, LANES), jnp.int32))
 
 
 def histogram_pallas(elements: jax.Array, n_bins: int,
@@ -47,19 +55,19 @@ def histogram_pallas(elements: jax.Array, n_bins: int,
     n = elements.shape[0]
     if n == 0:                       # zero-size grid is a pallas error
         return jnp.zeros((n_bins,), jnp.int32)
-    et = min(ELEM_TILE, max(1, n))
-    bt = min(BIN_TILE, n_bins)
-    n_pad = -(-n // et) * et
+    rows = -(-n // LANES)
+    tr = min(ROW_TILE, -(-rows // 8) * 8)
+    rows_p = -(-rows // tr) * tr
+    bt = min(BIN_TILE, -(-n_bins // 8) * 8)
     nb_pad = -(-n_bins // bt) * bt
-    elems = jnp.pad(elements.astype(jnp.int32), (0, n_pad - n),
-                    constant_values=-1)
-    grid = (n_pad // et, nb_pad // bt)
+    elems = jnp.pad(elements.astype(jnp.int32), (0, rows_p * LANES - n),
+                    constant_values=-1).reshape(rows_p, LANES)
     out = pl.pallas_call(
         functools.partial(_hist_kernel, bin_tile=bt),
-        grid=grid,
-        in_specs=[pl.BlockSpec((et,), lambda i, j: (i,))],
-        out_specs=pl.BlockSpec((bt,), lambda i, j: (j,)),
-        out_shape=jax.ShapeDtypeStruct((nb_pad,), jnp.int32),
+        grid=(nb_pad // bt, rows_p // tr),
+        in_specs=[pl.BlockSpec((tr, LANES), lambda j, i: (i, 0))],
+        out_specs=pl.BlockSpec((bt, LANES), lambda j, i: (j, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb_pad, LANES), jnp.int32),
         interpret=interpret,
     )(elems)
-    return out[:n_bins]
+    return jnp.sum(out, axis=1)[:n_bins]

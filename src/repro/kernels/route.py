@@ -9,20 +9,26 @@ HBM per stage, per round. This module provides the kernel tier of that
 loop (the paper's IQ admission is *the* throughput limiter, §III/§VI):
 
 * :func:`bucket_rank` — per-destination running counts live in VMEM and
-  elements stream through in tiles: O(N + S*tiles) traffic instead of
-  O(N*S). On TPU this is the Mosaic kernel
-  (:func:`bucket_rank_pallas`); off-TPU it lowers to the *same tiled
+  elements stream through in lane-dense [rows, 128] tiles: O(N + S*tiles)
+  traffic instead of O(N*S). On TPU this is the Mosaic kernel
+  (:func:`bucket_rank_pallas`, within-tile counts as MXU matmuls with
+  triangular masks; its compile for v5e is guarded by
+  tests/test_tpu_compile.py); off-TPU it lowers to the *same tiled
   algorithm* rendered in plain XLA (:func:`bucket_rank_xla` — within-tile
   ranks via an L*L compare, running counts via one scatter-add), never
   the Pallas interpreter, so the deployed fast path is interpreter-free
   on every backend. Tiny bucket counts keep the one-hot rank (it wins
   below :data:`ONEHOT_MAX_BUCKETS` — see the README routing section).
-* :func:`bucket_scatter_pallas` — the fused admission kernel: one pass
+* :func:`bucket_scatter_pallas` — a fused admission kernel: one pass
   over the task stream producing ``(xb, ints, task_slot, n_drop)``
-  (rank, capacity test, and slot scatter fused; the XLA paths need a
-  rank pass plus a ``segment_sum`` scatter).
-* :func:`reduce_received_pallas` — fused receive-side add/min/store into
-  local slots.
+  (rank, capacity test, and slot scatter fused).
+* :func:`reduce_received_pallas` — a fused receive-side add/min/store
+  into local slots.
+
+The two fused kernels store one scalar per element, which Mosaic refuses
+("Cannot store scalars to VMEM"): they run in interpret mode only, and no
+launch path calls them — every backend takes the rank + ``segment_sum``
+scatter of :func:`repro.core.routing.bucket`.
 
 Drop semantics are bit-identical to the one-hot path (first ``cap`` per
 channel, array order), differential-tested in tests/test_route_kernels.py
@@ -40,14 +46,15 @@ TPU, native XLA elsewhere; ``interpret=True`` is for tests only).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-ELEM_TILE = 256          # pallas kernels: elements streamed per grid step
+ELEM_TILE = 256          # fused kernels: elements streamed per grid step
+LANES = 128              # rank kernel: elements per tile row
+ROW_TILE = 256           # rank kernel: rows per grid step
 SCAN_TILE = 32           # XLA tile-scan: within-tile rank compare width
 ONEHOT_MAX_BUCKETS = 32  # below this S the one-hot rank wins off-TPU
 
@@ -56,18 +63,6 @@ ROUTE_IMPLS = ("pallas", "sort", "onehot")
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
-
-
-def fused_kernels_enabled() -> bool:
-    """Opt-in gate for the *per-element* Mosaic kernels
-    (:func:`bucket_scatter_pallas`, :func:`reduce_received_pallas`) on
-    real TPU. Their dynamic single-row stores inside ``fori_loop`` are
-    interpret-tested only (this container has no TPU), and Mosaic
-    restricts dynamic scalar-indexed stores — so until a TPU run
-    validates them (ROADMAP follow-up), the deployed TPU path keeps the
-    vectorized rank kernel + segment-op scatter and these engage only
-    under ``DCRA_ROUTE_FUSED=1``."""
-    return os.environ.get("DCRA_ROUTE_FUSED") == "1"
 
 
 def onehot_rank(dest, valid, n_buckets):
@@ -95,26 +90,48 @@ def resolve_route_impl(impl=None) -> str:
 # bucket-rank: stable cumcount of each element within its destination
 # ---------------------------------------------------------------------------
 
-def _rank_kernel(dest_ref, valid_ref, pos_ref, counts_ref, *, n_buckets):
-    """One element tile: pos = running count + within-tile exclusive
-    cumcount; per-destination running counts persist in VMEM scratch."""
-    i = pl.program_id(0)
+def _count_dot(a, b):
+    """0/1 bf16 matmul with exact f32 counts. The precision is pinned: a
+    caller's ``default_matmul_precision("highest")`` would otherwise ask
+    Mosaic for an f32 contraction of bf16 operands, which it refuses."""
+    return jnp.dot(a, b, precision=jax.lax.Precision.DEFAULT,
+                   preferred_element_type=jnp.float32)
 
-    @pl.when(i == 0)
+
+def _rank_kernel(key_ref, pos_ref, counts_ref, *, n_buckets):
+    """One [TR, 128] element tile (row-major = array order): pos = running
+    count + within-tile exclusive count, one bucket at a time.
+
+    The within-tile count is two MXU matmuls on the 0/1 bucket mask — a
+    strictly-upper [128, 128] mask counts earlier lanes of the same row,
+    a strictly-lower [TR, TR] mask earlier rows — exact in f32 up to the
+    tile size. Running counts are int32 (they exceed f32's exact range
+    on large streams) and live in VMEM, one lane-broadcast row per
+    bucket."""
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         counts_ref[...] = jnp.zeros_like(counts_ref)
 
-    dest = dest_ref[...]                                     # [ET]
-    valid = valid_ref[...] != 0
-    bins = jax.lax.broadcasted_iota(jnp.int32, (1, n_buckets), 1)
-    onehot = ((dest[:, None] == bins) &
-              valid[:, None]).astype(jnp.int32)              # [ET, S]
-    excl = jnp.cumsum(onehot, axis=0) - onehot               # within-tile
-    run = counts_ref[0, :][None, :]                          # [1, S]
-    # select this element's column without a dynamic gather: the one-hot
-    # row has a single 1 at the destination
-    pos_ref[...] = jnp.sum((excl + run) * onehot, axis=1)
-    counts_ref[0, :] += jnp.sum(onehot, axis=0)
+    key = key_ref[...]                                       # [TR, 128]
+    tr = key.shape[0]
+    lane_a = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    lane_b = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+    earlier_lane = (lane_a < lane_b).astype(jnp.bfloat16)
+    row_a = jax.lax.broadcasted_iota(jnp.int32, (tr, tr), 0)
+    row_b = jax.lax.broadcasted_iota(jnp.int32, (tr, tr), 1)
+    earlier_row = (row_b < row_a).astype(jnp.bfloat16)
+
+    def one_bucket(s, pos):
+        hit = key == s
+        m = hit.astype(jnp.bfloat16)
+        in_row = _count_dot(m, earlier_lane)
+        above = jnp.sum(_count_dot(earlier_row, m), axis=1, keepdims=True)
+        run = counts_ref[pl.ds(s, 1), :]                     # [1, 128]
+        counts_ref[pl.ds(s, 1), :] = run + jnp.sum(hit.astype(jnp.int32))
+        return jnp.where(hit, (in_row + above).astype(jnp.int32) + run, pos)
+
+    pos_ref[...] = jax.lax.fori_loop(0, n_buckets, one_bucket,
+                                     jnp.zeros(key.shape, jnp.int32))
 
 
 def bucket_rank_pallas(dest: jax.Array, valid: jax.Array, n_buckets: int,
@@ -122,28 +139,29 @@ def bucket_rank_pallas(dest: jax.Array, valid: jax.Array, n_buckets: int,
     """Stable position of each *valid* element within its destination
     bucket (invalid positions are 0 — callers mask with ``valid``).
 
-    dest [N] int32 in [0, n_buckets); valid [N] bool. Tail-padded to the
-    element tile, so any N works.
+    dest [N] int32 in [0, n_buckets); valid [N] bool. Invalid elements
+    are folded into the key as -1 (no bucket), and the stream is laid out
+    lane-dense as [rows, 128], tail-padded to the row tile, so any N
+    works.
     """
     n = dest.shape[0]
     if n == 0:                       # zero-size grid is a pallas error
         return jnp.zeros((0,), jnp.int32)
-    et = min(ELEM_TILE, max(8, n))
-    n_pad = -(-n // et) * et
-    pad = n_pad - n
-    dest_p = jnp.pad(dest.astype(jnp.int32), (0, pad))
-    valid_p = jnp.pad(valid.astype(jnp.int32), (0, pad))
+    rows = -(-n // LANES)
+    tr = min(ROW_TILE, -(-rows // 8) * 8)
+    rows_p = -(-rows // tr) * tr
+    key = jnp.where(valid, dest.astype(jnp.int32), -1)
+    key = jnp.pad(key, (0, rows_p * LANES - n), constant_values=-1)
     pos = pl.pallas_call(
         functools.partial(_rank_kernel, n_buckets=n_buckets),
-        grid=(n_pad // et,),
-        in_specs=[pl.BlockSpec((et,), lambda i: (i,)),
-                  pl.BlockSpec((et,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((et,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((1, n_buckets), jnp.int32)],
+        grid=(rows_p // tr,),
+        in_specs=[pl.BlockSpec((tr, LANES), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((tr, LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows_p, LANES), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((n_buckets, LANES), jnp.int32)],
         interpret=interpret,
-    )(dest_p, valid_p)
-    return pos[:n]
+    )(key.reshape(rows_p, LANES))
+    return pos.reshape(-1)[:n]
 
 
 def bucket_rank_xla(dest: jax.Array, valid: jax.Array, n_buckets: int,

@@ -125,7 +125,7 @@ def _histogram_stream(data, params, n_dev, seed):
 
 
 def _histogram_local_reduce(data, dest, vals, n_items):
-    """Single-shard kernel-tier reduce: the MXU histogram kernel counts
+    """Single-shard kernel-tier reduce: the histogram kernel counts
     the task stream directly (dest IS the bin id; -1 padding matches no
     bin), replacing the owner-routed ``reduce_received`` round.
 

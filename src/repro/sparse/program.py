@@ -485,7 +485,7 @@ def _build_scatter_fn(mesh, axis, pod_axis, pods,  # noqa: PLR0917
             recv_slot, recv_val, n_drop = owner_route(
                 vals_b, dest_c // n_dev, dest_c % n_dev, valid,
                 n_dev, cap, axis, impl=impl)
-            y = reduce_received(recv_slot, recv_val, n_local, op, impl=impl)
+            y = reduce_received(recv_slot, recv_val, n_local, op)
             return y, jax.lax.psum(n_drop, axis)
     else:
         n_intra, n_pods = pods
@@ -498,7 +498,7 @@ def _build_scatter_fn(mesh, axis, pod_axis, pods,  # noqa: PLR0917
             recv_slot, recv_val, n_drop = owner_route_hier(
                 vals_b, dest_c // n_dev, dest_c % n_dev, valid,
                 n_intra, axis, n_pods, pod_axis, cap1, cap2, impl=impl)
-            y = reduce_received(recv_slot, recv_val, n_local, op, impl=impl)
+            y = reduce_received(recv_slot, recv_val, n_local, op)
             return y, jax.lax.psum(n_drop, (pod_axis, axis))
 
     return jax.jit(shard_map_unchecked(kernel, mesh=mesh,
@@ -834,7 +834,7 @@ def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
                         vals, slot, owner, active, pods[0], axis, pods[1],
                         pod_axis, caps[0], caps[1], impl=impl)
                 upd = reduce_received(recv_slot, recv_val, n_local,
-                                      prog.reduce_op, impl=impl)
+                                      prog.reduce_op)
             state2, frontier2 = prog.update(ctx, state, frontier, upd)
             return state2, frontier2, m, gsum(nd.astype(jnp.int32))
 
@@ -867,7 +867,7 @@ def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
             communication edge."""
             recv_slot, recv_val = owner_route_finish(recv, meta_box[0])
             return reduce_received(recv_slot, recv_val, n_local,
-                                   prog.reduce_op, impl=impl)
+                                   prog.reduce_op)
 
         zeros = jnp.zeros((rounds,), jnp.int32)
         frontier0 = prog.frontier0(ctx, state_b)

@@ -151,7 +151,7 @@ def test_resolve_route_impl():
 
 
 def test_histogram_kernel_matches_reduce_received():
-    """The single-shard local-reduce glue: the MXU histogram kernel must
+    """The single-shard local-reduce glue: the histogram kernel must
     equal the routed receive-reduce over the same task stream."""
     from repro.kernels import ops
     rng = np.random.default_rng(3)

@@ -195,7 +195,7 @@ def test_some_case_actually_dropped(cases):
        dtype=st.sampled_from(["bfloat16", "float16"]))
 def test_half_width_packing_round_trips_exactly(seed, d, n_int, dtype):
     """bf16/f16 payloads with any D (odd included) round-trip bitwise and
-    ride two-per-f32-lane: the wire never inflates beyond
+    ride two per 32-bit lane: the wire never inflates beyond
     ceil(D/2) + n_int columns."""
     rng = np.random.default_rng(seed)
     n = 16
@@ -204,7 +204,7 @@ def test_half_width_packing_round_trips_exactly(seed, d, n_int, dtype):
     ints = [jnp.asarray(rng.integers(-2**31, 2**31 - 1, n), jnp.int32)
             for _ in range(n_int)]
     packed, meta = pack_wire(vals, ints)
-    assert packed.dtype == jnp.float32
+    assert packed.dtype == jnp.int32
     assert packed.shape == (n, -(-d // 2) + n_int)      # never inflates
     v_out, ints_out = unpack_wire(packed, meta)
     assert v_out.dtype == vals.dtype
