@@ -23,7 +23,7 @@ one fused ``all_to_all`` per NoC stage under ``shard_map``.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -105,6 +105,56 @@ class MeshInfo:
         return (self.expert_axis,), False, True
 
 
+class SlotPlan(NamedTuple):
+    """The slots one shard's dispatch allocates (see :func:`slot_plan`)."""
+    tasks: int              # routed tasks: tokens x top-k
+    dispatch_slots: int     # stage-1 bucket: group size x cap1
+    expert_slots: int       # rows the expert FFN runs over: E_local x cap_e
+    cap1: int               # stage-1 (tile-NoC) capacity per destination
+    cap2: Optional[int]     # stage-2 (pod portal) capacity; None on one pod
+    cap_e: int              # rows per local expert
+
+    @property
+    def slot_fill(self) -> float:
+        """Routed tasks over allocated expert slots: 1 when no slot is
+        padding; capacity factor ``f`` compounds to about ``1 / f**2``
+        on one shard, where dispatch and expert buckets both pad."""
+        return self.tasks / self.expert_slots
+
+
+def slot_plan(mc, info: MeshInfo, tokens: int,
+              queues: Optional[QueueConfig] = None) -> SlotPlan:
+    """Bucket sizes of :func:`moe_dcra` for ``tokens`` tokens on one shard
+    of ``info``'s mesh — the numbers ``moe_dcra`` sizes its buckets with.
+
+    ``mc`` is the model's MoE config; ``queues`` defaults to
+    :func:`dispatch_queues`. The stage-1 bucket holds ``cap1`` tasks per
+    rank of the dispatch group; what a shard receives (``cap1`` per
+    group rank, or ``cap2`` per pod when experts span pods) is bucketed
+    again by local expert into ``cap_e`` rows each. Host-side and static:
+    callers count slot fill without tracing the layer.
+    """
+    if queues is None:
+        queues = dispatch_queues(mc)
+    E = mc.num_experts
+    group, spans_pods, _ = info.dispatch_plan(E)
+    n_ex = info.axis_size(group)
+    n_pod = info.axis_size(info.pod_axis) if spans_pods else 1
+    e_local = E // (n_ex * n_pod)
+    tasks = tokens * mc.top_k
+    cap1 = queues.channel_cap("dispatch", tasks, n_ex)
+    cap2 = None
+    received = n_ex * cap1
+    if spans_pods:
+        cap2 = queues.channel_cap("portal", received, n_pod)
+        received = n_pod * cap2
+    cap_e = (received if e_local == 1
+             else queues.channel_cap("expert", received, e_local))
+    return SlotPlan(tasks=tasks, dispatch_slots=n_ex * cap1,
+                    expert_slots=e_local * cap_e, cap1=cap1, cap2=cap2,
+                    cap_e=cap_e)
+
+
 def _expert_ffn(xe, wg, wu, wd, tp_axis, n_tp):
     """xe [E_l, C, D]; wg/wu [E_l, D, F_l]; wd [E_l, F_l, D] -> [E_l, C, D]."""
     dt = xe.dtype
@@ -123,7 +173,14 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
 
     ``queues`` overrides the dispatch queue sizing; the default derives it
     from ``cfg.moe.capacity_factor`` via :func:`dispatch_queues` (a
-    ``DesignPoint.moe_queues()`` plugs in here for DSE sweeps).
+    ``DesignPoint.moe_queues()`` plugs in here for DSE sweeps). Bucket
+    sizes come from :func:`slot_plan`.
+
+    The layer's device work runs under five scopes: ``dcra.moe.router``
+    (logits, top-k, gates), ``dcra.moe.dispatch`` (stage buckets, token
+    gather, collectives), ``dcra.moe.expert_pad`` (rows into and out of
+    the per-expert buckets), ``dcra.moe.expert_ffn`` and
+    ``dcra.moe.combine`` (return path, gate-weighted sum, aux loss).
     """
     mc = cfg.moe
     assert mc is not None
@@ -197,95 +254,102 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
             wd = jax.lax.all_gather(wd, info.data_axis, axis=2, tiled=True)
 
         # --- routing (task spawning) -----------------------------------
-        logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
-                            router.astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)
-        gates, eids = jax.lax.top_k(probs, mc.top_k)        # [T_l, K]
-        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-        K = mc.top_k
-        eids_f = eids.reshape(-1)
-        gates_f = gates.reshape(-1).astype(jnp.float32)
-        src_f = jnp.repeat(jnp.arange(T_l, dtype=jnp.int32), K)
+        with jax.named_scope("dcra.moe.router"):
+            logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
+                                router.astype(jnp.float32))
+            probs = jax.nn.softmax(logits, axis=-1)
+            gates, eids = jax.lax.top_k(probs, mc.top_k)    # [T_l, K]
+            gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+            K = mc.top_k
+            eids_f = eids.reshape(-1)
+            gates_f = gates.reshape(-1).astype(jnp.float32)
+            src_f = jnp.repeat(jnp.arange(T_l, dtype=jnp.int32), K)
+        plan = slot_plan(mc, info, T_l, queues)
 
-        owner = eids_f // E_local                           # global shard id
-        cap1 = queues.channel_cap("dispatch", T_l * K, n_ex)
-        all_valid = jnp.ones_like(eids_f, dtype=bool)
-
-        if not spans_pods:
-            # ---- single-stage fused a2a (tile-NoC) ---------------------
-            _, (eid1, tok1), slot_of_task, _ = _bucket(
-                src_f[:, None] * 0, owner, all_valid,
-                [eids_f % E_local, src_f], n_ex, cap1, impl=impl)
-            xb1 = gather_rows(xf, tok1)
-            xr, (eidr,) = fused_all_to_all(xb1, [eid1], group)
-        else:
-            # ---- stage 1 over expert axis (tile-NoC) -------------------
-            e_coord = owner % n_ex
-            p_coord = owner // n_ex
-            _, (pc1, eid1, tok1), slot_of_task, _ = _bucket(
-                src_f[:, None] * 0, e_coord, all_valid,
-                [p_coord, eids_f % E_local, src_f], n_ex, cap1, impl=impl)
-            xb1 = gather_rows(xf, tok1)
-            xs1, (pcs, eids1) = fused_all_to_all(xb1, [pc1, eid1], group)
-            n1 = xs1.shape[0]
-            # ---- stage 2 over pod axis (die-NoC portal) ----------------
-            valid1 = pcs >= 0
-            cap2 = queues.channel_cap("portal", n1, n_pod)
-            _, (eid2, slot1_of_s2), _, _ = _bucket(
-                pcs[:, None] * 0, jnp.maximum(pcs, 0), valid1,
-                [eids1, jnp.arange(n1, dtype=jnp.int32)], n_pod, cap2,
-                impl=impl)
-            xb2 = gather_rows(xs1, slot1_of_s2)
-            xr, (eidr,) = fused_all_to_all(xb2, [eid2], info.pod_axis)
+        with jax.named_scope("dcra.moe.dispatch"):
+            owner = eids_f // E_local                       # global shard id
+            all_valid = jnp.ones_like(eids_f, dtype=bool)
+            if not spans_pods:
+                # ---- single-stage fused a2a (tile-NoC) -----------------
+                _, (eid1, tok1), slot_of_task, _ = _bucket(
+                    src_f[:, None] * 0, owner, all_valid,
+                    [eids_f % E_local, src_f], n_ex, plan.cap1, impl=impl)
+                xb1 = gather_rows(xf, tok1)
+                xr, (eidr,) = fused_all_to_all(xb1, [eid1], group)
+            else:
+                # ---- stage 1 over expert axis (tile-NoC) ---------------
+                e_coord = owner % n_ex
+                p_coord = owner // n_ex
+                _, (pc1, eid1, tok1), slot_of_task, _ = _bucket(
+                    src_f[:, None] * 0, e_coord, all_valid,
+                    [p_coord, eids_f % E_local, src_f], n_ex, plan.cap1,
+                    impl=impl)
+                xb1 = gather_rows(xf, tok1)
+                xs1, (pcs, eids1) = fused_all_to_all(xb1, [pc1, eid1], group)
+                n1 = xs1.shape[0]
+                # ---- stage 2 over pod axis (die-NoC portal) ------------
+                valid1 = pcs >= 0
+                _, (eid2, slot1_of_s2), _, _ = _bucket(
+                    pcs[:, None] * 0, jnp.maximum(pcs, 0), valid1,
+                    [eids1, jnp.arange(n1, dtype=jnp.int32)], n_pod,
+                    plan.cap2, impl=impl)
+                xb2 = gather_rows(xs1, slot1_of_s2)
+                xr, (eidr,) = fused_all_to_all(xb2, [eid2], info.pod_axis)
 
         # --- local expert execution (owner computes) --------------------
         N_r = xr.shape[0]
         validr = eidr >= 0
         if E_local == 1:
-            ye = _expert_ffn(xr[None].astype(xb.dtype), wg, wu, wd,
-                             info.tp_axis, n_tp)[0]
-            ye = ye * validr[:, None].astype(ye.dtype)
+            with jax.named_scope("dcra.moe.expert_ffn"):
+                ye = _expert_ffn(xr[None].astype(xb.dtype), wg, wu, wd,
+                                 info.tp_axis, n_tp)[0]
+                ye = ye * validr[:, None].astype(ye.dtype)
         else:
-            # second-level IQ: bucket received tasks by local expert
-            cap_e = queues.channel_cap("expert", N_r, E_local)
-            _, (srce,), _, _ = _bucket(
-                validr[:, None].astype(jnp.int32) * 0, jnp.maximum(eidr, 0),
-                validr, [jnp.arange(N_r, dtype=jnp.int32)], E_local, cap_e,
-                impl=impl)
-            xe = gather_rows(xr, srce)
-            ye_b = _expert_ffn(xe.reshape(E_local, cap_e, D).astype(xb.dtype),
-                               wg, wu, wd, info.tp_axis, n_tp)
-            ye = _slot_scatter(ye_b.reshape(E_local * cap_e, D),
-                               jnp.maximum(srce, 0), srce >= 0, N_r)
+            cap_e = plan.cap_e
+            with jax.named_scope("dcra.moe.expert_pad"):
+                # second-level IQ: bucket received tasks by local expert
+                _, (srce,), _, _ = _bucket(
+                    validr[:, None].astype(jnp.int32) * 0,
+                    jnp.maximum(eidr, 0), validr,
+                    [jnp.arange(N_r, dtype=jnp.int32)], E_local, cap_e,
+                    impl=impl)
+                xe = gather_rows(xr, srce).reshape(E_local, cap_e, D)
+            with jax.named_scope("dcra.moe.expert_ffn"):
+                ye_b = _expert_ffn(xe.astype(xb.dtype), wg, wu, wd,
+                                   info.tp_axis, n_tp)
+            with jax.named_scope("dcra.moe.expert_pad"):
+                ye = _slot_scatter(ye_b.reshape(E_local * cap_e, D),
+                                   jnp.maximum(srce, 0), srce >= 0, N_r)
 
-        # --- return path (retrace the NoC route) ------------------------
-        if not spans_pods:
-            yb1 = _a2a(ye, group)
-        else:
-            y2 = _a2a(ye, info.pod_axis)                    # back to portal
-            y1 = _slot_scatter(y2, jnp.maximum(slot1_of_s2, 0),
-                               slot1_of_s2 >= 0, n1)
-            yb1 = _a2a(y1, group)                # back to source
+        with jax.named_scope("dcra.moe.combine"):
+            # --- return path (retrace the NoC route) --------------------
+            if not spans_pods:
+                yb1 = _a2a(ye, group)
+            else:
+                y2 = _a2a(ye, info.pod_axis)                # back to portal
+                y1 = _slot_scatter(y2, jnp.maximum(slot1_of_s2, 0),
+                                   slot1_of_s2 >= 0, n1)
+                yb1 = _a2a(y1, group)                # back to source
 
-        # combine at the source: task slot -> token, weighted by gate
-        task_y = jnp.where(
-            (slot_of_task >= 0)[:, None],
-            yb1[jnp.maximum(slot_of_task, 0)], 0.0).astype(jnp.float32)
-        out = jax.ops.segment_sum(task_y * gates_f[:, None], src_f,
-                                  num_segments=T_l)
+            # combine at the source: task slot -> token, weighted by gate
+            task_y = jnp.where(
+                (slot_of_task >= 0)[:, None],
+                yb1[jnp.maximum(slot_of_task, 0)], 0.0).astype(jnp.float32)
+            out = jax.ops.segment_sum(task_y * gates_f[:, None], src_f,
+                                      num_segments=T_l)
 
-        # aux: load-balance loss, averaged over all devices
-        frac = jax.nn.one_hot(eids, E, dtype=jnp.float32).sum(1).mean(0)
-        aux = E * jnp.sum(frac * probs.mean(0))
-        aux = jax.lax.pmean(aux, info.all_axes())
-        if do_slice:   # restore the expert-replicated layout
-            out = jax.lax.all_gather(out, info.expert_axis, axis=0,
-                                     tiled=True)
-        out = out.reshape(b_l, s_l, D).astype(x.dtype)
-        if tp_gather:   # slice back this rank's seq shard
-            tp_i = jax.lax.axis_index(info.tp_axis)
-            out = jax.lax.dynamic_slice_in_dim(out, tp_i * s_shard, s_shard,
-                                               axis=1)
+            # aux: load-balance loss, averaged over all devices
+            frac = jax.nn.one_hot(eids, E, dtype=jnp.float32).sum(1).mean(0)
+            aux = E * jnp.sum(frac * probs.mean(0))
+            aux = jax.lax.pmean(aux, info.all_axes())
+            if do_slice:   # restore the expert-replicated layout
+                out = jax.lax.all_gather(out, info.expert_axis, axis=0,
+                                         tiled=True)
+            out = out.reshape(b_l, s_l, D).astype(x.dtype)
+            if tp_gather:   # slice back this rank's seq shard
+                tp_i = jax.lax.axis_index(info.tp_axis)
+                out = jax.lax.dynamic_slice_in_dim(out, tp_i * s_shard,
+                                                   s_shard, axis=1)
         return out, aux
 
     fn = shard_map_unchecked(kernel, mesh=info.mesh,

@@ -20,6 +20,12 @@ All functions here are **per-shard**: they are meant to be called *inside*
 a ``shard_map`` kernel (possibly inside a ``lax.while_loop`` for iterative
 apps), so callers control layout, reduction, and the return path.
 
+The three steps run under device scopes (``jax.named_scope``), nested in
+the caller's: ``dcra.route.rank`` (position within the destination
+bucket), ``dcra.route.scatter`` (tasks into slot order) and
+``dcra.route.a2a`` (the collective). A profile then attributes each
+device op to its step by the op's name stack.
+
 Shard-id convention for the hierarchical path: global shard
 ``g = pod * n_intra + intra`` — pods are the slow axis, matching a mesh
 declared as ``('pod', ..., intra_axis)``.
@@ -119,11 +125,12 @@ def positions_by_dest(dest, valid, n_buckets, impl=None):
     the legacy O(N*S) one-hot cumsum.
     """
     impl = resolve_route_impl(impl)
-    if impl == "pallas":
-        return bucket_rank(dest, valid, n_buckets)
-    if impl == "sort":
-        return _positions_by_dest_sort(dest, valid, n_buckets)
-    return onehot_rank(dest, valid, n_buckets)
+    with jax.named_scope("dcra.route.rank"):
+        if impl == "pallas":
+            return bucket_rank(dest, valid, n_buckets)
+        if impl == "sort":
+            return _positions_by_dest_sort(dest, valid, n_buckets)
+        return onehot_rank(dest, valid, n_buckets)
 
 
 def _positions_by_dest_sort(dest, valid, n_buckets):
@@ -142,12 +149,14 @@ def _positions_by_dest_sort(dest, valid, n_buckets):
 
 def slot_scatter(data, slot, valid, num_slots):
     """Scatter rows of ``data`` into slots (each slot receives <= 1 row)."""
-    seg = jnp.where(valid, slot, num_slots)
-    if data.ndim > 1:
-        data = data * valid[:, None].astype(data.dtype)
-    else:
-        data = data * valid.astype(data.dtype)
-    return jax.ops.segment_sum(data, seg, num_segments=num_slots + 1)[:num_slots]
+    with jax.named_scope("dcra.route.scatter"):
+        seg = jnp.where(valid, slot, num_slots)
+        if data.ndim > 1:
+            data = data * valid[:, None].astype(data.dtype)
+        else:
+            data = data * valid.astype(data.dtype)
+        return jax.ops.segment_sum(data, seg,
+                                   num_segments=num_slots + 1)[:num_slots]
 
 
 def bucket(x_tasks, dest, valid, aux_ints, n_buckets, cap, impl=None):
@@ -193,7 +202,8 @@ def gather_rows(table, ids):
 
 def noc_all_to_all(x, axis):
     """One NoC round over ``axis`` (tiled all_to_all on the leading dim)."""
-    return jax.lax.all_to_all(x, axis, 0, 0, tiled=True)
+    with jax.named_scope("dcra.route.a2a"):
+        return jax.lax.all_to_all(x, axis, 0, 0, tiled=True)
 
 
 def pack_wire(vals: Optional[jax.Array], int_cols: Sequence[jax.Array]

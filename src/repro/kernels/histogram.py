@@ -69,5 +69,6 @@ def histogram_pallas(elements: jax.Array, n_bins: int,
         out_specs=pl.BlockSpec((bt, LANES), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((nb_pad, LANES), jnp.int32),
         interpret=interpret,
+        name="histogram",
     )(elems)
     return jnp.sum(out, axis=1)[:n_bins]

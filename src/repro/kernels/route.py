@@ -160,6 +160,7 @@ def bucket_rank_pallas(dest: jax.Array, valid: jax.Array, n_buckets: int,
         out_shape=jax.ShapeDtypeStruct((rows_p, LANES), jnp.int32),
         scratch_shapes=[pltpu.VMEM((n_buckets, LANES), jnp.int32)],
         interpret=interpret,
+        name="bucket_rank",
     )(key.reshape(rows_p, LANES))
     return pos.reshape(-1)[:n]
 
@@ -240,25 +241,28 @@ def bucket_sort_gather(x_tasks, dest, valid, aux_ints, n_buckets, cap):
         return (xb[:, 0] if squeeze else xb,
                 [jnp.full((total,), -1, jnp.int32) for _ in aux_ints],
                 jnp.zeros((0,), jnp.int32), jnp.int32(0))
-    # stable argsort by destination; invalid tasks sort to a sentinel
-    key = jnp.where(valid, dest.astype(jnp.int32), n_buckets)
-    order = jnp.argsort(key, stable=True)
-    ks = key[order]
-    run_start = jnp.searchsorted(ks, ks, side="left")
-    pos_sorted = jnp.arange(n, dtype=jnp.int32) - run_start.astype(jnp.int32)
-    pos = jnp.zeros(n, jnp.int32).at[order].set(pos_sorted)
-    # bucket run offsets -> slot (b, p) gathers sorted index start[b] + p
-    bins = jnp.arange(n_buckets, dtype=jnp.int32)
-    b_start = jnp.searchsorted(ks, bins, side="left")
-    b_end = jnp.searchsorted(ks, bins, side="right")
-    slot_b = jnp.repeat(bins, cap)                           # [total]
-    slot_p = jnp.tile(jnp.arange(cap, dtype=jnp.int32), n_buckets)
-    src_sorted = b_start[slot_b] + slot_p
-    filled = src_sorted < b_end[slot_b]
-    src = order[jnp.minimum(src_sorted, n - 1)]
-    xb = jnp.where(filled[:, None], x2[src], 0).astype(x2.dtype)
-    ints = [jnp.where(filled, a.astype(jnp.int32)[src], -1)
-            for a in aux_ints]
+    with jax.named_scope("dcra.route.rank"):
+        # stable argsort by destination; invalid tasks sort to a sentinel
+        key = jnp.where(valid, dest.astype(jnp.int32), n_buckets)
+        order = jnp.argsort(key, stable=True)
+        ks = key[order]
+        run_start = jnp.searchsorted(ks, ks, side="left")
+        pos_sorted = (jnp.arange(n, dtype=jnp.int32)
+                      - run_start.astype(jnp.int32))
+        pos = jnp.zeros(n, jnp.int32).at[order].set(pos_sorted)
+    with jax.named_scope("dcra.route.scatter"):
+        # bucket run offsets -> slot (b, p) gathers sorted index start[b] + p
+        bins = jnp.arange(n_buckets, dtype=jnp.int32)
+        b_start = jnp.searchsorted(ks, bins, side="left")
+        b_end = jnp.searchsorted(ks, bins, side="right")
+        slot_b = jnp.repeat(bins, cap)                       # [total]
+        slot_p = jnp.tile(jnp.arange(cap, dtype=jnp.int32), n_buckets)
+        src_sorted = b_start[slot_b] + slot_p
+        filled = src_sorted < b_end[slot_b]
+        src = order[jnp.minimum(src_sorted, n - 1)]
+        xb = jnp.where(filled[:, None], x2[src], 0).astype(x2.dtype)
+        ints = [jnp.where(filled, a.astype(jnp.int32)[src], -1)
+                for a in aux_ints]
     keep = valid & (pos < cap)
     task_slot = jnp.where(keep, dest * cap + jnp.minimum(pos, cap - 1), -1)
     n_drop = jnp.sum(valid & ~keep)

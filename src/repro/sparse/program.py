@@ -33,6 +33,7 @@ message/drop agreement.
 """
 from __future__ import annotations
 
+import itertools
 from dataclasses import dataclass
 from typing import Callable, Dict, Mapping, Optional, Tuple
 
@@ -40,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from ..core.compat import shard_map_unchecked
@@ -529,12 +531,19 @@ class ProgramLaunch:
     * :meth:`result` — block + host transfer + owner-layout unpack:
       exactly the ``(state_arrays, AppStats)`` the synchronous
       :func:`run_program` returns, bit-identical.
+
+    ``launch`` is the launch's ordinal in this process. Every host span of
+    the launch (``dcra.graph.pack`` / ``.upload`` / ``.dispatch`` in
+    :func:`launch_program`, ``dcra.graph.wait`` / ``.transfer`` here)
+    carries it as ``launch=<n>``, so a profile pairs the spans of one
+    launch when the serving tier interleaves several.
     """
 
     def __init__(self, fab: Fabric, outs, n: int,  # noqa: PLR0917
-                 n_dev: int, n_states: int):
+                 n_dev: int, n_states: int, launch: int):
         self._fab, self._outs = fab, outs
         self._n, self._n_dev, self._n_states = n, n_dev, n_states
+        self.launch = launch
         self._result = None
 
     def is_ready(self) -> bool:
@@ -549,7 +558,8 @@ class ProgramLaunch:
 
     def block(self) -> "ProgramLaunch":
         """Wait for the device computation (no host transfer yet)."""
-        jax.block_until_ready(self._outs)
+        with TraceAnnotation("dcra.graph.wait", launch=self.launch):
+            jax.block_until_ready(self._outs)
         return self
 
     def result(self):
@@ -557,14 +567,17 @@ class ProgramLaunch:
         Idempotent: the materialized result is cached on first call."""
         if self._result is None:
             outs = self._outs
-            states = outs[:self._n_states]
-            r, msgs, drops = outs[self._n_states:]
-            stats = _collect_stats(r, msgs, drops)
-            states_np = tuple(
-                np.asarray(from_owner_layout(_host_gather(self._fab, s),
-                                             self._n, self._n_dev),
-                           np.float64)
-                for s in states)
+            with TraceAnnotation("dcra.graph.wait", launch=self.launch):
+                jax.block_until_ready(outs)
+            with TraceAnnotation("dcra.graph.transfer", launch=self.launch):
+                states = outs[:self._n_states]
+                r, msgs, drops = outs[self._n_states:]
+                stats = _collect_stats(r, msgs, drops)
+                states_np = tuple(
+                    np.asarray(from_owner_layout(_host_gather(self._fab, s),
+                                                 self._n, self._n_dev),
+                               np.float64)
+                    for s in states)
             self._result = (states_np, stats)
             self._outs = None                 # release device buffers
         return self._result
@@ -698,6 +711,10 @@ def run_program(prog: TaskProgram, data, fabric, *,
                          donate_states=donate_states).result()
 
 
+#: ordinals of this process's graph launches (see :class:`ProgramLaunch`)
+_LAUNCHES = itertools.count()
+
+
 def _launch_graph(prog: TaskProgram, g, fab: Fabric,  # noqa: PLR0917
                   opts: LaunchOptions, params, max_rounds,
                   dataset=None, donate_states: bool = False
@@ -705,31 +722,39 @@ def _launch_graph(prog: TaskProgram, g, fab: Fabric,  # noqa: PLR0917
     """The graph-program launch path shared by :func:`run_program` and
     :func:`launch_program`: resolve, pack, hit the compile cache, and
     dispatch — returning the :class:`ProgramLaunch` device future
-    *without* waiting on the result."""
+    *without* waiting on the result.
+
+    The host work is three spans: ``dcra.graph.pack`` (launch resolution,
+    edge and state packing), ``dcra.graph.upload`` (host arrays onto the
+    fabric) and ``dcra.graph.dispatch`` (compile-cache lookup and the
+    jitted call, which returns once the computation is enqueued)."""
     axis, pod_axis, queues = opts.axis, opts.pod_axis, opts.queues
     cap, capacity_factor = opts.cap, opts.capacity_factor
     seed, route_impl = opts.seed, opts.route_impl
     round_mode = opts.round_mode
-    lc = resolve_launch(opts.config, g if dataset is None else dataset,
-                        prog.name, opts.objective)
+    launch = next(_LAUNCHES)
     n_dev = fab.n_devices
     n = g.n
-    n_local, src_slot, dst, w, E_max = _graph_setup(
-        g, n_dev, undirected=prog.undirected, seed=seed)
-    if lc is not None:
-        pod_axis = (pod_axis if pod_axis is not None
-                    else lc.pod_axis_for(fab))
-        queues = lc.device_queues(n_dev, E_max, pod=pod_axis is not None)
-    if queues is None:
-        queues = _resolve_queues(prog, None, cap, capacity_factor)
-    caps, pods = resolve_caps(fab, queues, prog.task, E_max, axis,
-                              pod_axis, clamp=True)
-    impl = resolve_route_impl(route_impl if route_impl is not None
-                              else queues.route_impl)
-
-    states0, fills = prog.init(g, params)
-    packed = tuple(np.asarray(_owner_pack_np(s, n_dev, f)[0], np.float32)
-                   for s, f in zip(states0, fills))
+    with TraceAnnotation("dcra.graph.pack", launch=launch):
+        lc = resolve_launch(opts.config, g if dataset is None else dataset,
+                            prog.name, opts.objective)
+        n_local, src_slot, dst, w, E_max = _graph_setup(
+            g, n_dev, undirected=prog.undirected, seed=seed)
+        if lc is not None:
+            pod_axis = (pod_axis if pod_axis is not None
+                        else lc.pod_axis_for(fab))
+            queues = lc.device_queues(n_dev, E_max,
+                                      pod=pod_axis is not None)
+        if queues is None:
+            queues = _resolve_queues(prog, None, cap, capacity_factor)
+        caps, pods = resolve_caps(fab, queues, prog.task, E_max, axis,
+                                  pod_axis, clamp=True)
+        impl = resolve_route_impl(route_impl if route_impl is not None
+                                  else queues.route_impl)
+        states0, fills = prog.init(g, params)
+        packed = tuple(np.asarray(_owner_pack_np(s, n_dev, f)[0],
+                                  np.float32)
+                       for s, f in zip(states0, fills))
     if prog.mode == "fixed":
         rounds = int(params["iters"])
     else:
@@ -750,14 +775,17 @@ def _launch_graph(prog: TaskProgram, g, fab: Fabric,  # noqa: PLR0917
         # joins the key — but ONLY when set, keeping default launches'
         # cache keys byte-identical to every prior release
         key = key + ("donate",)
-    fn = _cached(key, lambda: _build_graph_fn(
-        prog, fab.mesh, axis, pod_axis, pods, n_dev, n_local, n, caps,
-        kparams, rounds, len(packed), impl, round_mode=round_mode,
-        donate_states=donate_states))
     spec = P((pod_axis, axis)) if pod_axis else P(axis)
-    out = fn(*(_to_global(fab, spec, a)
-               for a in (src_slot, dst, w) + packed))
-    return ProgramLaunch(fab, tuple(out), n, n_dev, len(packed))
+    with TraceAnnotation("dcra.graph.upload", launch=launch):
+        args = [_to_global(fab, spec, a)
+                for a in (src_slot, dst, w) + packed]
+    with TraceAnnotation("dcra.graph.dispatch", launch=launch):
+        fn = _cached(key, lambda: _build_graph_fn(
+            prog, fab.mesh, axis, pod_axis, pods, n_dev, n_local, n, caps,
+            kparams, rounds, len(packed), impl, round_mode=round_mode,
+            donate_states=donate_states))
+        out = fn(*args)
+    return ProgramLaunch(fab, tuple(out), n, n_dev, len(packed), launch)
 
 
 def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
@@ -793,6 +821,13 @@ def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
       instead folded into admission (:func:`local_route_reduce`) — no
       wire buffer at all; ``add``-reduce keeps the generic shape (its
       summation order must match lockstep's bucket order).
+
+    In every shape a round's device work runs under four scopes, so a
+    device profile splits the round by phase: ``dcra.graph.payload``
+    (active edges and their values), ``dcra.graph.route`` (bucket and
+    collective; the fold-local shape reduces here too),
+    ``dcra.graph.reduce`` (receive-reduce) and ``dcra.graph.update``
+    (state update, counter psums and per-round commits).
     """
     spec = P((pod_axis, axis)) if pod_axis else P(axis)
     axes = (pod_axis, axis) if pod_axis else axis
@@ -811,21 +846,23 @@ def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
         slot = jnp.maximum(dst_b, 0) // n_dev
         evalid = dst_b >= 0
 
-        def active_of(frontier):
-            return (frontier[src_slot_b] & evalid
-                    if prog.active == "frontier" else evalid)
+        def payload(state, frontier):
+            """The active edges and the value each one sends."""
+            with jax.named_scope("dcra.graph.payload"):
+                active = (frontier[src_slot_b] & evalid
+                          if prog.active == "frontier" else evalid)
+                vals = prog.payload(ctx, state, src_slot_b,
+                                    w_b).astype(jnp.float32)
+            return active, vals
 
         def do_round(state, frontier):
-            active = active_of(frontier)
-            vals = prog.payload(ctx, state, src_slot_b,
-                                w_b).astype(jnp.float32)
-            m = gsum(jnp.sum(active.astype(jnp.int32)))
-            if fold_local:
-                upd, nd = local_route_reduce(
-                    vals, slot, owner, active, n_dev, caps[0], n_local,
-                    prog.reduce_op, impl=impl)
-            else:
-                if pod_axis is None:
+            active, vals = payload(state, frontier)
+            with jax.named_scope("dcra.graph.route"):
+                if fold_local:
+                    upd, nd = local_route_reduce(
+                        vals, slot, owner, active, n_dev, caps[0], n_local,
+                        prog.reduce_op, impl=impl)
+                elif pod_axis is None:
                     recv_slot, recv_val, nd = owner_route(
                         vals, slot, owner, active, n_dev, caps[0], axis,
                         impl=impl)
@@ -833,10 +870,15 @@ def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
                     recv_slot, recv_val, nd = owner_route_hier(
                         vals, slot, owner, active, pods[0], axis, pods[1],
                         pod_axis, caps[0], caps[1], impl=impl)
-                upd = reduce_received(recv_slot, recv_val, n_local,
-                                      prog.reduce_op)
-            state2, frontier2 = prog.update(ctx, state, frontier, upd)
-            return state2, frontier2, m, gsum(nd.astype(jnp.int32))
+            if not fold_local:
+                with jax.named_scope("dcra.graph.reduce"):
+                    upd = reduce_received(recv_slot, recv_val, n_local,
+                                          prog.reduce_op)
+            with jax.named_scope("dcra.graph.update"):
+                state2, frontier2 = prog.update(ctx, state, frontier, upd)
+                return (state2, frontier2,
+                        gsum(jnp.sum(active.astype(jnp.int32))),
+                        gsum(nd.astype(jnp.int32)))
 
         # -- pipelined produce/consume halves --------------------------------
         meta_box = []                 # static wire meta (same every round)
@@ -845,19 +887,19 @@ def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
             """Round tail: payload + bucket + LAUNCH the collective.
             Stats stay shard-local; the local frontier count rides the
             wire as the convergence signal."""
-            active = active_of(frontier)
-            vals = prog.payload(ctx, state, src_slot_b,
-                                w_b).astype(jnp.float32)
-            m_loc = jnp.sum(active.astype(jnp.int32))
-            fcnt = jnp.sum(frontier.astype(jnp.int32))
-            if pod_axis is None:
-                recv, meta, nd_loc, gcnt = owner_route_start(
-                    vals, slot, owner, active, n_dev, caps[0], axis,
-                    fcnt, impl=impl)
-            else:
-                recv, meta, nd_loc, gcnt = owner_route_hier_start(
-                    vals, slot, owner, active, pods[0], axis, pods[1],
-                    pod_axis, caps[0], caps[1], fcnt, impl=impl)
+            active, vals = payload(state, frontier)
+            with jax.named_scope("dcra.graph.update"):
+                m_loc = jnp.sum(active.astype(jnp.int32))
+                fcnt = jnp.sum(frontier.astype(jnp.int32))
+            with jax.named_scope("dcra.graph.route"):
+                if pod_axis is None:
+                    recv, meta, nd_loc, gcnt = owner_route_start(
+                        vals, slot, owner, active, n_dev, caps[0], axis,
+                        fcnt, impl=impl)
+                else:
+                    recv, meta, nd_loc, gcnt = owner_route_hier_start(
+                        vals, slot, owner, active, pods[0], axis, pods[1],
+                        pod_axis, caps[0], caps[1], fcnt, impl=impl)
             if not meta_box:
                 meta_box.append(meta)
             return recv, m_loc, nd_loc, gcnt
@@ -865,9 +907,11 @@ def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
         def consume(recv):
             """Round head: receive-reduce folded into the carried
             communication edge."""
-            recv_slot, recv_val = owner_route_finish(recv, meta_box[0])
-            return reduce_received(recv_slot, recv_val, n_local,
-                                   prog.reduce_op)
+            with jax.named_scope("dcra.graph.route"):
+                recv_slot, recv_val = owner_route_finish(recv, meta_box[0])
+            with jax.named_scope("dcra.graph.reduce"):
+                return reduce_received(recv_slot, recv_val, n_local,
+                                       prog.reduce_op)
 
         zeros = jnp.zeros((rounds,), jnp.int32)
         frontier0 = prog.frontier0(ctx, state_b)
@@ -883,18 +927,20 @@ def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
                 (state, frontier, recv, m_pend, nd_pend, gcnt, r, msgs,
                  drops, _run) = s
                 upd = consume(recv)
-                # gcnt is the global pre-round frontier count (summed
-                # across both hier stages), identical on every shard —
-                # round 0 always executes, like lockstep's changed=True
-                is_real = (gcnt > 0) | (r == 0)
-                state2, frontier2 = prog.update(ctx, state, frontier, upd)
-                state_n = tuple(jnp.where(is_real, a, b)
-                                for a, b in zip(state2, state))
-                frontier_n = jnp.where(is_real, frontier2, frontier)
-                msgs_n = jnp.where(is_real, msgs.at[r].set(m_pend), msgs)
-                drops_n = jnp.where(is_real, drops.at[r].set(nd_pend),
-                                    drops)
-                r_n = r + is_real.astype(jnp.int32)
+                with jax.named_scope("dcra.graph.update"):
+                    # gcnt is the global pre-round frontier count (summed
+                    # across both hier stages), identical on every shard —
+                    # round 0 always executes, like lockstep's changed=True
+                    is_real = (gcnt > 0) | (r == 0)
+                    state2, frontier2 = prog.update(ctx, state, frontier,
+                                                    upd)
+                    state_n = tuple(jnp.where(is_real, a, b)
+                                    for a, b in zip(state2, state))
+                    frontier_n = jnp.where(is_real, frontier2, frontier)
+                    msgs_n = jnp.where(is_real, msgs.at[r].set(m_pend), msgs)
+                    drops_n = jnp.where(is_real, drops.at[r].set(nd_pend),
+                                        drops)
+                    r_n = r + is_real.astype(jnp.int32)
                 recv_n, m_n, nd_n, g_n = produce(state_n, frontier_n)
                 return (state_n, frontier_n, recv_n, m_n, nd_n, g_n, r_n,
                         msgs_n, drops_n, is_real)
@@ -902,7 +948,9 @@ def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
             out = jax.lax.while_loop(
                 cond, body, (state_b, frontier0, recv0, m0, nd0, g0,
                              jnp.int32(0), zeros, zeros, jnp.bool_(True)))
-            state, r, msgs, drops = out[0], out[6], gsum(out[7]), gsum(out[8])
+            state, r = out[0], out[6]
+            with jax.named_scope("dcra.graph.update"):
+                msgs, drops = gsum(out[7]), gsum(out[8])
         elif prog.mode == "while":                 # lockstep / fold_local
             def cond(s):
                 _, _, r, _, _, changed = s
@@ -911,9 +959,10 @@ def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
             def body(s):
                 state, frontier, r, msgs, drops, _ = s
                 state2, frontier2, m, nd = do_round(state, frontier)
-                changed = gsum(jnp.sum(frontier2.astype(jnp.int32))) > 0
-                return (state2, frontier2, r + 1, msgs.at[r].set(m),
-                        drops.at[r].set(nd), changed)
+                with jax.named_scope("dcra.graph.update"):
+                    changed = gsum(jnp.sum(frontier2.astype(jnp.int32))) > 0
+                    return (state2, frontier2, r + 1, msgs.at[r].set(m),
+                            drops.at[r].set(nd), changed)
 
             state, _, r, msgs, drops, _ = jax.lax.while_loop(
                 cond, body, (state_b, frontier0, jnp.int32(0), zeros,
@@ -924,10 +973,13 @@ def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
             def body(i, s):
                 state, frontier, recv, m_pend, nd_pend, msgs, drops = s
                 upd = consume(recv)
-                state2, frontier2 = prog.update(ctx, state, frontier, upd)
+                with jax.named_scope("dcra.graph.update"):
+                    state2, frontier2 = prog.update(ctx, state, frontier,
+                                                    upd)
+                    msgs, drops = (msgs.at[i].set(m_pend),
+                                   drops.at[i].set(nd_pend))
                 recv_n, m_n, nd_n, _g = produce(state2, frontier2)
-                return (state2, frontier2, recv_n, m_n, nd_n,
-                        msgs.at[i].set(m_pend), drops.at[i].set(nd_pend))
+                return (state2, frontier2, recv_n, m_n, nd_n, msgs, drops)
 
             # rounds-1 full iterations, then drain the last in-flight
             # round without launching a trailing (wasted) collective
@@ -936,16 +988,18 @@ def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
                                    zeros, zeros))
             state, frontier, recv, m_pend, nd_pend, msgs, drops = s
             upd = consume(recv)
-            state, _f = prog.update(ctx, state, frontier, upd)
-            msgs = gsum(msgs.at[rounds - 1].set(m_pend))
-            drops = gsum(drops.at[rounds - 1].set(nd_pend))
+            with jax.named_scope("dcra.graph.update"):
+                state, _f = prog.update(ctx, state, frontier, upd)
+                msgs = gsum(msgs.at[rounds - 1].set(m_pend))
+                drops = gsum(drops.at[rounds - 1].set(nd_pend))
             r = jnp.int32(rounds)
         else:                                      # "fixed" lockstep/fold
             def body(i, s):
                 state, frontier, msgs, drops = s
                 state2, frontier2, m, nd = do_round(state, frontier)
-                return (state2, frontier2, msgs.at[i].set(m),
-                        drops.at[i].set(nd))
+                with jax.named_scope("dcra.graph.update"):
+                    return (state2, frontier2, msgs.at[i].set(m),
+                            drops.at[i].set(nd))
 
             state, _, msgs, drops = jax.lax.fori_loop(
                 0, rounds, body, (state_b, frontier0, zeros, zeros))
