@@ -16,9 +16,9 @@ emits schema ``dcra-route-bench/v2`` with two kinds of wall-clock cells:
   one ``run_program`` round between collectives), timed in BOTH round
   shapes per impl: ``lockstep`` (``bucket`` + ``reduce_received``, the
   classic two-pass round) vs ``pipelined`` (``local_route_reduce``, the
-  round_mode="pipelined" fold of the receive-reduce into the
-  communication edge). The bench itself asserts the two shapes are
-  bit-identical (final state AND per-round drop streams) before timing,
+  one-device fold of the receive-reduce into admission). The bench
+  itself asserts the two shapes are bit-identical (final state AND
+  per-round drop streams) before timing,
   and ``round_speedup`` (lockstep ms / pipelined ms per impl) is gated
   by :mod:`repro.dse.route_compare` like the op-level ratios.
 
@@ -108,9 +108,9 @@ def _bench_round_cell(n: int, s: int, reps: int) -> Dict:
     capacity-bounded buckets, receive-reduce into the state vector, and
     recompute the frontier from what improved. ``lockstep`` renders it as
     the classic two-pass ``bucket`` -> ``reduce_received``; ``pipelined``
-    as the fused ``local_route_reduce`` fold (exactly what
-    ``round_mode="pipelined"`` runs on a single shard). Both are asserted
-    bit-identical — same final state, same per-round drop stream — before
+    as the fused ``local_route_reduce`` fold (what a one-device launch
+    runs in either round mode). Both are asserted bit-identical — same
+    final state, same per-round drop stream — before
     any timing, so the speedup column can never hide a semantic change.
     """
     import jax

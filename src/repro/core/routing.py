@@ -416,38 +416,45 @@ def owner_route_hier_start(vals, slot_ids, owner, valid, n_intra,
     return recv2, meta2, drop1 + drop2, gsignal
 
 
-def local_route_reduce(vals, slot_ids, dest, valid, n_buckets, cap, n_local,
-                       op, impl=None):
-    """One whole round with a LOCAL communication edge: when producer and
-    consumer are the same shard (``n_dev == 1`` launches; the per-shard
-    round the bench simulates), folding the receive-reduce into admission
-    eliminates the wire buffer — rank, capacity-test, and segment-reduce
-    straight off the task stream, never materializing the
-    ``[n_buckets*cap]`` bucket array or re-reading it at the receiver.
+def local_route(vals, slot_ids, dest, valid, n_buckets, cap, impl=None):
+    """Admission of one round whose producer and consumer are the same
+    shard (a one-device flat launch): rank and capacity test, with no
+    bucket array, no wire and no collective — the task stream itself is
+    the receive buffer.
 
-    Valid only for order-insensitive reduces (``min`` / ``store``): the
-    kept set is identical to ``bucket`` + :func:`reduce_received` (same
-    first-``cap``-per-channel rule, same rank ``impl``) and min/max are
-    exact in f32, so the result and drop count are bit-identical to the
-    two-pass path. ``add`` must keep the two-pass path — its summation
-    order would differ. Returns ``(y [n_local], n_drop)``.
+    The kept set is :func:`bucket`'s: the first ``cap`` valid tasks per
+    channel in array order, ranked by the same ``impl``, so the drop
+    count is the same too. Returns ``(recv_slot, recv_val, n_drop)`` as
+    :func:`owner_route` does, with ``recv_slot`` -1 for every task not
+    kept, ready for :func:`reduce_received`.
     """
-    if op not in ("min", "store"):
-        raise ValueError(f"local_route_reduce needs an order-insensitive "
-                         f"reduce, got {op!r}")
     pos = positions_by_dest(dest, valid, n_buckets, impl=impl)
     keep = valid & (pos < cap)
-    n_drop = jnp.sum(valid & ~keep)
-    seg = jnp.where(keep, slot_ids, n_local)
-    if op == "min":
-        y = jax.ops.segment_min(jnp.where(keep, vals, jnp.inf), seg,
-                                num_segments=n_local + 1)[:n_local]
-        y = jnp.where(jnp.isfinite(y), y, jnp.inf)
-    else:                                                # "store" (max)
-        y = jax.ops.segment_max(jnp.where(keep, vals, -jnp.inf), seg,
-                                num_segments=n_local + 1)[:n_local]
-        y = jnp.where(jnp.isfinite(y), y, 0.0)
-    return y, n_drop
+    return jnp.where(keep, slot_ids, -1), vals, jnp.sum(valid & ~keep)
+
+
+def local_route_reduce(vals, slot_ids, dest, valid, n_buckets, cap, n_local,
+                       op, impl=None):
+    """One whole round with a LOCAL communication edge:
+    :func:`local_route` then :func:`reduce_received` straight off the
+    task stream, never materializing the ``[n_buckets*cap]`` bucket array
+    or re-reading it at the receiver.
+
+    The result and drop count are bit-identical to ``bucket`` +
+    :func:`reduce_received` for ``min`` / ``store`` with any number of
+    buckets (order-insensitive, exact in f32), and for ``add`` with ONE
+    bucket: there the kept tasks are summed in array order, which is the
+    bucket's slot order. With several buckets the bucket array is ordered
+    by destination, not by array index, so ``add`` raises
+    ``ValueError``. Returns ``(y [n_local], n_drop)``.
+    """
+    if op == "add" and n_buckets > 1:
+        raise ValueError(f"local_route_reduce sums in array order, which "
+                         f"is bucket order only for one bucket, got "
+                         f"{n_buckets}")
+    recv_slot, recv_val, n_drop = local_route(vals, slot_ids, dest, valid,
+                                              n_buckets, cap, impl=impl)
+    return reduce_received(recv_slot, recv_val, n_local, op), n_drop
 
 
 def reduce_received(recv_slot, recv_val, n_local, op):

@@ -48,7 +48,9 @@ class LaunchOptions:
     shuffle; ``route_impl`` picks the routing hot-path engine ("pallas" |
     "sort" | "onehot" | None = autodetect); ``round_mode`` picks the round
     execution shape ("lockstep" | "pipelined" — bit-identical results,
-    see README "Pipelined rounds").
+    see README "Pipelined rounds"). It shapes multi-device rounds only: a
+    one-device flat launch has no wire to overlap, so both modes run the
+    same round, with the receive-reduce folded into admission.
     """
     axis: str = "data"
     pod_axis: Optional[str] = None

@@ -47,7 +47,7 @@ from jax.sharding import PartitionSpec as P
 from ..core.compat import shard_map_unchecked
 from ..core.fabric import Fabric, as_fabric
 from ..core.queues import QueueConfig
-from ..core.routing import (local_route_reduce, owner_route,
+from ..core.routing import (local_route, owner_route,
                             owner_route_finish, owner_route_hier,
                             owner_route_hier_start, owner_route_start,
                             reduce_received, resolve_caps,
@@ -344,13 +344,16 @@ def _host_gather(fab: Fabric, x):
 # ---------------------------------------------------------------------------
 
 _CACHE: Dict[tuple, Callable] = {}
-CACHE_STATS = {"hits": 0, "misses": 0, "kernel_traces": 0}
+CACHE_STATS = {"hits": 0, "misses": 0, "kernel_traces": 0,
+               "local_fold_builds": 0}
 
 
 def cache_stats() -> Dict[str, int]:
     """Copy of the compile-cache counters (asserted by tests: a repeated
     same-shape launch must be a ``hits`` increment with ``kernel_traces``
-    unchanged — no re-trace)."""
+    unchanged — no re-trace). ``local_fold_builds`` counts the graph
+    callables built with the one-device local fold (see
+    :func:`_build_graph_fn`)."""
     return dict(CACHE_STATS)
 
 
@@ -647,8 +650,9 @@ def run_program(prog: TaskProgram, data, fabric, *,
     kwarg above (the legacy kwargs keep working through the deprecation
     shim, resolving through the identical conflict checks and producing
     the identical cache key). ``round_mode="pipelined"`` selects the
-    double-buffered round shape (see :func:`_build_graph_fn`) —
-    bit-identical results and per-round stats, fewer collectives.
+    double-buffered round shape on more than one device (see
+    :func:`_build_graph_fn`) — bit-identical results and per-round stats,
+    fewer collectives; one device runs one local round in either mode.
     Graph programs dispatch through :func:`launch_program` and block on
     its :meth:`ProgramLaunch.result` — the asynchronous serving tier
     skips only that final wait, never the launch path itself.
@@ -816,18 +820,23 @@ def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
       are all gated off (``is_real``), exactly reproducing lockstep's
       "round 0 always executes" initial ``changed=True``.
 
-      The degenerate 1-device flat launch with an order-insensitive
-      reduce has a *local* communication edge, so the receive-reduce is
-      instead folded into admission (:func:`local_route_reduce`) — no
-      wire buffer at all; ``add``-reduce keeps the generic shape (its
-      summation order must match lockstep's bucket order).
+    A one-device flat launch (``n_dev == 1``, no ``pod_axis``) has a
+    *local* communication edge whatever ``round_mode`` says, so it runs
+    neither shape's bucket and collective: the receive-reduce is folded
+    into admission (:func:`~repro.core.routing.local_route`) — rank,
+    capacity test, then the segment reduce straight off the edge stream.
+    The kept set is ``bucket``'s, and with one bucket array order is
+    bucket order, so every reduce op (``add`` included) gives the
+    two-pass result and drop count; both round modes run the same
+    lockstep loop, with nothing to overlap. ``CACHE_STATS
+    ["local_fold_builds"]`` counts the callables built this way.
 
     In every shape a round's device work runs under four scopes, so a
     device profile splits the round by phase: ``dcra.graph.payload``
     (active edges and their values), ``dcra.graph.route`` (bucket and
-    collective; the fold-local shape reduces here too),
-    ``dcra.graph.reduce`` (receive-reduce) and ``dcra.graph.update``
-    (state update, counter psums and per-round commits).
+    collective, or the local rank), ``dcra.graph.reduce``
+    (receive-reduce) and ``dcra.graph.update`` (state update, counter
+    psums and per-round commits).
     """
     spec = P((pod_axis, axis)) if pod_axis else P(axis)
     axes = (pod_axis, axis) if pod_axis else axis
@@ -836,9 +845,10 @@ def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
         return jax.lax.psum(x, axes)
 
     ctx = Ctx(xp=jnp, n=n, n_dev=n_dev, params=params, gsum=gsum)
-    fold_local = (round_mode == "pipelined" and pod_axis is None
-                  and n_dev == 1 and prog.reduce_op in ("min", "store"))
+    fold_local = n_dev == 1 and pod_axis is None
     pipelined = round_mode == "pipelined" and not fold_local
+    if fold_local:
+        CACHE_STATS["local_fold_builds"] += 1
 
     def kernel(src_slot_b, dst_b, w_b, *state_b):
         CACHE_STATS["kernel_traces"] += 1
@@ -859,9 +869,9 @@ def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
             active, vals = payload(state, frontier)
             with jax.named_scope("dcra.graph.route"):
                 if fold_local:
-                    upd, nd = local_route_reduce(
-                        vals, slot, owner, active, n_dev, caps[0], n_local,
-                        prog.reduce_op, impl=impl)
+                    recv_slot, recv_val, nd = local_route(
+                        vals, slot, owner, active, n_dev, caps[0],
+                        impl=impl)
                 elif pod_axis is None:
                     recv_slot, recv_val, nd = owner_route(
                         vals, slot, owner, active, n_dev, caps[0], axis,
@@ -870,10 +880,9 @@ def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
                     recv_slot, recv_val, nd = owner_route_hier(
                         vals, slot, owner, active, pods[0], axis, pods[1],
                         pod_axis, caps[0], caps[1], impl=impl)
-            if not fold_local:
-                with jax.named_scope("dcra.graph.reduce"):
-                    upd = reduce_received(recv_slot, recv_val, n_local,
-                                          prog.reduce_op)
+            with jax.named_scope("dcra.graph.reduce"):
+                upd = reduce_received(recv_slot, recv_val, n_local,
+                                      prog.reduce_op)
             with jax.named_scope("dcra.graph.update"):
                 state2, frontier2 = prog.update(ctx, state, frontier, upd)
                 return (state2, frontier2,

@@ -6,7 +6,11 @@ the warning fires once per process, conflicts raise), ``round_mode`` /
 ``route_impl`` land in the compile-cache key, every entrypoint accepts
 ``options=``, ``local_route_reduce`` is bit-identical to the two-pass
 ``bucket`` + ``reduce_received`` shape, the round-level route_compare
-gate, and a pipelined ``ProgramServer`` serves identically.
+gate, and a pipelined ``ProgramServer`` serves identically. On a
+one-device fabric both round modes run the local fold: the seven apps
+agree bitwise across modes and match the oracles, tight caps drop as the
+analytic twin says, and ``cache_stats()["local_fold_builds"]`` counts the
+folded builds.
 
 Part B (subprocess, 8 fake host devices) — the bit-identity contract of
 ``round_mode="pipelined"``: for every iterative program, flat AND
@@ -144,18 +148,21 @@ def test_every_entrypoint_accepts_options():
     assert np.array_equal(np.asarray(y1), np.asarray(y2))
 
 
-@pytest.mark.parametrize("op", ["min", "store"])
-def test_local_route_reduce_matches_two_pass_shape(op):
-    """The 1-device pipelined fold == bucket + reduce_received, bitwise,
-    including the drop count, under overflowing caps."""
+@pytest.mark.parametrize("op,s", [("min", 8), ("store", 8), ("min", 1),
+                                  ("add", 1)])
+def test_local_route_reduce_matches_two_pass_shape(op, s):
+    """The local fold == bucket + reduce_received, bitwise, including the
+    drop count, under overflowing caps: min / store with any number of
+    buckets, add with one (array order is bucket order only there)."""
     import jax.numpy as jnp
     from repro.core.routing import (bucket, local_route_reduce,
                                     reduce_received)
     rng = np.random.default_rng(5)
-    n, s, cap, n_local = 512, 8, 16, 64        # 512 >> s*cap: drops
+    n, n_local = 512, 64
+    cap = 16 if s > 1 else 100                 # 512 >> s*cap: drops
     dest = jnp.asarray(rng.integers(0, s, n), jnp.int32)
     valid = jnp.asarray(rng.random(n) < 0.8)
-    vals = jnp.asarray(rng.random(n), jnp.float32)
+    vals = jnp.asarray(rng.standard_normal(n), jnp.float32)
     slots = jnp.asarray(rng.integers(0, n_local, n), jnp.int32)
     xb, (slot_b,), _, nd_ref = bucket(vals[:, None], dest, valid, [slots],
                                       s, cap)
@@ -163,10 +170,17 @@ def test_local_route_reduce_matches_two_pass_shape(op):
     got, nd = local_route_reduce(vals, slots, dest, valid, s, cap,
                                  n_local, op)
     assert int(nd) == int(nd_ref) and int(nd) > 0
-    assert np.array_equal(np.asarray(want), np.asarray(got))
-    with pytest.raises(ValueError):
-        local_route_reduce(vals, slots, dest, valid, s, cap, n_local,
-                           "add")
+    assert np.array_equal(np.asarray(want).view(np.uint32),
+                          np.asarray(got).view(np.uint32))
+
+
+def test_local_route_reduce_refuses_add_over_several_buckets():
+    import jax.numpy as jnp
+    from repro.core.routing import local_route_reduce
+    dest = jnp.asarray(np.arange(32) % 4, jnp.int32)
+    ones = jnp.ones(32, jnp.float32)
+    with pytest.raises(ValueError, match="one bucket"):
+        local_route_reduce(ones, dest, dest, ones > 0, 4, 8, 4, "add")
 
 
 def test_route_compare_gates_round_cells():
@@ -208,6 +222,110 @@ def test_pipelined_program_server_serves_identically():
     with pytest.raises(ValueError, match="conflicts"):
         ProgramServer(mesh, {"g": g}, axis="model",
                       options=LaunchOptions())
+
+
+# ---------------------------------------------------------------------------
+# Part A, one device: both round modes run the local fold
+# ---------------------------------------------------------------------------
+
+def _fabric1():
+    from repro.core.fabric import Fabric
+    return Fabric.single((1,), ("data",))
+
+
+def _graph256():
+    from repro.sparse import datasets
+    return datasets.wiki_like(256, avg_degree=8, seed=7)
+
+
+ONE_DEVICE_PARAMS = {"bfs": {"root": 0}, "sssp": {"root": 0}, "wcc": {},
+                     "pagerank": {"damping": 0.85, "iters": 20},
+                     "kcore": {"k": 8.0}, "spmv": {}, "histogram": {}}
+
+
+def _one_device_run(app, round_mode, **kw):
+    """``run_program`` of ``app`` on a one-device fabric and its oracle's
+    answer, each in the app's own units (hops, labels, degrees)."""
+    from repro.sparse import LaunchOptions, datasets, ref
+    from repro.sparse.jax_apps import PROGRAMS, run_program
+    g = _graph256()
+    x = np.random.default_rng(0).random(g.n)
+    els = datasets.histogram_data(1 << 11, 64, seed=4)
+    data = {"spmv": (g, x), "histogram": (els, 64)}.get(app, g)
+    out, stats = run_program(
+        PROGRAMS[app], data, _fabric1(), params=ONE_DEVICE_PARAMS[app],
+        options=LaunchOptions(round_mode=round_mode, **kw))
+    if app in ("spmv", "histogram"):
+        got = np.asarray(out)
+        want = (ref.spmv_ref(g, x) if app == "spmv"
+                else ref.histogram_ref(els, 64))
+    elif app in ("bfs", "sssp"):
+        got = out[0]
+        want = (ref.bfs_ref(g, 0) if app == "bfs" else ref.sssp_ref(g, 0))
+        if app == "bfs":
+            got = np.where(np.isfinite(got), got, -1)
+    elif app == "kcore":
+        deg, alive = out
+        got, want = np.where(alive > 0, deg, -1), ref.kcore_ref(g, 8)
+    else:
+        got = out[0]
+        want = ref.wcc_ref(g) if app == "wcc" else ref.pagerank_ref(g)
+    return np.asarray(got, np.float64), np.asarray(want, np.float64), stats
+
+
+@pytest.mark.parametrize("app", sorted(ONE_DEVICE_PARAMS))
+def test_one_device_modes_agree_and_match_oracle(app):
+    """Lockstep and pipelined run the same folded round on one device:
+    bitwise the same answer and stats, the oracle's answer, no drops."""
+    got_l, want, s_l = _one_device_run(app, "lockstep")
+    got_p, _, s_p = _one_device_run(app, "pipelined")
+    assert np.array_equal(got_l.view(np.uint64), got_p.view(np.uint64))
+    assert s_l.rounds == s_p.rounds
+    assert np.array_equal(s_l.messages, s_p.messages)
+    assert np.array_equal(s_l.drops, s_p.drops)
+    both = np.isfinite(want)
+    assert np.array_equal(np.isfinite(got_l), both)
+    err = np.abs(got_l[both] - want[both]).max()
+    # relative to the largest answer where the answer is a float sum
+    scale = {"pagerank": want.max(),
+             "spmv": max(1.0, np.abs(want).max())}.get(app, 1.0)
+    assert err / scale < 1e-4, err
+    assert s_l.total_drops == 0
+
+
+@pytest.mark.parametrize("app", ITER_APPS)
+def test_one_device_tight_cap_streams_match_twin(app):
+    """Under cap=2 the fold drops tasks, and both modes' message and drop
+    streams are the analytic twin's, round for round."""
+    from repro.sparse.jax_apps import PROGRAMS
+    from repro.sparse.program import program_app_stats
+    twin = program_app_stats(PROGRAMS[app], _graph256(), 1, cap=2,
+                             params=ONE_DEVICE_PARAMS[app])
+    got = {}
+    for mode in ("lockstep", "pipelined"):
+        got[mode], _, stats = _one_device_run(app, mode, cap=2)
+        assert stats.total_drops > 0
+        assert stats.rounds == twin.rounds
+        assert np.array_equal(stats.messages, twin.messages)
+        assert np.array_equal(stats.drops, twin.drops)
+    assert np.array_equal(got["lockstep"].view(np.uint64),
+                          got["pipelined"].view(np.uint64))
+
+
+def test_local_fold_builds_count_one_device_graph_callables():
+    from repro.sparse import LaunchOptions, program
+    from repro.sparse.jax_apps import dcra_bfs, dcra_pagerank
+    g, fab = _graph256(), _fabric1()
+    program.clear_cache()
+    dcra_bfs(g, 0, fab)
+    dcra_bfs(g, 1, fab)                          # same shape: a cache hit
+    dcra_bfs(g, 0, fab, options=LaunchOptions(round_mode="pipelined"))
+    dcra_pagerank(g, fab, iters=3)
+    stats = program.cache_stats()
+    assert stats["misses"] == 3 and stats["hits"] == 1
+    assert stats["local_fold_builds"] == 3
+    program.clear_cache()
+    assert program.cache_stats()["local_fold_builds"] == 0
 
 
 # ---------------------------------------------------------------------------

@@ -6,7 +6,9 @@
   carrying the launch's ordinal.
 * The HLO of the graph function (each round shape) and of ``moe_dcra``
   names every device scope its ops run under, so a profile can split the
-  time by phase; a refactor that drops one fails here.
+  time by phase; a refactor that drops one fails here. One-device graph
+  rounds, in either round mode, carry no scatter or collective scope and
+  are the only ones counted in ``local_fold_builds``.
 * ``slot_plan`` gives the bucket sizes ``moe_dcra`` allocates.
 """
 import dataclasses
@@ -108,8 +110,12 @@ def scopes(text):
                    for p in st.split("/") if p.startswith("dcra.")})
 
 
+FOLDS = {}
+
+
 def graph(app, round_mode, route_impl, shape, names, pod_axis):
     texts, build = [], program._build_graph_fn
+    folds0 = program.cache_stats()["local_fold_builds"]
 
     def spy(*a, **kw):
         fn = build(*a, **kw)
@@ -130,6 +136,8 @@ def graph(app, round_mode, route_impl, shape, names, pod_axis):
                               round_mode=round_mode, route_impl=route_impl))
     program._build_graph_fn = build
     (text,) = texts
+    FOLDS[(app, round_mode, route_impl, shape)] = (
+        program.cache_stats()["local_fold_builds"] - folds0)
     return scopes(text)
 
 
@@ -155,12 +163,17 @@ res = {
     "graph-bfs-pipelined": graph("bfs", "pipelined", None, *flat),
     "graph-bfs-pipelined-pods": graph("bfs", "pipelined", None, *pods),
     "graph-bfs-pipelined-one-device": graph("bfs", "pipelined", None, *one),
+    "graph-bfs-lockstep-one-device": graph("bfs", "lockstep", None, *one),
     "graph-pagerank-lockstep": graph("pagerank", "lockstep", None, *flat),
     "graph-pagerank-pipelined": graph("pagerank", "pipelined", None, *flat),
+    "graph-pagerank-lockstep-one-device": graph("pagerank", "lockstep", None,
+                                                *one),
     "moe": moe(8, *moe_mesh),
     "moe-one-expert-per-shard": moe(4, *moe_mesh),
     "moe-pods": moe(8, *moe_pods),
 }
+res["local_fold_builds"] = [[list(k[:3]) + [list(k[3])], v]
+                            for k, v in FOLDS.items()]
 print("RESULT " + json.dumps(res))
 """
 
@@ -172,12 +185,14 @@ EXPECTED_SCOPES = {
     "graph-bfs-lockstep-pods": EVERY_GRAPH,
     "graph-bfs-pipelined": EVERY_GRAPH,
     "graph-bfs-pipelined-pods": EVERY_GRAPH,
-    # one device, min-reduce: the receive-reduce folds into the route and
-    # nothing crosses a wire
-    "graph-bfs-pipelined-one-device": (GRAPH_PHASES - {"dcra.graph.reduce"}
-                                       | {"dcra.route.rank"}),
+    # one device, any round mode or reduce op: the route only ranks, the
+    # reduce reads the edge stream, and nothing is scattered or crosses a
+    # wire
+    "graph-bfs-pipelined-one-device": GRAPH_PHASES | {"dcra.route.rank"},
+    "graph-bfs-lockstep-one-device": GRAPH_PHASES | {"dcra.route.rank"},
     "graph-pagerank-lockstep": EVERY_GRAPH,
     "graph-pagerank-pipelined": EVERY_GRAPH,
+    "graph-pagerank-lockstep-one-device": GRAPH_PHASES | {"dcra.route.rank"},
     "moe": EVERY_MOE,
     # one expert per shard: no per-expert bucket to pad
     "moe-one-expert-per-shard": EVERY_MOE - {"dcra.moe.expert_pad"},
@@ -199,6 +214,16 @@ def hlo_scopes():
 @pytest.mark.parametrize("case", sorted(EXPECTED_SCOPES))
 def test_layer_hlo_carries_every_scope(case, hlo_scopes):
     assert set(hlo_scopes[case]) == EXPECTED_SCOPES[case]
+
+
+def test_local_fold_builds_only_on_one_device(hlo_scopes):
+    """Each graph callable built on the one-device fabric takes the fold;
+    none built on the four-device flat or 2x2 pod fabric does."""
+    builds = hlo_scopes["local_fold_builds"]
+    assert len(builds) == 10
+    assert sum(n for (*_, shape), n in builds if shape == [1]) == 3
+    for (app, mode, impl, shape), n in builds:
+        assert n == (1 if shape == [1] else 0), (app, mode, impl, shape)
 
 
 def _moe(capacity_factor=1.25, num_experts=4, top_k=2):
