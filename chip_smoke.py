@@ -211,7 +211,7 @@ def moe_phase(devices) -> None:
     from repro.core.compat import make_mesh
     from repro.core.dispatch import MeshInfo, moe_dcra
     from repro.models.moe import (GROUP_SIZE, capacity, init_moe,
-                                  moe_einsum, router_probs)
+                                  moe_einsum, route)
     cfg = get_config("olmoe-1b-7b")
     mc = dataclasses.replace(cfg.moe, capacity_factor=MOE_CAPACITY_FACTOR)
     cfg = dataclasses.replace(cfg, moe=mc)
@@ -220,9 +220,7 @@ def moe_phase(devices) -> None:
                           (MOE_BATCH, MOE_SEQ, cfg.d_model), jnp.float32)
     # the einsum reference drops nothing when no expert of any token
     # group receives more than its capacity
-    probs, _ = router_probs(params, x.reshape(-1, GROUP_SIZE, cfg.d_model),
-                            mc)
-    _, eids = jax.lax.top_k(probs, mc.top_k)
+    _, eids, _ = route(params, x.reshape(-1, GROUP_SIZE, cfg.d_model), mc)
     load = np.stack([np.bincount(np.asarray(e).ravel(),
                                  minlength=mc.num_experts)
                      for e in eids]).max()

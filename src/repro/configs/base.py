@@ -58,6 +58,19 @@ class MoEConfig:
     # 'dcra'     : shard_map hierarchical two-level all-to-all (paper technique)
     dispatch_impl: str = "einsum"
     router_jitter: float = 0.0
+    # gating (core.dispatch.gate): 'softmax' takes the top-k of the
+    # softmax; 'sigmoid' is DeepSeek-V3's noaux_tc: sigmoid scores, a
+    # selection-only bias, and the top-k of the experts in the topk_group
+    # best of n_group groups. Either way the k gates are renormalised to
+    # sum to 1, then scaled by routed_scaling_factor.
+    scoring: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    # shared experts: a dense SwiGLU of width n_shared * d_shared that
+    # every token passes through beside its routed experts
+    n_shared: int = 0
+    d_shared: int = 0
 
 
 @dataclass(frozen=True)
@@ -164,6 +177,7 @@ class ArchConfig:
         # ffn
         if self.moe is not None:
             p += self.moe.num_experts * 3 * d * self.moe.d_expert + d * self.moe.num_experts
+            p += 3 * d * self.moe.n_shared * self.moe.d_shared
         else:
             p += 3 * d * self.d_ff  # SwiGLU: gate,up,down
         return p

@@ -61,6 +61,11 @@ class MeshInfo:
     fsdp: bool = True                    # expert weights sharded over data
     fuse_tp: bool = True                 # fold tp into the expert group when
                                          # E divides (no psum, no seq gather)
+    # (first, count): this mesh holds experts [first, first + count) of
+    # the layer's E, split over its expert shards as the whole E would be;
+    # tokens route over all E and only the held experts' tasks run here.
+    # None: the mesh holds every expert.
+    expert_share: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
         from .fabric import Fabric
@@ -107,7 +112,8 @@ class MeshInfo:
 
 class SlotPlan(NamedTuple):
     """The slots one shard's dispatch allocates (see :func:`slot_plan`)."""
-    tasks: int              # routed tasks: tokens x top-k
+    tasks: int              # routed tasks the held experts expect:
+                            # tokens x top-k x held / E
     dispatch_slots: int     # stage-1 bucket: group size x cap1
     expert_slots: int       # rows the expert FFN runs over: E_local x cap_e
     cap1: int               # stage-1 (tile-NoC) capacity per destination
@@ -116,7 +122,7 @@ class SlotPlan(NamedTuple):
 
     @property
     def slot_fill(self) -> float:
-        """Routed tasks over allocated expert slots: 1 when no slot is
+        """Held tasks over allocated expert slots: 1 when no slot is
         padding; capacity factor ``f`` compounds to about ``1 / f**2``
         on one shard, where dispatch and expert buckets both pad."""
         return self.tasks / self.expert_slots
@@ -131,17 +137,20 @@ def slot_plan(mc, info: MeshInfo, tokens: int,
     :func:`dispatch_queues`. The stage-1 bucket holds ``cap1`` tasks per
     rank of the dispatch group; what a shard receives (``cap1`` per
     group rank, or ``cap2`` per pod when experts span pods) is bucketed
-    again by local expert into ``cap_e`` rows each. Host-side and static:
+    again by local expert into ``cap_e`` rows each. With an expert share
+    (``info.expert_share``) the buckets are sized for the tasks the held
+    experts expect, ``tokens * top_k * held / E``. Host-side and static:
     callers count slot fill without tracing the layer.
     """
     if queues is None:
         queues = dispatch_queues(mc)
     E = mc.num_experts
-    group, spans_pods, _ = info.dispatch_plan(E)
+    _, held = info.expert_share or (0, E)
+    group, spans_pods, _ = info.dispatch_plan(held)
     n_ex = info.axis_size(group)
     n_pod = info.axis_size(info.pod_axis) if spans_pods else 1
-    e_local = E // (n_ex * n_pod)
-    tasks = tokens * mc.top_k
+    e_local = held // (n_ex * n_pod)
+    tasks = -(-tokens * mc.top_k * held // E)
     cap1 = queues.channel_cap("dispatch", tasks, n_ex)
     cap2 = None
     received = n_ex * cap1
@@ -166,6 +175,67 @@ def _expert_ffn(xe, wg, wu, wd, tp_axis, n_tp):
     return y
 
 
+def _group_limit(choice, n_group: int, topk_group: int):
+    """``choice`` [..., E] with the experts outside each token's
+    ``topk_group`` best of ``n_group`` equal groups set to -inf. A group's
+    score is the sum of its two best choice scores (DeepSeek-V3)."""
+    *lead, E = choice.shape
+    grouped = choice.reshape(*lead, n_group, E // n_group)
+    group_score = jax.lax.top_k(grouped, min(2, E // n_group))[0].sum(-1)
+    _, kept = jax.lax.top_k(group_score, topk_group)
+    keep = jnp.any(kept[..., :, None] == jnp.arange(n_group), axis=-2)
+    return jnp.where(keep[..., None], grouped, -jnp.inf).reshape(choice.shape)
+
+
+def gate(logits, mc, bias=None):
+    """The router's choice from its float32 logits [..., E].
+
+    Returns ``(gates [..., K], eids [..., K], probs [..., E])``: each
+    token's top-k experts, their weights, and the per-expert scores the
+    load-balance loss averages. ``mc.scoring`` picks the rule:
+
+    * ``softmax``: the top-k of the softmax;
+    * ``sigmoid`` (DeepSeek-V3's ``noaux_tc``): sigmoid scores; ``bias``
+      [E] (``e_score_correction_bias``) is added to them only to choose.
+      With ``n_group`` > 1 the choice is limited to each token's
+      ``topk_group`` best groups (:func:`_group_limit`); the gates are the
+      chosen experts' unbiased scores. Experts of other groups are masked
+      to -inf, where DeepSeek-V3's reference code writes 0.0: a masked
+      expert is then never chosen, even over a negative biased score.
+
+    Either way the k gates are renormalised to sum to 1 and multiplied by
+    ``mc.routed_scaling_factor``.
+    """
+    if mc.scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, eids = jax.lax.top_k(probs, mc.top_k)
+    elif mc.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        choice = scores if bias is None else scores + bias
+        if mc.n_group > 1:
+            choice = _group_limit(choice, mc.n_group, mc.topk_group)
+        _, eids = jax.lax.top_k(choice, mc.top_k)
+        gates = jnp.take_along_axis(scores, eids, axis=-1)
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    else:
+        raise ValueError(f"unknown MoE scoring {mc.scoring!r}")
+    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if mc.routed_scaling_factor != 1.0:
+        gates = gates * mc.routed_scaling_factor
+    return gates, eids, probs
+
+
+def shared_expert(params, x):
+    """The shared experts, one SwiGLU over every token: x [..., D] ->
+    [..., D] in x's dtype (weights ``shared_wg``/``shared_wu`` [D, Fs] and
+    ``shared_wd`` [Fs, D])."""
+    dt = x.dtype
+    with jax.named_scope("dcra.moe.shared"):
+        h = jax.nn.silu(x @ params["shared_wg"].astype(dt)) * \
+            (x @ params["shared_wu"].astype(dt))
+        return h @ params["shared_wd"].astype(dt)
+
+
 def moe_dcra(params, x, cfg, info: MeshInfo,
              queues: Optional[QueueConfig] = None
              ) -> Tuple[jax.Array, jax.Array]:
@@ -176,11 +246,20 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
     ``DesignPoint.moe_queues()`` plugs in here for DSE sweeps). Bucket
     sizes come from :func:`slot_plan`.
 
-    The layer's device work runs under five scopes: ``dcra.moe.router``
+    Tokens route over all ``E`` experts (:func:`gate`; ``router_bias``
+    in ``params`` is the sigmoid router's selection bias). With
+    ``info.expert_share`` the mesh holds only those experts: ``wg``/``wu``
+    /``wd`` are theirs, the other experts' tasks are not dispatched, and
+    the output is the held experts' part of the routed sum. With
+    ``mc.n_shared`` the shared experts (:func:`shared_expert`) are added
+    to it.
+
+    The layer's device work runs under six scopes: ``dcra.moe.router``
     (logits, top-k, gates), ``dcra.moe.dispatch`` (stage buckets, token
     gather, collectives), ``dcra.moe.expert_pad`` (rows into and out of
-    the per-expert buckets), ``dcra.moe.expert_ffn`` and
-    ``dcra.moe.combine`` (return path, gate-weighted sum, aux loss).
+    the per-expert buckets), ``dcra.moe.expert_ffn``,
+    ``dcra.moe.combine`` (return path, gate-weighted sum, aux loss) and
+    ``dcra.moe.shared``.
     """
     mc = cfg.moe
     assert mc is not None
@@ -189,11 +268,12 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
     # the three bounded dispatch buckets share one routing engine
     impl = resolve_route_impl(queues.route_impl)
     E = mc.num_experts
-    group, spans_pods, tp_ffn = info.dispatch_plan(E)
+    first, held = info.expert_share or (0, E)
+    group, spans_pods, tp_ffn = info.dispatch_plan(held)
     n_group = info.axis_size(group)
     n_pod = info.axis_size(info.pod_axis) if spans_pods else 1
     n_ex = n_group
-    E_local = E // (n_group * n_pod)
+    E_local = held // (n_group * n_pod)
     n_tp = info.axis_size(info.tp_axis) if tp_ffn else 1
 
     batch_ax = ((info.pod_axis, info.data_axis) if info.pod_axis
@@ -227,7 +307,7 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
                P(e_dim, d_axis, f_axis),      # wu
                P(e_dim, f_axis, d_axis))      # wd
 
-    def kernel(router, wg, wu, wd, xb):
+    def kernel(router, wg, wu, wd, xb, *bias):
         s_shard = xb.shape[1]
         tp_gather = tp_ffn and n_tp > 1 and seq_mode is not None
         if tp_gather:
@@ -257,9 +337,7 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
         with jax.named_scope("dcra.moe.router"):
             logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
                                 router.astype(jnp.float32))
-            probs = jax.nn.softmax(logits, axis=-1)
-            gates, eids = jax.lax.top_k(probs, mc.top_k)    # [T_l, K]
-            gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+            gates, eids, probs = gate(logits, mc, *bias)    # [T_l, K]
             K = mc.top_k
             eids_f = eids.reshape(-1)
             gates_f = gates.reshape(-1).astype(jnp.float32)
@@ -267,13 +345,20 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
         plan = slot_plan(mc, info, T_l, queues)
 
         with jax.named_scope("dcra.moe.dispatch"):
-            owner = eids_f // E_local                       # global shard id
-            all_valid = jnp.ones_like(eids_f, dtype=bool)
+            if held == E:
+                local = eids_f
+                owner = local // E_local                    # global shard id
+                valid = jnp.ones_like(eids_f, dtype=bool)
+            else:   # tasks of experts held elsewhere stay undispatched
+                local = eids_f - first
+                valid = (local >= 0) & (local < held)
+                local = jnp.where(valid, local, 0)
+                owner = local // E_local
             if not spans_pods:
                 # ---- single-stage fused a2a (tile-NoC) -----------------
                 _, (eid1, tok1), slot_of_task, _ = _bucket(
-                    src_f[:, None] * 0, owner, all_valid,
-                    [eids_f % E_local, src_f], n_ex, plan.cap1, impl=impl)
+                    src_f[:, None] * 0, owner, valid,
+                    [local % E_local, src_f], n_ex, plan.cap1, impl=impl)
                 xb1 = gather_rows(xf, tok1)
                 xr, (eidr,) = fused_all_to_all(xb1, [eid1], group)
             else:
@@ -281,8 +366,8 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
                 e_coord = owner % n_ex
                 p_coord = owner // n_ex
                 _, (pc1, eid1, tok1), slot_of_task, _ = _bucket(
-                    src_f[:, None] * 0, e_coord, all_valid,
-                    [p_coord, eids_f % E_local, src_f], n_ex, plan.cap1,
+                    src_f[:, None] * 0, e_coord, valid,
+                    [p_coord, local % E_local, src_f], n_ex, plan.cap1,
                     impl=impl)
                 xb1 = gather_rows(xf, tok1)
                 xs1, (pcs, eids1) = fused_all_to_all(xb1, [pc1, eid1], group)
@@ -331,12 +416,24 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
                                    slot1_of_s2 >= 0, n1)
                 yb1 = _a2a(y1, group)                # back to source
 
-            # combine at the source: task slot -> token, weighted by gate
-            task_y = jnp.where(
-                (slot_of_task >= 0)[:, None],
-                yb1[jnp.maximum(slot_of_task, 0)], 0.0).astype(jnp.float32)
-            out = jax.ops.segment_sum(task_y * gates_f[:, None], src_f,
-                                      num_segments=T_l)
+            # combine at the source, weighted by gate: read the fewer of
+            # the tasks and the returned slots (an expert share leaves
+            # most tasks undispatched)
+            if plan.dispatch_slots < T_l * K:
+                n_slots = yb1.shape[0]
+                kept = slot_of_task >= 0
+                gate1 = _slot_scatter(gates_f, jnp.maximum(slot_of_task, 0),
+                                      kept, n_slots)
+                out = jax.ops.segment_sum(
+                    yb1.astype(jnp.float32) * gate1[:, None],
+                    jnp.maximum(tok1, 0), num_segments=T_l)
+            else:
+                task_y = jnp.where(
+                    (slot_of_task >= 0)[:, None],
+                    yb1[jnp.maximum(slot_of_task, 0)],
+                    0.0).astype(jnp.float32)
+                out = jax.ops.segment_sum(task_y * gates_f[:, None], src_f,
+                                          num_segments=T_l)
 
             # aux: load-balance loss, averaged over all devices
             frac = jax.nn.one_hot(eids, E, dtype=jnp.float32).sum(1).mean(0)
@@ -352,9 +449,12 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
                                                    s_shard, axis=1)
         return out, aux
 
+    bias = ((params["router_bias"],) if "router_bias" in params else ())
     fn = shard_map_unchecked(kernel, mesh=info.mesh,
-                             in_specs=(*w_specs, x_spec),
+                             in_specs=(*w_specs, x_spec) + (P(None),) * len(bias),
                              out_specs=(x_spec, P()))
     out, aux = fn(params["router"], params["wg"], params["wu"], params["wd"],
-                  x)
+                  x, *bias)
+    if mc.n_shared:
+        out = out + shared_expert(params, x)
     return out, aux

@@ -23,41 +23,48 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig, MoEConfig
+from ..core.dispatch import gate, shared_expert
 from .common import dense_init, shard, swiglu
 
 GROUP_SIZE = 1024  # tokens per dispatch group (DCRA: per-tile task batch)
 
 
 def init_moe(key, cfg: ArchConfig):
+    """Router [D, E], experts ``wg``/``wu`` [E, D, F] and ``wd`` [E, F, D];
+    a zero ``router_bias`` [E] for sigmoid scoring, and the shared experts'
+    ``shared_wg``/``shared_wu`` [D, Fs], ``shared_wd`` [Fs, D] with
+    ``Fs = n_shared * d_shared``."""
     mc = cfg.moe
     assert mc is not None
     d, e, f = cfg.d_model, mc.num_experts, mc.d_expert
     ks = jax.random.split(key, 4)
-    return {
+    params = {
         "router": dense_init(ks[0], d, (e,), scale=0.1),
         "wg": _expert_init(ks[1], e, d, f),
         "wu": _expert_init(ks[2], e, d, f),
         "wd": _expert_init(ks[3], e, f, d),
     }
+    if mc.scoring == "sigmoid":
+        params["router_bias"] = jnp.zeros((e,), jnp.float32)
+    if mc.n_shared:
+        fs = mc.n_shared * mc.d_shared
+        kg, ku, kd = jax.random.split(jax.random.fold_in(key, 1), 3)
+        params.update(shared_wg=dense_init(kg, d, (fs,)),
+                      shared_wu=dense_init(ku, d, (fs,)),
+                      shared_wd=dense_init(kd, fs, (d,)))
+    return params
 
 
 def _expert_init(key, e, din, dout):
     return jax.random.normal(key, (e, din, dout)) * (din ** -0.5)
 
 
-def router_probs(params, x, mc: MoEConfig):
-    """x [G, T, D] -> probs [G, T, E] (fp32)."""
-    logits = jnp.einsum("gtd,de->gte", x.astype(jnp.float32),
+def route(params, x, mc: MoEConfig):
+    """x [..., D] -> (gates [..., K], expert ids [..., K], probs [..., E]):
+    the float32 router and :func:`repro.core.dispatch.gate`."""
+    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
                         params["router"].astype(jnp.float32))
-    return jax.nn.softmax(logits, axis=-1), logits
-
-
-def _topk_mask(probs, k):
-    """-> gates [G,T,K], expert one-hot [G,T,K,E]."""
-    vals, idx = jax.lax.top_k(probs, k)
-    vals = vals / jnp.maximum(vals.sum(-1, keepdims=True), 1e-9)  # renorm
-    onehot = jax.nn.one_hot(idx, probs.shape[-1], dtype=jnp.float32)
-    return vals, onehot
+    return gate(logits, mc, params.get("router_bias"))
 
 
 def capacity(group_tokens: int, mc: MoEConfig) -> int:
@@ -66,8 +73,15 @@ def capacity(group_tokens: int, mc: MoEConfig) -> int:
 
 
 def moe_einsum(params, x, cfg: ArchConfig) -> Tuple[jax.Array, jax.Array]:
-    """Dense-mask dispatch. x [B, S, D] -> (out [B,S,D], aux loss [])."""
+    """Dense-mask dispatch. x [B, S, D] -> (out [B,S,D], aux loss []).
+
+    It computes the whole layer: weights of a share of the experts (as
+    ``moe_dcra`` takes with ``MeshInfo.expert_share``) raise."""
     mc = cfg.moe
+    if params["wg"].shape[0] != mc.num_experts:
+        raise ValueError(
+            f"moe_einsum needs all {mc.num_experts} experts, got the "
+            f"weights of {params['wg'].shape[0]}")
     B, S, D = x.shape
     T = B * S
     g_size = min(GROUP_SIZE, T)
@@ -75,8 +89,8 @@ def moe_einsum(params, x, cfg: ArchConfig) -> Tuple[jax.Array, jax.Array]:
     xg = x.reshape(G, g_size, D)
     xg = shard(xg, "act_group", None, "act_embed")
 
-    probs, logits = router_probs(params, xg, mc)            # [G,T,E]
-    gates, onehot = _topk_mask(probs, mc.top_k)             # [G,T,K],[G,T,K,E]
+    gates, eids, probs = route(params, xg, mc)              # [G,T,K],[G,T,E]
+    onehot = jax.nn.one_hot(eids, mc.num_experts, dtype=jnp.float32)
     C = capacity(g_size, mc)
 
     # queue position of each (token, k) task within its expert queue
@@ -99,7 +113,10 @@ def moe_einsum(params, x, cfg: ArchConfig) -> Tuple[jax.Array, jax.Array]:
     out = jnp.einsum("gecd,gtec->gtd", ye, combine.astype(x.dtype))
 
     aux = load_balance_loss(probs, onehot)
-    return out.reshape(B, S, D), aux
+    out = out.reshape(B, S, D)
+    if mc.n_shared:
+        out = out + shared_expert(params, x)
+    return out, aux
 
 
 def load_balance_loss(probs, onehot) -> jax.Array:

@@ -56,6 +56,29 @@ with set_mesh(mesh2):
         params8, x)
 res['grads_finite'] = all(bool(jnp.isfinite(v).all())
                           for v in jax.tree.leaves(g))
+
+# expert shares: sigmoid group-limited routing over 32 experts, a shared
+# expert; two meshes each holding 16 experts add up to the whole layer
+from repro.core.dispatch import shared_expert
+cfgs = dataclasses.replace(cfg, moe=dataclasses.replace(
+    cfg.moe, num_experts=32, top_k=4, scoring='sigmoid', n_group=8,
+    topk_group=4, routed_scaling_factor=2.5, n_shared=1, d_shared=48))
+routed_only = dataclasses.replace(cfgs, moe=dataclasses.replace(
+    cfgs.moe, n_shared=0))
+params_s = init_moe(jax.random.key(4), cfgs)
+params_s['router_bias'] = 0.05 * jax.random.normal(jax.random.key(5), (32,))
+with jax.default_matmul_precision('highest'):
+    whole, _ = moe_einsum(params_s, x, cfgs)
+    for case, m, pod in [('share_flat', mesh, None), ('share_pods', mesh2, 'pod')]:
+        parts = shared_expert(params_s, x)
+        for first in (0, 16):
+            ps = dict(params_s, **{k: params_s[k][first:first + 16]
+                                   for k in ('wg', 'wu', 'wd')})
+            info_s = MeshInfo(m, pod_axis=pod, expert_share=(first, 16))
+            with set_mesh(m):
+                parts = parts + jax.jit(lambda p, x: moe_dcra(
+                    p, x, routed_only, info_s))(ps, x)[0]
+        res[case] = float(jnp.max(jnp.abs(parts - whole)))
 print('RESULT ' + json.dumps(res))
 """
 
@@ -85,3 +108,8 @@ def test_hierarchical_two_stage_matches_einsum(results):
 
 def test_gradients_flow(results):
     assert results["grads_finite"]
+
+
+@pytest.mark.parametrize("case", ["share_flat", "share_pods"])
+def test_expert_shares_add_up_to_the_whole_layer(results, case):
+    assert results[case] < 1e-4

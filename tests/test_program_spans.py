@@ -141,15 +141,19 @@ def graph(app, round_mode, route_impl, shape, names, pod_axis):
     return scopes(text)
 
 
-def moe(n_experts, shape, names, pod_axis):
+def moe(n_experts, shape, names, pod_axis, share=None, **moe_fields):
     cfg = get_config("olmoe-1b-7b").reduced()
     cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
-        cfg.moe, num_experts=n_experts, top_k=1))
-    info = MeshInfo(make_mesh(shape, names), pod_axis=pod_axis)
+        cfg.moe, num_experts=n_experts, top_k=1, **moe_fields))
+    info = MeshInfo(make_mesh(shape, names), pod_axis=pod_axis,
+                    expert_share=share)
+    params = init_moe(jax.random.key(0), cfg)
+    if share:
+        params.update({k: params[k][share[0]:sum(share)]
+                       for k in ("wg", "wu", "wd")})
     x = jnp.ones((2, 8, cfg.d_model), jnp.float32)
     return scopes(jax.jit(lambda p, x: moe_dcra(p, x, cfg, info)).lower(
-        init_moe(jax.random.key(0), cfg), x).as_text(dialect="hlo",
-                                                      debug_info=True))
+        params, x).as_text(dialect="hlo", debug_info=True))
 
 
 flat, pods = ((4,), ("data",), None), ((2, 2), ("pod", "data"), "pod")
@@ -171,6 +175,9 @@ res = {
     "moe": moe(8, *moe_mesh),
     "moe-one-expert-per-shard": moe(4, *moe_mesh),
     "moe-pods": moe(8, *moe_pods),
+    "moe-share-shared-expert": moe(16, *moe_mesh, share=(8, 8),
+                                   scoring="sigmoid", n_group=4, topk_group=2,
+                                   n_shared=1, d_shared=32),
 }
 res["local_fold_builds"] = [[list(k[:3]) + [list(k[3])], v]
                             for k, v in FOLDS.items()]
@@ -197,6 +204,8 @@ EXPECTED_SCOPES = {
     # one expert per shard: no per-expert bucket to pad
     "moe-one-expert-per-shard": EVERY_MOE - {"dcra.moe.expert_pad"},
     "moe-pods": EVERY_MOE,
+    # a share of sigmoid-routed experts with a shared expert beside them
+    "moe-share-shared-expert": EVERY_MOE | {"dcra.moe.shared"},
 }
 
 
