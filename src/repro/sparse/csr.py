@@ -8,7 +8,15 @@ import numpy as np
 
 @dataclass
 class CSR:
-    """Compressed Sparse Row: three arrays, no partitioning (paper §IV-A)."""
+    """Compressed Sparse Row: three arrays, no partitioning (paper §IV-A).
+
+    A launched graph is resident: the first graph-program launch on a CSR
+    packs its edges and keeps them, on the host and on the fabric, for
+    every later launch on the same object
+    (:func:`repro.sparse.program.packed_graph`). Packing marks the three
+    arrays read-only, so an in-place edit raises; a changed graph is a new
+    ``CSR`` (or a field assigned a new array), which packs again.
+    """
     row_ptr: np.ndarray   # [V+1] int64
     col_idx: np.ndarray   # [E] int32
     values: np.ndarray    # [E] float32 (edge weights / nonzeros)

@@ -13,7 +13,8 @@ reduce op, frontier-update rule, convergence predicate, task class — and
   launch :class:`~repro.core.fabric.Fabric`);
 * flat vs pod/portal path selection (iterative apps route hierarchically
   now, not just the one-round scatters);
-* the cyclic owner layout pack/unpack;
+* the cyclic owner layout pack/unpack, with each graph's edges packed
+  once and kept resident (see :func:`packed_graph`);
 * the one-round vs ``lax.while_loop`` / ``lax.fori_loop`` execution shape
   with per-round :class:`AppStats`;
 * a **compile cache** keyed by (program, shapes, mesh, capacities) so
@@ -34,6 +35,7 @@ message/drop agreement.
 from __future__ import annotations
 
 import itertools
+import weakref
 from dataclasses import dataclass
 from typing import Callable, Dict, Mapping, Optional, Tuple
 
@@ -42,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from jax.profiler import TraceAnnotation
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.compat import shard_map_unchecked
 from ..core.fabric import Fabric, as_fabric
@@ -249,6 +251,79 @@ def _graph_setup(g, n_dev, undirected=False, seed=0):
 
 
 # ---------------------------------------------------------------------------
+# resident packed graphs: one pack per (graph, device count), kept
+# ---------------------------------------------------------------------------
+
+class PackedGraph:
+    """A graph's edges packed for ``n_dev`` devices, kept while the graph
+    lives.
+
+    The host part is the :func:`_graph_setup` result (``n_local``,
+    ``src_slot`` / ``dst`` / ``w``, ``E_max``), which the analytic twin
+    reads. The device part is the same three arrays placed on a fabric's
+    mesh, once per (fabric, partition spec): every launch hands them to
+    the jitted call as its edge arguments, which are never donated. Every
+    caller shares the host arrays, so they are read-only.
+    """
+
+    def __init__(self, setup):
+        self.n_local, self.src_slot, self.dst, self.w, self.E_max = setup
+        for a in (self.src_slot, self.dst, self.w):
+            a.setflags(write=False)
+        self._placed: Dict[tuple, tuple] = {}
+
+    def edges_on(self, fab: Fabric, spec) -> tuple:
+        """``(src_slot, dst, w)`` laid out on ``fab`` under ``spec``."""
+        key = (fab.fabric_key(), spec)
+        got = self._placed.get(key)
+        if got is None:
+            if fab.is_multiprocess:
+                got = tuple(_to_global(fab, spec, a)
+                            for a in (self.src_slot, self.dst, self.w))
+            else:
+                sharding = NamedSharding(fab.mesh, spec)
+                got = tuple(jax.device_put(a, sharding)
+                            for a in (self.src_slot, self.dst, self.w))
+            self._placed[key] = got
+        return got
+
+
+# (graph id, n_dev, undirected, seed) -> (weakref to the graph, its
+# (row_ptr, col_idx, values), PackedGraph). A lookup is a hit only when
+# the referent IS the argument and its three arrays are the same objects,
+# so neither id() reuse nor a reassigned field serves a stale pack; the
+# weakref callback purges a collected graph's entries.
+_PACKED: Dict[tuple, tuple] = {}
+
+
+def packed_graph(g, n_dev: int, undirected: bool = False,
+                 seed: int = 0) -> PackedGraph:
+    """The resident :class:`PackedGraph` of ``g`` for ``n_dev`` devices,
+    packed on first use (``CACHE_STATS["graph_packs"]``) and found again
+    on every later one (``["graph_pack_hits"]``).
+
+    Packing marks the graph's three arrays read-only, so an in-place edit
+    of a packed graph raises instead of leaving its pack stale: a changed
+    graph is a new ``CSR``.
+    """
+    key = (id(g), n_dev, bool(undirected), seed)
+    fields = (g.row_ptr, g.col_idx, g.values)
+    got = _PACKED.get(key)
+    if (got is not None and got[0]() is g
+            and all(a is b for a, b in zip(got[1], fields))):
+        CACHE_STATS["graph_pack_hits"] += 1
+        return got[2]
+    CACHE_STATS["graph_packs"] += 1
+    for a in fields:
+        a.setflags(write=False)
+    pg = PackedGraph(_graph_setup(g, n_dev, undirected=undirected,
+                                  seed=seed))
+    ref = weakref.ref(g, lambda _r, _k=key: _PACKED.pop(_k, None))
+    _PACKED[key] = (ref, fields, pg)
+    return pg
+
+
+# ---------------------------------------------------------------------------
 # launch resolution (config= / kwargs conflicts) — shared by every app
 # ---------------------------------------------------------------------------
 
@@ -323,7 +398,6 @@ def _to_global(fab: Fabric, spec, arr):
     if not fab.is_multiprocess:
         return jnp.asarray(arr)
     from jax import make_array_from_callback
-    from jax.sharding import NamedSharding
     a = np.asarray(arr)
     return make_array_from_callback(
         a.shape, NamedSharding(fab.mesh, spec), lambda idx: a[idx])
@@ -345,7 +419,8 @@ def _host_gather(fab: Fabric, x):
 
 _CACHE: Dict[tuple, Callable] = {}
 CACHE_STATS = {"hits": 0, "misses": 0, "kernel_traces": 0,
-               "local_fold_builds": 0}
+               "local_fold_builds": 0, "graph_packs": 0,
+               "graph_pack_hits": 0}
 
 
 def cache_stats() -> Dict[str, int]:
@@ -353,12 +428,18 @@ def cache_stats() -> Dict[str, int]:
     same-shape launch must be a ``hits`` increment with ``kernel_traces``
     unchanged — no re-trace). ``local_fold_builds`` counts the graph
     callables built with the one-device local fold (see
-    :func:`_build_graph_fn`)."""
+    :func:`_build_graph_fn`). ``graph_packs`` counts the resident
+    :class:`PackedGraph` handles built and ``graph_pack_hits`` the
+    lookups that found one (see :func:`packed_graph`): N jobs on one
+    graph read 1 and N - 1."""
     return dict(CACHE_STATS)
 
 
 def clear_cache() -> None:
+    """Empty the compile cache and the packed-graph memo, and zero the
+    counters."""
     _CACHE.clear()
+    _PACKED.clear()
     for k in CACHE_STATS:
         CACHE_STATS[k] = 0
 
@@ -728,10 +809,18 @@ def _launch_graph(prog: TaskProgram, g, fab: Fabric,  # noqa: PLR0917
     dispatch — returning the :class:`ProgramLaunch` device future
     *without* waiting on the result.
 
+    The graph's edges are resident: the first launch on a graph packs
+    them (:func:`packed_graph`) and places them on the fabric, and every
+    later launch on the same graph object, seed and fabric hands the jitted
+    call the device arrays already there. What each launch still does is
+    the V-sized work: the initial state, packed and uploaded.
+
     The host work is three spans: ``dcra.graph.pack`` (launch resolution,
-    edge and state packing), ``dcra.graph.upload`` (host arrays onto the
-    fabric) and ``dcra.graph.dispatch`` (compile-cache lookup and the
-    jitted call, which returns once the computation is enqueued)."""
+    the packed-graph lookup, which packs the edges on a miss, and state
+    packing), ``dcra.graph.upload`` (the edges' placement on a miss, the
+    states onto the fabric) and ``dcra.graph.dispatch`` (compile-cache
+    lookup and the jitted call, which returns once the computation is
+    enqueued)."""
     axis, pod_axis, queues = opts.axis, opts.pod_axis, opts.queues
     cap, capacity_factor = opts.cap, opts.capacity_factor
     seed, route_impl = opts.seed, opts.route_impl
@@ -742,8 +831,8 @@ def _launch_graph(prog: TaskProgram, g, fab: Fabric,  # noqa: PLR0917
     with TraceAnnotation("dcra.graph.pack", launch=launch):
         lc = resolve_launch(opts.config, g if dataset is None else dataset,
                             prog.name, opts.objective)
-        n_local, src_slot, dst, w, E_max = _graph_setup(
-            g, n_dev, undirected=prog.undirected, seed=seed)
+        pg = packed_graph(g, n_dev, undirected=prog.undirected, seed=seed)
+        n_local, E_max = pg.n_local, pg.E_max
         if lc is not None:
             pod_axis = (pod_axis if pod_axis is not None
                         else lc.pod_axis_for(fab))
@@ -781,8 +870,8 @@ def _launch_graph(prog: TaskProgram, g, fab: Fabric,  # noqa: PLR0917
         key = key + ("donate",)
     spec = P((pod_axis, axis)) if pod_axis else P(axis)
     with TraceAnnotation("dcra.graph.upload", launch=launch):
-        args = [_to_global(fab, spec, a)
-                for a in (src_slot, dst, w) + packed]
+        args = [*pg.edges_on(fab, spec),
+                *(_to_global(fab, spec, a) for a in packed)]
     with TraceAnnotation("dcra.graph.dispatch", launch=launch):
         fn = _cached(key, lambda: _build_graph_fn(
             prog, fab.mesh, axis, pod_axis, pods, n_dev, n_local, n, caps,
@@ -1082,21 +1171,23 @@ def _hier_keep(dev_of, owner, active, caps, pods):  # noqa: PLR0917
 
 def program_rounds(prog: TaskProgram, g, n_dev, caps,  # noqa: PLR0917
                    params=None, seed=0,
-                   pods=None, max_rounds=None, setup=None):
+                   pods=None, max_rounds=None, packed=None):
     """Host mirror of :func:`run_program`'s round loop for a graph
     program: yields, per executable round, the routed task stream
     ``(src_global, dst_global, n_drop)`` — *all* active tasks, with the
     drop count of the first-``cap``-per-channel keep rule — while
     evolving vertex state with kept-only updates, exactly as the
-    shard_map path does. Deterministic: shares ``_pack_edges`` (and its
-    admission order) with the executable. ``setup`` short-circuits the
-    edge packing with a precomputed ``_graph_setup`` result.
+    shard_map path does. Deterministic: reads the executable's own
+    :class:`PackedGraph` (and so its admission order); ``packed`` passes
+    one already looked up.
     """
     params = dict(params or {})
     n = g.n
-    n_local, src_slot, dst, w, E_max = (
-        setup if setup is not None
-        else _graph_setup(g, n_dev, undirected=prog.undirected, seed=seed))
+    if packed is None:
+        packed = packed_graph(g, n_dev, undirected=prog.undirected,
+                              seed=seed)
+    n_local, src_slot, dst, w, E_max = (packed.n_local, packed.src_slot,
+                                        packed.dst, packed.w, packed.E_max)
     dev_of = np.repeat(np.arange(n_dev), E_max)
     evalid = dst >= 0
     dstl = dst.astype(np.int64)
@@ -1187,8 +1278,9 @@ def program_app_stats(prog: TaskProgram, data, n_dev, *,
                         drops=np.array([n_drop], np.int64))
 
     # graph program: mirror the rounds, replay flat rounds through route()
-    setup = _graph_setup(data, n_dev, undirected=prog.undirected, seed=seed)
-    caps = _graph_caps(queues, prog.task, setup[-1], n_dev, pods)
+    packed = packed_graph(data, n_dev, undirected=prog.undirected,
+                          seed=seed)
+    caps = _graph_caps(queues, prog.task, packed.E_max, n_dev, pods)
     msgs, drops = [], []
     engine = None
     if pods is None:
@@ -1198,7 +1290,7 @@ def program_app_stats(prog: TaskProgram, data, n_dev, *,
     for src, dst, n_drop in program_rounds(prog, data, n_dev, caps,
                                            params=params, seed=seed,
                                            pods=pods, max_rounds=max_rounds,
-                                           setup=setup):
+                                           packed=packed):
         if engine is not None:
             rs = engine.route(prog.task, src_idx=src, dst_idx=dst)
             assert rs.drops == n_drop, (rs.drops, n_drop)  # model coherence

@@ -230,3 +230,198 @@ def test_repeated_launches_hit_the_compile_cache(results):
     assert repeat["kernel_traces"] == first["kernel_traces"]
     # a different deployment is a genuine miss, not a stale reuse
     assert other["misses"] == repeat["misses"] + 1
+
+
+# ---------------------------------------------------------------------------
+# Part C: resident packed graphs (subprocess, 8 fake host devices)
+# ---------------------------------------------------------------------------
+
+RESIDENT_SCRIPT = r"""
+import os
+os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
+import gc
+import json
+import jax
+import numpy as np
+from repro.core.fabric import Fabric
+from repro.sparse import datasets, program
+from repro.sparse.csr import CSR
+from repro.sparse.jax_apps import BFS, PAGERANK, SSSP, WCC
+from repro.sparse.program import (launch_program, program_app_stats,
+                                  run_program)
+
+g = datasets.rmat(8, edge_factor=8, seed=5)
+FABRICS = {'one': Fabric.single((1,), ('data',)), 'fake4': Fabric.fake(4)}
+ROOTS = [int(v) for v in np.argsort(-g.degrees(), kind='stable')[:3]]
+APPS = {'bfs': (BFS, [{'root': r} for r in ROOTS]),
+        'pagerank': (PAGERANK, [{'damping': 0.85, 'iters': 4}] * 3)}
+
+
+def same(a, b):
+    (sa, ta), (sb, tb) = a, b
+    return (len(sa) == len(sb)
+            and all(x.tobytes() == y.tobytes() for x, y in zip(sa, sb))
+            and ta.rounds == tb.rounds
+            and np.array_equal(ta.messages, tb.messages)
+            and np.array_equal(ta.drops, tb.drops))
+
+
+def copy_of(h):
+    return CSR(h.row_ptr.copy(), h.col_idx.copy(), h.values.copy())
+
+
+def packs(fn):
+    before = program.cache_stats()['graph_packs']
+    out = fn()
+    return program.cache_stats()['graph_packs'] - before, out
+
+
+res = {'resident': {}, 'repack': {}, 'twin': {}}
+
+# ---- N launches on one graph: 1 pack, N - 1 hits, cold-pack results ----
+for fname, fab in FABRICS.items():
+    for app, (prog, plist) in APPS.items():
+        cold = []
+        for p in plist:
+            program.clear_cache()
+            cold.append(run_program(prog, g, fab, params=p))
+        program.clear_cache()
+        warm = [launch_program(prog, g, fab, params=plist[0]).result()]
+        c1 = program.cache_stats()
+        warm += [launch_program(prog, g, fab, params=p).result()
+                 for p in plist[1:]]
+        c = program.cache_stats()
+        res['resident'][f'{fname}-{app}'] = {
+            'n': len(plist), 'packs': c['graph_packs'],
+            'hits': c['graph_pack_hits'],
+            'traces_on_hits': c['kernel_traces'] - c1['kernel_traces'],
+            'identical': all(same(a, b) for a, b in zip(cold, warm))}
+
+# ---- what packs again ---------------------------------------------------
+one = FABRICS['one']
+program.clear_cache()
+h = copy_of(g)
+run_program(BFS, h, one, params={'root': 0})
+rp = res['repack']
+rp['same'] = packs(lambda: run_program(BFS, h, one, params={'root': 1}))[0]
+rp['new_csr'] = packs(lambda: run_program(
+    BFS, CSR(h.row_ptr, h.col_idx, h.values), one, params={'root': 0}))[0]
+rp['seed'] = packs(lambda: run_program(BFS, h, one, params={'root': 0},
+                                       seed=1))[0]
+rp['undirected'] = packs(lambda: run_program(WCC, h, one))[0]
+rp['fabric'] = packs(lambda: run_program(BFS, h, FABRICS['fake4'],
+                                         params={'root': 0}))[0]
+# the same device count on other devices: one host pack, placed twice
+other4 = Fabric.single((4,), ('data',), devices=jax.devices()[4:8])
+n_other, got = packs(lambda: run_program(BFS, h, other4,
+                                         params={'root': 0}))
+res['other_devices'] = {
+    'packs': n_other,
+    'placements': len(program.packed_graph(h, 4)._placed),
+    'identical': same(got, run_program(BFS, copy_of(h), FABRICS['fake4'],
+                                       params={'root': 0}))}
+run_program(SSSP, h, one, params={'root': 0})
+h.values = h.values * 3.0
+n_re, got = packs(lambda: run_program(SSSP, h, one, params={'root': 0}))
+rp['reassigned_field'] = n_re
+rp['reassigned_fresh'] = same(got, run_program(SSSP, copy_of(h), one,
+                                               params={'root': 0}))
+
+# ---- read-only once packed ----------------------------------------------
+res['read_only'] = {}
+for field in ('row_ptr', 'col_idx', 'values'):
+    try:
+        getattr(h, field)[0] = 1
+        res['read_only'][field] = False
+    except ValueError:
+        res['read_only'][field] = True
+
+# ---- memo lifetime ------------------------------------------------------
+h3 = copy_of(g)
+run_program(BFS, h3, one, params={'root': 0})
+k3 = id(h3)
+had = any(key[0] == k3 for key in program._PACKED)
+del h3
+gc.collect()
+res['lifetime'] = {'collected': had and not any(key[0] == k3
+                                                for key in program._PACKED)}
+had = len(program._PACKED) > 0
+program.clear_cache()
+res['lifetime']['cleared'] = had and not program._PACKED
+
+# ---- the analytic twin reads the launch's handle ------------------------
+fab4 = FABRICS['fake4']
+for app, (prog, plist) in APPS.items():
+    p = plist[0]
+    program.clear_cache()
+    cold = program_app_stats(prog, g, 4, cap=2, params=p)
+    program.clear_cache()
+    _, st = run_program(prog, g, fab4, cap=2, params=p)
+    c0 = program.cache_stats()
+    twin = program_app_stats(prog, g, 4, cap=2, params=p)
+    c1 = program.cache_stats()
+    res['twin'][app] = {
+        'unchanged': same(((), cold), ((), twin)),
+        'matches_launch': same(((), st), ((), twin)),
+        'drops': twin.total_drops,
+        'new_packs': c1['graph_packs'] - c0['graph_packs'],
+        'new_hits': c1['graph_pack_hits'] - c0['graph_pack_hits']}
+print('RESULT ' + json.dumps(res))
+"""
+
+
+@pytest.fixture(scope="module")
+def resident():
+    env = dict(os.environ)
+    env["PYTHONPATH"] = SRC
+    out = subprocess.run([sys.executable, "-c", RESIDENT_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=900)
+    assert out.returncode == 0, out.stderr[-3000:]
+    line = [l for l in out.stdout.splitlines() if l.startswith("RESULT ")][0]
+    return json.loads(line[len("RESULT "):])
+
+
+@pytest.mark.parametrize("fabric", ["one", "fake4"])
+@pytest.mark.parametrize("app", ["bfs", "pagerank"])
+def test_launches_on_one_graph_pack_once(resident, fabric, app):
+    """N launches: one pack, N - 1 hits, no re-trace, and every state and
+    AppStats bit-identical to a launch that packed cold."""
+    r = resident["resident"][f"{fabric}-{app}"]
+    assert r["packs"] == 1
+    assert r["hits"] == r["n"] - 1
+    assert r["traces_on_hits"] == 0
+    assert r["identical"]
+
+
+@pytest.mark.parametrize("change", ["new_csr", "reassigned_field", "seed",
+                                    "undirected", "fabric"])
+def test_a_changed_input_packs_again(resident, change):
+    rp = resident["repack"]
+    assert rp["same"] == 0
+    assert rp[change] == 1
+    if change == "reassigned_field":
+        assert rp["reassigned_fresh"]       # the new weights, not stale
+
+
+def test_other_devices_reuse_the_host_pack(resident):
+    r = resident["other_devices"]
+    assert r["packs"] == 0 and r["placements"] == 2 and r["identical"]
+
+
+@pytest.mark.parametrize("field", ["row_ptr", "col_idx", "values"])
+def test_in_place_write_to_a_launched_graph_raises(resident, field):
+    assert resident["read_only"][field]
+
+
+@pytest.mark.parametrize("how", ["collected", "cleared"])
+def test_memo_holds_no_dead_or_cleared_graph(resident, how):
+    assert resident["lifetime"][how]
+
+
+@pytest.mark.parametrize("app", ["bfs", "pagerank"])
+def test_twin_reuses_the_launch_handle(resident, app):
+    t = resident["twin"][app]
+    assert t["unchanged"] and t["matches_launch"]
+    assert t["new_packs"] == 0 and t["new_hits"] == 1
+    if app == "bfs":
+        assert t["drops"] > 0               # cap=2 bites: not vacuous
