@@ -32,6 +32,7 @@ from repro.core.compat import make_mesh                      # noqa: E402
 from repro.sparse import datasets, ref                       # noqa: E402
 from repro.sparse.jax_apps import (dcra_bfs, dcra_histogram,  # noqa: E402
                                    dcra_spmv)
+from repro.sparse.options import LaunchOptions               # noqa: E402
 
 from .common import emit                                     # noqa: E402
 
@@ -57,8 +58,9 @@ def die_crossings(dest, n_dev, n_pods):
     return rs.die_crossings
 
 
-def _bfs_stats(g, mesh, **kw):
-    d, st = dcra_bfs(g, 0, mesh, capacity_factor=4.0, **kw)
+def _bfs_stats(g, mesh, pod_axis=None):
+    d, st = dcra_bfs(g, 0, mesh, options=LaunchOptions(
+        pod_axis=pod_axis, capacity_factor=4.0))
     return d.astype(np.float64), st.total_drops
 
 
@@ -70,16 +72,17 @@ def main(scale: int = 11, n_dev: int = 8, n_pods: int = 2):
     x = np.random.default_rng(0).random(g.n)
     els = datasets.histogram_data(1 << 16, 1 << 10)
 
+    one = LaunchOptions(capacity_factor=3.0)
+    two = one.with_(pod_axis="pod")
     rows = []
     for name, fn_flat, fn_hier, oracle in (
         ("spmv",
-         lambda: dcra_spmv(g, x, flat, capacity_factor=3.0),
-         lambda: dcra_spmv(g, x, hier, pod_axis="pod", capacity_factor=3.0),
+         lambda: dcra_spmv(g, x, flat, options=one),
+         lambda: dcra_spmv(g, x, hier, options=two),
          ref.spmv_ref(g, x)),
         ("histogram",
-         lambda: dcra_histogram(els, 1 << 10, flat, capacity_factor=3.0),
-         lambda: dcra_histogram(els, 1 << 10, hier, pod_axis="pod",
-                                capacity_factor=3.0),
+         lambda: dcra_histogram(els, 1 << 10, flat, options=one),
+         lambda: dcra_histogram(els, 1 << 10, hier, options=two),
          ref.histogram_ref(els, 1 << 10)),
         # iterative TaskPrograms route hierarchically too: every
         # while_loop round re-enters the two-stage pod/portal collective

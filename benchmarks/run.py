@@ -35,10 +35,6 @@ def main() -> None:
                          "fig11_scaling")]
     figs += [
         ("moe_dispatch", child("moe_dispatch", "")),
-        # wall-clock routing hot path -> BENCH_route.json (the committed
-        # baseline is the --quick grid; see repro.dse.route_compare)
-        ("route_bench", child("route_bench",
-                              "['--quick']" if args.quick else "[]")),
         # run as __main__: these two pick their own fake-device topology
         # before jax is imported
         ("noc_routing", [sys.executable, "-m", "benchmarks.noc_routing",

@@ -255,16 +255,19 @@ def multichip_phase(scale: int, devices) -> None:
     from repro.core.fabric import Fabric
     from repro.sparse import datasets, ref
     from repro.sparse.jax_apps import dcra_bfs, dcra_sssp, dcra_wcc
+    from repro.sparse.options import LaunchOptions
     g = datasets.rmat(scale, edge_factor=16)
     root = int(np.argmax(g.degrees()))
     fabrics = [("device0", Fabric.single((1,), ("data",)), None),
                ("flat(4,)", Fabric.single((4,), ("data",)), None),
                ("pod(2,2)", Fabric.single((2, 2), ("pod", "data")), "pod")]
-    apps = [("bfs", lambda fab, pa: dcra_bfs(g, root, fab, pod_axis=pa),
+    def on(pa):
+        return LaunchOptions(pod_axis=pa)
+    apps = [("bfs", lambda fab, pa: dcra_bfs(g, root, fab, options=on(pa)),
              lambda: ref.bfs_ref(g, root)),
-            ("sssp", lambda fab, pa: dcra_sssp(g, root, fab, pod_axis=pa),
+            ("sssp", lambda fab, pa: dcra_sssp(g, root, fab, options=on(pa)),
              lambda: ref.sssp_ref(g, root)),
-            ("wcc", lambda fab, pa: dcra_wcc(g, fab, pod_axis=pa),
+            ("wcc", lambda fab, pa: dcra_wcc(g, fab, options=on(pa)),
              lambda: ref.wcc_ref(g))]
     log(f"multichip: RMAT-{scale} V={g.n} E={g.nnz}")
     log(f"{'app':6s} {'fabric':9s} {'rounds':>6s} {'messages':>11s} "

@@ -43,6 +43,7 @@ def run_distributed(g, scale):
     from repro.sparse.jax_apps import (dcra_bfs, dcra_histogram,
                                        dcra_kcore, dcra_pagerank,
                                        dcra_spmv, dcra_sssp, dcra_wcc)
+    from repro.sparse.options import LaunchOptions
     n_dev = len(jax.devices())
     mesh = Fabric.single((n_dev,), ("data",))
     x = np.random.default_rng(0).random(g.n)
@@ -61,10 +62,11 @@ def run_distributed(g, scale):
               f"{stats.total_drops:7d} {err:10.2e}")
 
     from repro.sparse.jax_apps import AppStats
-    y, drops = dcra_spmv(g, x, mesh, capacity_factor=3.0)
+    wide = LaunchOptions(capacity_factor=3.0)
+    y, drops = dcra_spmv(g, x, mesh, options=wide)
     one = AppStats(1, np.array([g.nnz]), np.array([int(drops)]))
     row("spmv", y, ref.spmv_ref(g, x), one)
-    h, drops = dcra_histogram(els, 256, mesh, capacity_factor=3.0)
+    h, drops = dcra_histogram(els, 256, mesh, options=wide)
     one = AppStats(1, np.array([len(els)]), np.array([int(drops)]))
     row("histogram", h, ref.histogram_ref(els, 256), one)
     d, st = dcra_bfs(g, 0, mesh)
@@ -87,7 +89,8 @@ def run_distributed(g, scale):
     lc = autoconfigure(g, "bfs")
     print(f"auto-config (bfs, objective=teps): {lc.point.point_id} "
           f"[{lc.source}]")
-    d, st = dcra_bfs(g, 0, mesh, config=lc)   # reuse the resolved config
+    # reuse the resolved config
+    d, st = dcra_bfs(g, 0, mesh, options=LaunchOptions(config=lc))
     row("bfs[auto]", d, ref.bfs_ref(g, 0), st)
     print()
 

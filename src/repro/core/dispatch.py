@@ -32,7 +32,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from .compat import shard_map_unchecked
 from .queues import QueueConfig
 from .routing import (bucket as _bucket, fused_all_to_all, gather_rows,
-                      noc_all_to_all as _a2a, resolve_route_impl,
+                      noc_all_to_all as _a2a,
                       slot_scatter as _slot_scatter)
 
 
@@ -265,8 +265,6 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
     assert mc is not None
     if queues is None:
         queues = dispatch_queues(mc)
-    # the three bounded dispatch buckets share one routing engine
-    impl = resolve_route_impl(queues.route_impl)
     E = mc.num_experts
     first, held = info.expert_share or (0, E)
     group, spans_pods, tp_ffn = info.dispatch_plan(held)
@@ -358,7 +356,7 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
                 # ---- single-stage fused a2a (tile-NoC) -----------------
                 _, (eid1, tok1), slot_of_task, _ = _bucket(
                     src_f[:, None] * 0, owner, valid,
-                    [local % E_local, src_f], n_ex, plan.cap1, impl=impl)
+                    [local % E_local, src_f], n_ex, plan.cap1)
                 xb1 = gather_rows(xf, tok1)
                 xr, (eidr,) = fused_all_to_all(xb1, [eid1], group)
             else:
@@ -367,8 +365,7 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
                 p_coord = owner // n_ex
                 _, (pc1, eid1, tok1), slot_of_task, _ = _bucket(
                     src_f[:, None] * 0, e_coord, valid,
-                    [p_coord, local % E_local, src_f], n_ex, plan.cap1,
-                    impl=impl)
+                    [p_coord, local % E_local, src_f], n_ex, plan.cap1)
                 xb1 = gather_rows(xf, tok1)
                 xs1, (pcs, eids1) = fused_all_to_all(xb1, [pc1, eid1], group)
                 n1 = xs1.shape[0]
@@ -377,7 +374,7 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
                 _, (eid2, slot1_of_s2), _, _ = _bucket(
                     pcs[:, None] * 0, jnp.maximum(pcs, 0), valid1,
                     [eids1, jnp.arange(n1, dtype=jnp.int32)], n_pod,
-                    plan.cap2, impl=impl)
+                    plan.cap2)
                 xb2 = gather_rows(xs1, slot1_of_s2)
                 xr, (eidr,) = fused_all_to_all(xb2, [eid2], info.pod_axis)
 
@@ -396,8 +393,7 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
                 _, (srce,), _, _ = _bucket(
                     validr[:, None].astype(jnp.int32) * 0,
                     jnp.maximum(eidr, 0), validr,
-                    [jnp.arange(N_r, dtype=jnp.int32)], E_local, cap_e,
-                    impl=impl)
+                    [jnp.arange(N_r, dtype=jnp.int32)], E_local, cap_e)
                 xe = gather_rows(xr, srce).reshape(E_local, cap_e, D)
             with jax.named_scope("dcra.moe.expert_ffn"):
                 ye_b = _expert_ffn(xe.astype(xb.dtype), wg, wu, wd,

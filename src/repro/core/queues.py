@@ -46,12 +46,6 @@ class QueueConfig:
     # (the MoE "capacity factor" IS the IQ axis — ROADMAP fold-in). An
     # explicit per-task entry in ``iq_sizes`` always wins over a factor.
     iq_factors: Dict[str, float] = field(default_factory=dict)
-    # Routing hot-path engine: "pallas" | "sort" | "onehot" (None = the
-    # backend-autodetected fast path). Capacity *semantics* are identical
-    # across impls — this only picks how the executable ranks/scatters,
-    # so the analytic TaskEngine twin needs no matching knob. See
-    # repro.kernels.route.
-    route_impl: Optional[str] = None
 
     def iq(self, task: str) -> Optional[int]:
         """Explicit per-channel IQ capacity for ``task`` (None =

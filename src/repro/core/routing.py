@@ -5,7 +5,10 @@ Everything DCRA routes — MoE tokens to expert-owning tiles
 vertex-owning tiles (:mod:`repro.sparse.jax_apps`) — is the same motion:
 
   1. *bucket*: tasks are grouped by destination shard into capacity-bounded
-     buckets (the paper's input queue; overflow is dropped and counted);
+     buckets (the paper's input queue; overflow is dropped and counted),
+     ranked within their bucket by the one routing rank,
+     :func:`repro.kernels.route.bucket_rank`, and scattered into slot
+     order;
   2. *deliver*: ONE ``all_to_all`` per NoC round carries a *fused payload* —
      value columns are bitcast (bytes reinterpreted, never converted) to
      int32 and packed next to the int32 metadata columns, so index+value
@@ -40,12 +43,9 @@ import jax.numpy as jnp
 # Capacity helpers live with the queue-sizing source of truth; re-exported
 # here because every routing call site thinks in lane-aligned bucket sizes.
 from .queues import round8  # noqa: F401
-# The routing hot path has a kernel tier: `impl="pallas"` ranks
-# through repro.kernels.route (Mosaic on TPU, the same tiled algorithm in
-# plain XLA off-TPU); "sort" is the argsort fallback below; "onehot" is
-# the legacy O(N*S) rank. Re-exported so call sites resolve the knob once.
-from ..kernels.route import (bucket_rank, bucket_sort_gather,  # noqa: F401
-                             onehot_rank, resolve_route_impl)
+# The rank of every bucketing is repro.kernels.route.bucket_rank (Mosaic
+# on TPU, the same tiled algorithm in plain XLA off-TPU).
+from ..kernels.route import bucket_rank
 
 
 # ---------------------------------------------------------------------------
@@ -114,37 +114,14 @@ def resolve_caps(fabric, queues, task: str, e_local: int, axis: str,
 # bucketing (the bounded IQ)
 # ---------------------------------------------------------------------------
 
-def positions_by_dest(dest, valid, n_buckets, impl=None):
+def positions_by_dest(dest, valid, n_buckets):
     """Stable position of each *valid* task within its destination bucket
-    (invalid entries are unspecified — callers mask with ``valid``).
-
-    ``impl`` selects the ranking engine (see module doc of
-    :mod:`repro.kernels.route`): ``"pallas"`` streams elements in tiles
-    against per-destination running counts — O(N + S*tiles); ``"sort"``
-    is the argsort-by-dest + segment-offsets fallback; ``"onehot"`` is
-    the legacy O(N*S) one-hot cumsum.
+    (invalid entries are unspecified — callers mask with ``valid``), by
+    :func:`repro.kernels.route.bucket_rank`: O(N + S*tiles), elements
+    streamed in tiles against per-destination running counts.
     """
-    impl = resolve_route_impl(impl)
     with jax.named_scope("dcra.route.rank"):
-        if impl == "pallas":
-            return bucket_rank(dest, valid, n_buckets)
-        if impl == "sort":
-            return _positions_by_dest_sort(dest, valid, n_buckets)
-        return onehot_rank(dest, valid, n_buckets)
-
-
-def _positions_by_dest_sort(dest, valid, n_buckets):
-    """Sort-based rank: stable argsort by destination (invalid pushed to a
-    sentinel bucket), position = index - first index of the run — the same
-    trick :func:`repro.sparse.program._pack_edges` uses host-side."""
-    n = dest.shape[0]
-    key = jnp.where(valid, dest.astype(jnp.int32), n_buckets)
-    order = jnp.argsort(key, stable=True)
-    ks = key[order]
-    start = jnp.searchsorted(ks, ks, side="left")
-    pos_sorted = (jnp.arange(n, dtype=jnp.int32)
-                  - start.astype(jnp.int32))
-    return jnp.zeros(n, jnp.int32).at[order].set(pos_sorted)
+        return bucket_rank(dest, valid, n_buckets)
 
 
 def slot_scatter(data, slot, valid, num_slots):
@@ -159,25 +136,16 @@ def slot_scatter(data, slot, valid, num_slots):
                                    num_segments=num_slots + 1)[:num_slots]
 
 
-def bucket(x_tasks, dest, valid, aux_ints, n_buckets, cap, impl=None):
+def bucket(x_tasks, dest, valid, aux_ints, n_buckets, cap):
     """Capacity-bounded bucketing (the IQ). Returns (xb, ints, slot, n_drop).
 
     xb [n_buckets*cap, D]; ints: like aux_ints but slot-ordered (-1 = empty);
     also returns each task's slot (-1 if dropped) for building return maps.
 
-    ``impl`` picks the hot-path engine (see :func:`positions_by_dest`);
-    drop semantics are bit-identical across impls — first ``cap`` tasks
-    per channel in array order — so the analytic twins stay exact no
-    matter which impl a launch resolves.
+    Admission keeps the first ``cap`` tasks per channel in array order,
+    the rule the analytic twins mirror, so they stay exact.
     """
-    impl = resolve_route_impl(impl)
-    if impl == "sort":
-        # the argsort already groups each bucket contiguously: build xb by
-        # gathering the first `cap` of each run instead of paying a second
-        # segment-sum scatter (bit-identical drop semantics)
-        return bucket_sort_gather(x_tasks, dest, valid, aux_ints,
-                                  n_buckets, cap)
-    pos = positions_by_dest(dest, valid, n_buckets, impl=impl)
+    pos = positions_by_dest(dest, valid, n_buckets)
     keep = valid & (pos < cap)
     slot = dest * cap + jnp.minimum(pos, cap - 1)
     total = n_buckets * cap
@@ -286,8 +254,7 @@ def fused_all_to_all(vals: Optional[jax.Array], int_cols: Sequence[jax.Array],
 # owner-routed rounds (bucket + fused a2a), flat and hierarchical
 # ---------------------------------------------------------------------------
 
-def owner_route(vals, slot_ids, owner, valid, n_shards, cap, axis,
-                impl=None):
+def owner_route(vals, slot_ids, owner, valid, n_shards, cap, axis):
     """One flat NoC round: route ``(slot_ids, vals)`` tasks to ``owner``.
 
     Per-shard (call inside shard_map). vals [N] f32 payload, slot_ids [N]
@@ -297,13 +264,13 @@ def owner_route(vals, slot_ids, owner, valid, n_shards, cap, axis,
     IQ-overflow drops (psum over ``axis`` for the global count).
     """
     xb, (slot_b,), _, n_drop = bucket(vals[:, None], owner, valid,
-                                      [slot_ids], n_shards, cap, impl=impl)
+                                      [slot_ids], n_shards, cap)
     recv_vals, (recv_slot,) = fused_all_to_all(xb, [slot_b], axis)
     return recv_slot, recv_vals[:, 0], n_drop
 
 
 def owner_route_hier(vals, slot_ids, owner, valid, n_intra, intra_axis,
-                     n_pods, pod_axis, cap1, cap2, impl=None):
+                     n_pods, pod_axis, cap1, cap2):
     """Two-stage pod/portal NoC round (paper §III-A two-level torus).
 
     Stage 1 (tile-NoC): tasks go to the device in the *sender's* pod with
@@ -315,12 +282,11 @@ def owner_route_hier(vals, slot_ids, owner, valid, n_intra, intra_axis,
     e_coord = owner % n_intra
     p_coord = owner // n_intra
     xb, (pc_b, slot_b), _, drop1 = bucket(vals[:, None], e_coord, valid,
-                                          [p_coord, slot_ids], n_intra, cap1,
-                                          impl=impl)
+                                          [p_coord, slot_ids], n_intra, cap1)
     v1, (pc1, slot1) = fused_all_to_all(xb, [pc_b, slot_b], intra_axis)
     valid1 = pc1 >= 0
     xb2, (slot2_b,), _, drop2 = bucket(v1, jnp.maximum(pc1, 0), valid1,
-                                       [slot1], n_pods, cap2, impl=impl)
+                                       [slot1], n_pods, cap2)
     v2, (recv_slot,) = fused_all_to_all(xb2, [slot2_b], pod_axis)
     return recv_slot, v2[:, 0], drop1 + drop2
 
@@ -366,7 +332,7 @@ def _a2a_with_signal(packed, n_blocks, signal, axis):
 
 
 def owner_route_start(vals, slot_ids, owner, valid, n_shards, cap, axis,
-                      signal, impl=None):
+                      signal):
     """Produce half of one flat NoC round: bucket + pack + the fused
     collective (with ``signal`` ridden along, see :func:`_a2a_with_signal`).
 
@@ -377,7 +343,7 @@ def owner_route_start(vals, slot_ids, owner, valid, n_shards, cap, axis,
     wire buffer itself is carried.
     """
     xb, (slot_b,), _, n_drop = bucket(vals[:, None], owner, valid,
-                                      [slot_ids], n_shards, cap, impl=impl)
+                                      [slot_ids], n_shards, cap)
     packed, meta = pack_wire(xb, [slot_b])
     recv, gsignal = _a2a_with_signal(packed, n_shards, signal, axis)
     return recv, meta, n_drop, gsignal
@@ -393,7 +359,7 @@ def owner_route_finish(recv_wire, meta):
 
 def owner_route_hier_start(vals, slot_ids, owner, valid, n_intra,
                            intra_axis, n_pods, pod_axis, cap1, cap2,
-                           signal, impl=None):
+                           signal):
     """Produce half of one pod/portal round (both stages complete here —
     stage-2 bucketing needs stage-1's receive, so the die-NoC edge is the
     one the pipelined loop carries). The signal crosses both stages:
@@ -403,38 +369,37 @@ def owner_route_hier_start(vals, slot_ids, owner, valid, n_intra,
     e_coord = owner % n_intra
     p_coord = owner // n_intra
     xb, (pc_b, slot_b), _, drop1 = bucket(vals[:, None], e_coord, valid,
-                                          [p_coord, slot_ids], n_intra, cap1,
-                                          impl=impl)
+                                          [p_coord, slot_ids], n_intra, cap1)
     packed1, meta1 = pack_wire(xb, [pc_b, slot_b])
     recv1, sig1 = _a2a_with_signal(packed1, n_intra, signal, intra_axis)
     v1, (pc1, slot1) = unpack_wire(recv1, meta1)
     valid1 = pc1 >= 0
     xb2, (slot2_b,), _, drop2 = bucket(v1, jnp.maximum(pc1, 0), valid1,
-                                       [slot1], n_pods, cap2, impl=impl)
+                                       [slot1], n_pods, cap2)
     packed2, meta2 = pack_wire(xb2, [slot2_b])
     recv2, gsignal = _a2a_with_signal(packed2, n_pods, sig1, pod_axis)
     return recv2, meta2, drop1 + drop2, gsignal
 
 
-def local_route(vals, slot_ids, dest, valid, n_buckets, cap, impl=None):
+def local_route(vals, slot_ids, dest, valid, n_buckets, cap):
     """Admission of one round whose producer and consumer are the same
     shard (a one-device flat launch): rank and capacity test, with no
     bucket array, no wire and no collective — the task stream itself is
     the receive buffer.
 
     The kept set is :func:`bucket`'s: the first ``cap`` valid tasks per
-    channel in array order, ranked by the same ``impl``, so the drop
-    count is the same too. Returns ``(recv_slot, recv_val, n_drop)`` as
+    channel in array order, ranked by the same rank, so the drop count
+    is the same too. Returns ``(recv_slot, recv_val, n_drop)`` as
     :func:`owner_route` does, with ``recv_slot`` -1 for every task not
     kept, ready for :func:`reduce_received`.
     """
-    pos = positions_by_dest(dest, valid, n_buckets, impl=impl)
+    pos = positions_by_dest(dest, valid, n_buckets)
     keep = valid & (pos < cap)
     return jnp.where(keep, slot_ids, -1), vals, jnp.sum(valid & ~keep)
 
 
 def local_route_reduce(vals, slot_ids, dest, valid, n_buckets, cap, n_local,
-                       op, impl=None):
+                       op):
     """One whole round with a LOCAL communication edge:
     :func:`local_route` then :func:`reduce_received` straight off the
     task stream, never materializing the ``[n_buckets*cap]`` bucket array
@@ -453,7 +418,7 @@ def local_route_reduce(vals, slot_ids, dest, valid, n_buckets, cap, n_local,
                          f"is bucket order only for one bucket, got "
                          f"{n_buckets}")
     recv_slot, recv_val, n_drop = local_route(vals, slot_ids, dest, valid,
-                                              n_buckets, cap, impl=impl)
+                                              n_buckets, cap)
     return reduce_received(recv_slot, recv_val, n_local, op), n_drop
 
 

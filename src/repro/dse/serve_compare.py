@@ -1,6 +1,6 @@
 """``python -m repro.dse.serve_compare OLD.json NEW.json`` — serving
-trajectory gate (sibling of :mod:`repro.dse.route_compare`, for the
-wall-clock ``dcra-serve-bench/v1`` artifact ``BENCH_serve.json``).
+trajectory gate (for the wall-clock ``dcra-serve-bench/v1`` artifact
+``BENCH_serve.json``).
 
 Absolute req/s do not transfer across machines (the committed baseline
 is produced on a dev box, CI runs on shared runners), so the gate

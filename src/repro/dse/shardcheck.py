@@ -83,6 +83,7 @@ def check_point(check: dict, n_dev: int, scale: int, seed: int) -> list:
     from ..sparse import datasets
     from ..sparse.jax_apps import (dcra_histogram, dcra_scatter, dcra_spmv,
                                    histogram_task_stream, spmv_task_stream)
+    from ..sparse.options import LaunchOptions
 
     fab = Fabric.fake(n_dev)
     mesh = fab             # every launch below goes through the Fabric path
@@ -93,15 +94,16 @@ def check_point(check: dict, n_dev: int, scale: int, seed: int) -> list:
         if app == "spmv":
             x = np.random.default_rng(seed).random(g.n)
             dest, _ = spmv_task_stream(g, x, n_dev, seed)
-            _, dropped = dcra_spmv(g, x, mesh, seed=seed, cap=cap)
+            _, dropped = dcra_spmv(g, x, mesh,
+                                   options=LaunchOptions(seed=seed, cap=cap))
             n_items = g.n
             # measure delivered-task count END TO END: route unit payloads
             # through the same collective so kept+dropped is observed at
             # the owners, not recomputed from the host-side stream
             ones = np.ones(len(dest), np.float32)
             y1, drop1 = dcra_scatter(jnp.asarray(dest), jnp.asarray(ones),
-                                     n_items, mesh, "data", op="add",
-                                     cap=cap)
+                                     n_items, mesh, op="add",
+                                     options=LaunchOptions(cap=cap))
             kept = int(round(float(np.asarray(y1).sum())))
             assert int(drop1) == int(dropped)   # same stream, same cap
         elif app == "histogram":
@@ -109,7 +111,8 @@ def check_point(check: dict, n_dev: int, scale: int, seed: int) -> list:
                                           seed=seed + 3)
             n_items = max(g.n // 16, 64)
             dest, _ = histogram_task_stream(els, n_dev)
-            y, dropped = dcra_histogram(els, n_items, mesh, cap=cap)
+            y, dropped = dcra_histogram(els, n_items, mesh,
+                                        options=LaunchOptions(cap=cap))
             # the histogram IS a unit-payload scatter: its own output
             # counts the delivered tasks
             kept = int(round(float(np.asarray(y).sum())))
@@ -128,7 +131,8 @@ def check_point(check: dict, n_dev: int, scale: int, seed: int) -> list:
                              rng.integers(0, n_dev, n_dev * e_local))
             els = np.minimum(bins + owner, n_items - 1)
             dest, _ = histogram_task_stream(els, n_dev)
-            y, dropped = dcra_histogram(els, n_items, mesh, cap=cap)
+            y, dropped = dcra_histogram(els, n_items, mesh,
+                                        options=LaunchOptions(cap=cap))
             kept = int(round(float(np.asarray(y).sum())))
         elif app in PROGRAM_PARAMS:
             # iterative app: run the whole program, compare the per-round
@@ -136,8 +140,9 @@ def check_point(check: dict, n_dev: int, scale: int, seed: int) -> list:
             from ..sparse.jax_apps import PROGRAMS
             from ..sparse.program import program_app_stats, run_program
             params = PROGRAM_PARAMS[app]
-            _, stats = run_program(PROGRAMS[app], g, mesh, cap=cap,
-                                   params=params, seed=seed)
+            _, stats = run_program(
+                PROGRAMS[app], g, mesh,
+                options=LaunchOptions(cap=cap, seed=seed), params=params)
             twin = program_app_stats(PROGRAMS[app], g, n_dev, cap=cap,
                                      params=params, seed=seed)
             ok = (stats.rounds == twin.rounds

@@ -176,12 +176,10 @@ class ProgramServer:
     ``tenant_queues`` maps tenant -> :class:`QueueConfig` admission
     budget (``default_queues`` covers the rest; ``None`` = unbounded
     admission). ``options`` is the :class:`LaunchOptions` default applied
-    to EVERY launch the server issues (pre-warm included) — queue sizing,
-    ``route_impl``, ``round_mode="pipelined"``, all of it; the legacy
-    ``axis=`` / ``launch_queues=`` kwargs keep working when ``options``
-    is not given (mixing the two raises). The default factor-4 sizing is
-    drop-free for the serving graphs, which is what keeps batched results
-    bit-identical to standalone runs.
+    to EVERY launch the server issues (pre-warm included) — mesh axes,
+    queue sizing, ``round_mode="pipelined"``, all of it. The default
+    factor-4 sizing is drop-free for the serving graphs, which is what
+    keeps batched results bit-identical to standalone runs.
 
     ``serve_options`` is the :class:`~repro.serve.options.ServeOptions`
     for the loop itself — inflight window depth, batch-formation
@@ -233,34 +231,22 @@ class ProgramServer:
     """
 
     def __init__(self, fabric, graphs: Dict[str, CSR], *,
-                 axis: str = "data",
                  batch_width: int = 4,
                  tenant_queues: Optional[Dict[str, QueueConfig]] = None,
                  default_queues: Optional[QueueConfig] = None,
-                 launch_queues: Optional[QueueConfig] = None,
                  max_rounds: Optional[int] = None,
                  moe: Optional["MoEService"] = None,
                  options: Optional[LaunchOptions] = None,
                  serve_options: Optional[ServeOptions] = None,
                  failure_plan: Optional[ServeFailurePlan] = None):
-        if options is not None:
-            if axis != "data" or launch_queues is not None:
-                raise ValueError("options= conflicts with explicit axis=/"
-                                 "launch_queues=: fold them into the "
-                                 "LaunchOptions")
-            self.options = options.resolve()
-        else:
-            self.options = LaunchOptions(axis=axis,
-                                         queues=launch_queues).resolve()
+        self.options = (options or LaunchOptions()).resolve()
         from ..core.fabric import as_fabric
         self.fabric = as_fabric(fabric)     # raw Mesh -> warn-once shim
         self.mesh = self.fabric.mesh        # kept for the MoE lane
-        self.axis = self.options.axis
         self.graphs = dict(graphs)
         self.batch_width = int(batch_width)
         self.tenant_queues = dict(tenant_queues or {})
         self.default_queues = default_queues
-        self.launch_queues = self.options.queues
         self.max_rounds = max_rounds
         self.moe = moe
         self.serve_options = (serve_options or ServeOptions()).resolve()

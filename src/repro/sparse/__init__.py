@@ -9,7 +9,7 @@ _JAX_APPS = ("AppStats", "PROGRAMS", "TaskProgram", "dcra_bfs",
              "ProgramLaunch", "spmv_task_stream")
 
 # launch configuration (numpy-only module — no jax import)
-_OPTIONS = ("LaunchOptions", "resolve_options")
+_OPTIONS = ("LaunchOptions",)
 
 
 def __getattr__(name):

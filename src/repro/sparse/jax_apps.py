@@ -304,135 +304,77 @@ PROGRAMS = {p.name: p for p in (BFS, SSSP, WCC, PAGERANK, SPMV, HISTOGRAM,
 # ---------------------------------------------------------------------------
 
 def dcra_spmv(g: CSR, x: np.ndarray, mesh, *,
-              options: Optional[LaunchOptions] = None, axis="data",
-              capacity_factor: Optional[float] = None, seed: int = 0,
-              pod_axis=None, cap: Optional[int] = None, config=None,
-              objective="teps", route_impl: Optional[str] = None,
-              round_mode: Optional[str] = None):
+              options: Optional[LaunchOptions] = None):
     """Distributed y = A @ x via one owner-routed round.
 
-    ``config="auto"`` resolves pod/portal routing and the per-task IQ
-    sizing from the tracked Pareto frontier (see
-    :mod:`repro.dse.autoconfig`) instead of the kwargs (combining the
-    two raises). ``capacity_factor`` defaults to 2.0. ``options=`` takes
-    a :class:`LaunchOptions` in place of the legacy launch kwargs.
+    ``options=`` holds the launch settings (:class:`LaunchOptions`);
+    ``config="auto"`` there resolves pod/portal routing and the per-task
+    IQ sizing from the tracked Pareto frontier (see
+    :mod:`repro.dse.autoconfig`), and ``capacity_factor`` defaults to
+    2.0.
     """
-    y, stats = run_program(SPMV, (g, x), mesh, dataset=g, options=options,
-                           axis=axis, pod_axis=pod_axis, cap=cap,
-                           capacity_factor=capacity_factor, config=config,
-                           objective=objective, seed=seed,
-                           route_impl=route_impl, round_mode=round_mode)
+    y, stats = run_program(SPMV, (g, x), mesh, dataset=g, options=options)
     return y, stats.total_drops
 
 
 def dcra_histogram(elements: np.ndarray, n_bins: int, mesh, *,
-                   options: Optional[LaunchOptions] = None, axis="data",
-                   capacity_factor: Optional[float] = None, pod_axis=None,
-                   cap: Optional[int] = None, config=None,
-                   objective="teps", route_impl: Optional[str] = None,
-                   round_mode: Optional[str] = None):
+                   options: Optional[LaunchOptions] = None):
     y, stats = run_program(HISTOGRAM, (elements, n_bins), mesh,
-                           dataset=elements, options=options, axis=axis,
-                           pod_axis=pod_axis, cap=cap,
-                           capacity_factor=capacity_factor, config=config,
-                           objective=objective, route_impl=route_impl,
-                           round_mode=round_mode)
+                           dataset=elements, options=options)
     return y, stats.total_drops
 
 
 def dcra_bfs(g: CSR, root: int, mesh, *,
-             options: Optional[LaunchOptions] = None, axis="data",
-             capacity_factor: Optional[float] = None, max_rounds: int = 128,
-             seed: int = 0, config=None, objective="teps",
-             cap: Optional[int] = None, pod_axis=None,
-             route_impl: Optional[str] = None,
-             round_mode: Optional[str] = None
+             options: Optional[LaunchOptions] = None, max_rounds: int = 128
              ) -> Tuple[np.ndarray, AppStats]:
     """Distributed BFS: hop count from root, -1 if unreachable.
 
+    ``options=`` holds the launch settings (:class:`LaunchOptions`):
     ``config="auto"`` picks the deployment (grid, topology, IQ sizing)
     from the tracked Pareto frontier for this graph + objective;
     ``capacity_factor`` (default 4.0) is the manual alternative —
-    passing both raises. ``options=`` takes a :class:`LaunchOptions` in
-    place of the legacy launch kwargs; ``route_impl`` / ``round_mode``
-    thread through to :func:`run_program` unchanged.
+    setting both raises.
     """
-    (d,), stats = run_program(BFS, g, mesh, options=options, axis=axis,
-                              pod_axis=pod_axis, cap=cap,
-                              capacity_factor=capacity_factor,
-                              config=config, objective=objective,
+    (d,), stats = run_program(BFS, g, mesh, options=options,
                               params={"root": int(root)},
-                              max_rounds=max_rounds, seed=seed,
-                              route_impl=route_impl, round_mode=round_mode)
+                              max_rounds=max_rounds)
     return np.where(np.isfinite(d), d, -1).astype(np.int64), stats
 
 
 def dcra_sssp(g: CSR, root: int, mesh, *,
-              options: Optional[LaunchOptions] = None, axis="data",
-              capacity_factor: Optional[float] = None, max_rounds: int = 256,
-              seed: int = 0, config=None, objective="teps",
-              cap: Optional[int] = None, pod_axis=None,
-              route_impl: Optional[str] = None,
-              round_mode: Optional[str] = None
+              options: Optional[LaunchOptions] = None, max_rounds: int = 256
               ) -> Tuple[np.ndarray, AppStats]:
     """Distributed SSSP (frontier Bellman-Ford): inf if unreachable."""
-    (d,), stats = run_program(SSSP, g, mesh, options=options, axis=axis,
-                              pod_axis=pod_axis, cap=cap,
-                              capacity_factor=capacity_factor,
-                              config=config, objective=objective,
+    (d,), stats = run_program(SSSP, g, mesh, options=options,
                               params={"root": int(root)},
-                              max_rounds=max_rounds, seed=seed,
-                              route_impl=route_impl, round_mode=round_mode)
+                              max_rounds=max_rounds)
     return d.astype(np.float64), stats
 
 
-def dcra_wcc(g: CSR, mesh, *,
-             options: Optional[LaunchOptions] = None, axis="data",
-             capacity_factor: Optional[float] = None,
-             max_rounds: int = 128, seed: int = 0, config=None,
-             objective="teps", cap: Optional[int] = None, pod_axis=None,
-             route_impl: Optional[str] = None,
-             round_mode: Optional[str] = None
-             ) -> Tuple[np.ndarray, AppStats]:
+def dcra_wcc(g: CSR, mesh, *, options: Optional[LaunchOptions] = None,
+             max_rounds: int = 128) -> Tuple[np.ndarray, AppStats]:
     """Distributed WCC via min-label propagation over both edge directions."""
     if g.n > (1 << 24):
         # labels ride the f32 NoC payload; ids above 2^24 would collide
         raise ValueError(f"dcra_wcc supports up to 2^24 vertices, got {g.n}")
-    (lab,), stats = run_program(WCC, g, mesh, options=options, axis=axis,
-                                pod_axis=pod_axis, cap=cap,
-                                capacity_factor=capacity_factor,
-                                config=config, objective=objective,
-                                max_rounds=max_rounds, seed=seed,
-                                route_impl=route_impl, round_mode=round_mode)
+    (lab,), stats = run_program(WCC, g, mesh, options=options,
+                                max_rounds=max_rounds)
     return lab.astype(np.int64), stats
 
 
 def dcra_pagerank(g: CSR, mesh, damping: float = 0.85, iters: int = 20, *,
-                  options: Optional[LaunchOptions] = None, axis="data",
-                  capacity_factor: Optional[float] = None,
-                  seed: int = 0, config=None, objective="teps",
-                  cap: Optional[int] = None, pod_axis=None,
-                  route_impl: Optional[str] = None,
-                  round_mode: Optional[str] = None
+                  options: Optional[LaunchOptions] = None
                   ) -> Tuple[np.ndarray, AppStats]:
     """Distributed PageRank: ``iters`` owner-routed epochs (fori_loop),
     dangling mass redistributed uniformly each epoch (matches the oracle)."""
     (rank, _, _), stats = run_program(
-        PAGERANK, g, mesh, options=options, axis=axis, pod_axis=pod_axis,
-        cap=cap, capacity_factor=capacity_factor, config=config,
-        objective=objective,
-        params={"damping": float(damping), "iters": int(iters)}, seed=seed,
-        route_impl=route_impl, round_mode=round_mode)
+        PAGERANK, g, mesh, options=options,
+        params={"damping": float(damping), "iters": int(iters)})
     return rank, stats
 
 
 def dcra_kcore(g: CSR, k: int, mesh, *,
-               options: Optional[LaunchOptions] = None, axis="data",
-               capacity_factor: Optional[float] = None,
-               max_rounds: int = 128, seed: int = 0, config=None,
-               objective="teps", cap: Optional[int] = None, pod_axis=None,
-               route_impl: Optional[str] = None,
-               round_mode: Optional[str] = None
+               options: Optional[LaunchOptions] = None, max_rounds: int = 128
                ) -> Tuple[np.ndarray, AppStats]:
     """Distributed k-core decomposition: iterative peel via owner-routed
     degree decrements. Returns each vertex's within-core degree (in+out,
@@ -440,8 +382,6 @@ def dcra_kcore(g: CSR, k: int, mesh, *,
     k-core. Oracle: :func:`repro.sparse.ref.kcore_ref`.
     """
     (deg, alive), stats = run_program(
-        KCORE, g, mesh, options=options, axis=axis, pod_axis=pod_axis,
-        cap=cap, capacity_factor=capacity_factor, config=config,
-        objective=objective, params={"k": float(k)}, max_rounds=max_rounds,
-        seed=seed, route_impl=route_impl, round_mode=round_mode)
+        KCORE, g, mesh, options=options, params={"k": float(k)},
+        max_rounds=max_rounds)
     return np.where(alive > 0, deg, -1).astype(np.int64), stats

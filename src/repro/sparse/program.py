@@ -7,7 +7,8 @@ program abstraction): an app is a ~30-line *spec* — edge-payload rule,
 reduce op, frontier-update rule, convergence predicate, task class — and
 :func:`run_program` owns everything the apps used to duplicate:
 
-* ``config=`` launch resolution and the kwargs-conflict checks;
+* launch resolution from one :class:`~repro.sparse.options.LaunchOptions`
+  (``config=`` included);
 * :class:`~repro.core.queues.QueueConfig` capacity resolution + clamping
   (via the shared :func:`~repro.core.routing.resolve_caps` against the
   launch :class:`~repro.core.fabric.Fabric`);
@@ -53,9 +54,8 @@ from ..core.routing import (local_route, owner_route,
                             owner_route_finish, owner_route_hier,
                             owner_route_hier_start, owner_route_start,
                             reduce_received, resolve_caps,
-                            resolve_flat_cap, resolve_hier_caps,
-                            resolve_route_impl)
-from .options import LaunchOptions, resolve_options
+                            resolve_flat_cap, resolve_hier_caps)
+from .options import LaunchOptions
 from ..core.task_engine import (EngineConfig, RoundStats, RunStats,
                                 TaskEngine)
 from ..core.topology import TileGrid
@@ -324,26 +324,21 @@ def packed_graph(g, n_dev: int, undirected: bool = False,
 
 
 # ---------------------------------------------------------------------------
-# launch resolution (config= / kwargs conflicts) — shared by every app
+# launch resolution (LaunchOptions.config) — shared by every app
 # ---------------------------------------------------------------------------
 
-def resolve_launch(config, g, app, objective="teps",  # noqa: PLR0917
-                   kwargs_set=()):
-    """Resolve an app's ``config=`` kwarg to a ``LaunchConfig`` (or None).
+def resolve_launch(config, g, app, objective="teps"):
+    """Resolve a launch's ``LaunchOptions.config`` to a ``LaunchConfig``
+    (or None).
 
     ``"auto"`` runs the Pareto-guided selection in
     :mod:`repro.dse.autoconfig`; a ``LaunchConfig`` passes through; a
     ``DesignPoint`` is wrapped as an explicit choice. ``None`` keeps the
-    legacy kwarg-driven sizing. ``kwargs_set`` names explicitly-passed
-    sizing kwargs — combining those with ``config=`` is an error, not a
-    silent override.
+    sizing of ``cap`` / ``capacity_factor`` / ``queues``
+    (:meth:`LaunchOptions.resolve` refuses ``config`` beside them).
     """
     if config is None:
         return None
-    if kwargs_set:
-        raise ValueError(f"config= conflicts with explicit {kwargs_set}: "
-                         f"queue sizing comes from the resolved "
-                         f"LaunchConfig, drop one of them")
     from ..dse.autoconfig import LaunchConfig, autoconfigure, launch_for
     if isinstance(config, str):
         if config != "auto":
@@ -487,13 +482,9 @@ def prewarm_program(prog: TaskProgram, data, fabric, **kwargs) -> Tuple[
 # the one-round owner-routed scatter (stream programs; public API)
 # ---------------------------------------------------------------------------
 
-def dcra_scatter(dest, vals, n, fabric, axis="data", *,  # noqa: PLR0917
+def dcra_scatter(dest, vals, n, fabric, *,
                  options: Optional[LaunchOptions] = None,
-                 op="add", capacity_factor: Optional[float] = None,
-                 pod_axis=None, cap: Optional[int] = None,
-                 queues: Optional[QueueConfig] = None, task: str = "T3",
-                 route_impl: Optional[str] = None,
-                 round_mode: Optional[str] = None):
+                 op="add", task: str = "T3"):
     """Owner-routed scatter-reduce: one NoC round.
 
     dest/vals: [E] sharded over the device axes (edge-parallel tasks);
@@ -501,39 +492,30 @@ def dcra_scatter(dest, vals, n, fabric, axis="data", *,  # noqa: PLR0917
     on device i % n_dev at local slot i // n_dev) plus the dropped-task
     count (queue overflow).
 
-    ``pod_axis`` selects the hierarchical pod/portal two-stage path
-    (paper §III-A): stage 1 aggregates at the per-pod portal over ``axis``
-    (tile-NoC), stage 2 crosses pods exactly once (die-NoC).
+    ``options.pod_axis`` selects the hierarchical pod/portal two-stage
+    path (paper §III-A): stage 1 aggregates at the per-pod portal over
+    ``options.axis`` (tile-NoC), stage 2 crosses pods exactly once
+    (die-NoC).
 
     Queue sizing resolves through ONE path — :class:`QueueConfig` — like
-    everywhere else in the repo. ``queues`` names the per-``task`` IQ
-    directly; the legacy ``cap=`` / ``capacity_factor=`` kwargs are sugar
-    for ``QueueConfig.from_cap`` / ``QueueConfig.from_factor`` overrides.
-    Explicit capacities are honored exactly (flat path only — the DSE
-    revalidation sweeps the IQ axis in queue entries, so rounding would
-    validate a different capacity than the analytic model swept);
+    everywhere else in the repo. ``options.queues`` names the per-``task``
+    IQ directly; ``cap`` / ``capacity_factor`` are sugar for
+    ``QueueConfig.from_cap`` / ``QueueConfig.from_factor`` (default factor
+    1.5). Explicit capacities are honored exactly (flat path only — the
+    DSE revalidation sweeps the IQ axis in queue entries, so rounding
+    would validate a different capacity than the analytic model swept);
     factor-derived capacities keep the lane-aligned round8. Compiled
-    kernels are cached by (shapes, fabric key, capacities, op, route
-    impl). ``fabric`` is a :class:`~repro.core.fabric.Fabric` (raw
-    meshes keep working through the warn-once shim, with the identical
-    cache key — :meth:`~repro.core.fabric.Fabric.fabric_key`).
+    kernels are cached by (shapes, fabric key, capacities, op).
+    ``fabric`` is a :class:`~repro.core.fabric.Fabric` (raw meshes keep
+    working through the warn-once shim, with the identical cache key —
+    :meth:`~repro.core.fabric.Fabric.fabric_key`).
 
-    ``route_impl`` picks the routing hot-path engine ("pallas" | "sort" |
-    "onehot"; None = ``queues.route_impl`` or the backend-autodetected
-    fast path — see :mod:`repro.kernels.route`); drop semantics are
-    identical across impls, so the analytic twin needs no matching knob.
-
-    ``options=`` takes a :class:`LaunchOptions` in place of the legacy
-    kwargs (which keep working through the deprecation shim);
-    ``round_mode`` is validated but has no effect here — a scatter is a
-    single round, so lockstep and pipelined are the same shape (and share
-    one cache entry).
+    ``round_mode`` has no effect here — a scatter is a single round, so
+    lockstep and pipelined are the same shape (and share one cache
+    entry).
     """
-    opts = resolve_options(options, axis=axis, pod_axis=pod_axis, cap=cap,
-                           capacity_factor=capacity_factor, queues=queues,
-                           route_impl=route_impl, round_mode=round_mode)
-    axis, pod_axis = opts.axis, opts.pod_axis
-    queues, route_impl = opts.queues, opts.route_impl
+    opts = (options or LaunchOptions()).resolve()
+    axis, pod_axis, queues = opts.axis, opts.pod_axis, opts.queues
     fab = as_fabric(fabric)
     n_dev = fab.n_devices
     e_local = dest.shape[0] // n_dev
@@ -545,20 +527,17 @@ def dcra_scatter(dest, vals, n, fabric, axis="data", *,  # noqa: PLR0917
                       1.5 if opts.capacity_factor is None
                       else opts.capacity_factor, task))
     caps, pods = resolve_caps(fab, queues, task, e_local, axis, pod_axis)
-    impl = resolve_route_impl(route_impl if route_impl is not None
-                              else queues.route_impl)
 
-    key = ("scatter", op, n_local, n_dev, axis, pod_axis, pods, caps, impl,
+    key = ("scatter", op, n_local, n_dev, axis, pod_axis, pods, caps,
            fab.fabric_key(), int(dest.shape[0]))
     fn = _cached(key, lambda: _build_scatter_fn(
-        fab.mesh, axis, pod_axis, pods, n_dev, n_local, caps, op, impl))
+        fab.mesh, axis, pod_axis, pods, n_dev, n_local, caps, op))
     spec = P((pod_axis, axis)) if pod_axis else P(axis)
     return fn(_to_global(fab, spec, dest), _to_global(fab, spec, vals))
 
 
 def _build_scatter_fn(mesh, axis, pod_axis, pods,  # noqa: PLR0917
-                      n_dev, n_local, caps, op,
-                      impl):
+                      n_dev, n_local, caps, op):
     spec = P((pod_axis, axis)) if pod_axis else P(axis)
 
     if pod_axis is None:
@@ -570,7 +549,7 @@ def _build_scatter_fn(mesh, axis, pod_axis, pods,  # noqa: PLR0917
             dest_c = jnp.maximum(dest_b, 0)
             recv_slot, recv_val, n_drop = owner_route(
                 vals_b, dest_c // n_dev, dest_c % n_dev, valid,
-                n_dev, cap, axis, impl=impl)
+                n_dev, cap, axis)
             y = reduce_received(recv_slot, recv_val, n_local, op)
             return y, jax.lax.psum(n_drop, axis)
     else:
@@ -583,7 +562,7 @@ def _build_scatter_fn(mesh, axis, pod_axis, pods,  # noqa: PLR0917
             dest_c = jnp.maximum(dest_b, 0)
             recv_slot, recv_val, n_drop = owner_route_hier(
                 vals_b, dest_c // n_dev, dest_c % n_dev, valid,
-                n_intra, axis, n_pods, pod_axis, cap1, cap2, impl=impl)
+                n_intra, axis, n_pods, pod_axis, cap1, cap2)
             y = reduce_received(recv_slot, recv_val, n_local, op)
             return y, jax.lax.psum(n_drop, (pod_axis, axis))
 
@@ -694,7 +673,7 @@ def launch_program(prog: TaskProgram, data, fabric, *,
         raise ValueError("launch_program handles graph programs only — "
                          "stream programs return sharded arrays from "
                          "dcra_scatter already; use run_program")
-    opts = resolve_options(options)
+    opts = (options or LaunchOptions()).resolve()
     return _launch_graph(prog, data, as_fabric(fabric), opts,
                          dict(params or {}), max_rounds,
                          donate_states=donate_states)
@@ -702,16 +681,9 @@ def launch_program(prog: TaskProgram, data, fabric, *,
 
 def run_program(prog: TaskProgram, data, fabric, *,
                 options: Optional[LaunchOptions] = None,
-                axis="data", pod_axis=None,
-                capacity_factor: Optional[float] = None,
-                cap: Optional[int] = None,
-                queues: Optional[QueueConfig] = None,
-                config=None, objective="teps",
                 params: Optional[Mapping] = None,
-                max_rounds: Optional[int] = None, seed: int = 0,
-                dataset=None, route_impl: Optional[str] = None,
-                round_mode: Optional[str] = None,
-                donate_states: bool = False):
+                max_rounds: Optional[int] = None,
+                dataset=None, donate_states: bool = False):
     """Execute a :class:`TaskProgram` on ``fabric``.
 
     Graph programs return ``(state_arrays, AppStats)`` — each state array
@@ -723,50 +695,36 @@ def run_program(prog: TaskProgram, data, fabric, *,
     back, same numbers); raw meshes keep working through the warn-once
     shim with the identical compile-cache key. ``dataset`` overrides
     what ``config="auto"`` signatures (defaults to ``data``).
-    ``route_impl`` picks the routing hot-path engine ("pallas" | "sort" |
-    "onehot"; None = ``queues.route_impl`` or backend autodetect) — part
-    of the compile-cache key, never of the drop semantics.
 
     ``options=`` takes a :class:`LaunchOptions` holding every launch
-    kwarg above (the legacy kwargs keep working through the deprecation
-    shim, resolving through the identical conflict checks and producing
-    the identical cache key). ``round_mode="pipelined"`` selects the
-    double-buffered round shape on more than one device (see
-    :func:`_build_graph_fn`) — bit-identical results and per-round stats,
-    fewer collectives; one device runs one local round in either mode.
-    Graph programs dispatch through :func:`launch_program` and block on
-    its :meth:`ProgramLaunch.result` — the asynchronous serving tier
-    skips only that final wait, never the launch path itself.
+    setting. ``round_mode="pipelined"`` selects the double-buffered round
+    shape on more than one device (see :func:`_build_graph_fn`) —
+    bit-identical results and per-round stats, fewer collectives; one
+    device runs one local round in either mode. Graph programs dispatch
+    through :func:`launch_program` and block on its
+    :meth:`ProgramLaunch.result` — the asynchronous serving tier skips
+    only that final wait, never the launch path itself.
     """
-    opts = resolve_options(options, axis=axis, pod_axis=pod_axis,
-                           capacity_factor=capacity_factor, cap=cap,
-                           queues=queues, config=config, objective=objective,
-                           seed=seed, route_impl=route_impl,
-                           round_mode=round_mode)
+    opts = (options or LaunchOptions()).resolve()
     axis, pod_axis, queues = opts.axis, opts.pod_axis, opts.queues
-    cap, capacity_factor = opts.cap, opts.capacity_factor
-    config, objective, seed = opts.config, opts.objective, opts.seed
-    route_impl, round_mode = opts.route_impl, opts.round_mode
     params = dict(params or {})
-    lc = resolve_launch(config, data if dataset is None else dataset,
-                        prog.name, objective)
+    lc = resolve_launch(opts.config, data if dataset is None else dataset,
+                        prog.name, opts.objective)
     fab = as_fabric(fabric)
     n_dev = fab.n_devices
 
     if prog.mode == "single":
-        dest, vals, n_items = prog.stream(data, params, n_dev, seed)
+        dest, vals, n_items = prog.stream(data, params, n_dev, opts.seed)
         if lc is not None:
             pod_axis = (pod_axis if pod_axis is not None
                         else lc.pod_axis_for(fab))
             queues = lc.device_queues(n_dev, len(dest) // n_dev,
                                       pod=pod_axis is not None)
         if queues is None:
-            queues = _resolve_queues(prog, None, cap, capacity_factor)
-        # an explicit route_impl request always runs the routed path —
-        # the local-reduce shortcut only replaces the *default* engine
+            queues = _resolve_queues(prog, None, opts.cap,
+                                     opts.capacity_factor)
         if (prog.local_reduce is not None and n_dev == 1
-                and pod_axis is None and route_impl is None
-                and queues.route_impl is None):
+                and pod_axis is None):
             e_local = len(dest)
             rcap = resolve_flat_cap(queues, prog.task, e_local, n_dev)
             if rcap >= e_local:    # no task can drop -> bit-identical
@@ -781,7 +739,7 @@ def run_program(prog: TaskProgram, data, fabric, *,
         y_sh, dropped = dcra_scatter(
             jnp.asarray(dest), jnp.asarray(vals), n_items, fab,
             options=LaunchOptions(axis=axis, pod_axis=pod_axis,
-                                  queues=queues, route_impl=route_impl),
+                                  queues=queues),
             op=prog.reduce_op, task=prog.task)
         stats = AppStats(rounds=1,
                          messages=np.array([int((dest >= 0).sum())],
@@ -823,8 +781,7 @@ def _launch_graph(prog: TaskProgram, g, fab: Fabric,  # noqa: PLR0917
     enqueued)."""
     axis, pod_axis, queues = opts.axis, opts.pod_axis, opts.queues
     cap, capacity_factor = opts.cap, opts.capacity_factor
-    seed, route_impl = opts.seed, opts.route_impl
-    round_mode = opts.round_mode
+    seed, round_mode = opts.seed, opts.round_mode
     launch = next(_LAUNCHES)
     n_dev = fab.n_devices
     n = g.n
@@ -842,8 +799,6 @@ def _launch_graph(prog: TaskProgram, g, fab: Fabric,  # noqa: PLR0917
             queues = _resolve_queues(prog, None, cap, capacity_factor)
         caps, pods = resolve_caps(fab, queues, prog.task, E_max, axis,
                                   pod_axis, clamp=True)
-        impl = resolve_route_impl(route_impl if route_impl is not None
-                                  else queues.route_impl)
         states0, fills = prog.init(g, params)
         packed = tuple(np.asarray(_owner_pack_np(s, n_dev, f)[0],
                                   np.float32)
@@ -861,7 +816,7 @@ def _launch_graph(prog: TaskProgram, g, fab: Fabric,  # noqa: PLR0917
     if rounds == 0:
         round_mode = "lockstep"          # no rounds, nothing to overlap
     key = (prog, n, n_dev, n_local, E_max, axis, pod_axis, pods, caps,
-           impl, rounds, round_mode, len(packed),
+           rounds, round_mode, len(packed),
            tuple(sorted(kparams.items())), fab.fabric_key())
     if donate_states:
         # donation changes lowering (input/output buffer aliasing), so it
@@ -875,7 +830,7 @@ def _launch_graph(prog: TaskProgram, g, fab: Fabric,  # noqa: PLR0917
     with TraceAnnotation("dcra.graph.dispatch", launch=launch):
         fn = _cached(key, lambda: _build_graph_fn(
             prog, fab.mesh, axis, pod_axis, pods, n_dev, n_local, n, caps,
-            kparams, rounds, len(packed), impl, round_mode=round_mode,
+            kparams, rounds, len(packed), round_mode=round_mode,
             donate_states=donate_states))
         out = fn(*args)
     return ProgramLaunch(fab, tuple(out), n, n_dev, len(packed), launch)
@@ -883,7 +838,7 @@ def _launch_graph(prog: TaskProgram, g, fab: Fabric,  # noqa: PLR0917
 
 def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
                     n_dev, n_local, n,
-                    caps, params, rounds, n_states, impl=None,
+                    caps, params, rounds, n_states,
                     round_mode="lockstep", donate_states=False):
     """Build the jitted shard_map callable for one graph-program shape.
 
@@ -959,16 +914,14 @@ def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
             with jax.named_scope("dcra.graph.route"):
                 if fold_local:
                     recv_slot, recv_val, nd = local_route(
-                        vals, slot, owner, active, n_dev, caps[0],
-                        impl=impl)
+                        vals, slot, owner, active, n_dev, caps[0])
                 elif pod_axis is None:
                     recv_slot, recv_val, nd = owner_route(
-                        vals, slot, owner, active, n_dev, caps[0], axis,
-                        impl=impl)
+                        vals, slot, owner, active, n_dev, caps[0], axis)
                 else:
                     recv_slot, recv_val, nd = owner_route_hier(
                         vals, slot, owner, active, pods[0], axis, pods[1],
-                        pod_axis, caps[0], caps[1], impl=impl)
+                        pod_axis, caps[0], caps[1])
             with jax.named_scope("dcra.graph.reduce"):
                 upd = reduce_received(recv_slot, recv_val, n_local,
                                       prog.reduce_op)
@@ -993,11 +946,11 @@ def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
                 if pod_axis is None:
                     recv, meta, nd_loc, gcnt = owner_route_start(
                         vals, slot, owner, active, n_dev, caps[0], axis,
-                        fcnt, impl=impl)
+                        fcnt)
                 else:
                     recv, meta, nd_loc, gcnt = owner_route_hier_start(
                         vals, slot, owner, active, pods[0], axis, pods[1],
-                        pod_axis, caps[0], caps[1], fcnt, impl=impl)
+                        pod_axis, caps[0], caps[1], fcnt)
             if not meta_box:
                 meta_box.append(meta)
             return recv, m_loc, nd_loc, gcnt

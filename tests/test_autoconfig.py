@@ -6,7 +6,8 @@ acceptance bar: ``config="auto"`` never picks a point whose analytic TEPS
 on the quick datasets is below the all-defaults baseline.
 
 Part B — the executable path (subprocess, 8 fake host devices):
-``dcra_bfs(g, root, mesh, config="auto")`` selects a frontier point, still
+``dcra_bfs(g, root, mesh, options=LaunchOptions(config="auto"))`` selects
+a frontier point, still
 matches the numpy oracle, and the auto-resolved ``QueueConfig`` sizing
 stays drop-free at emulation granularity.
 """
@@ -239,11 +240,14 @@ def test_element_stream_signature_lives_in_bin_space():
 
 def test_config_conflicts_with_explicit_sizing_kwargs(quick_data):
     from repro.sparse.jax_apps import dcra_bfs, dcra_spmv
+    from repro.sparse.options import LaunchOptions
     g = quick_data[sorted(quick_data)[0]]
     with pytest.raises(ValueError, match="conflicts"):
-        dcra_bfs(g, 0, mesh=None, capacity_factor=2.0, config="auto")
+        dcra_bfs(g, 0, mesh=None, options=LaunchOptions(
+            capacity_factor=2.0, config="auto"))
     with pytest.raises(ValueError, match="conflicts"):
-        dcra_spmv(g, np.ones(g.n), mesh=None, cap=4, config="auto")
+        dcra_spmv(g, np.ones(g.n), mesh=None,
+                  options=LaunchOptions(cap=4, config="auto"))
 
 
 # ---------------------------------------------------------------------------
@@ -330,6 +334,7 @@ from repro.core.compat import make_mesh
 from repro.dse.autoconfig import autoconfigure
 from repro.sparse import datasets, ref
 from repro.sparse.jax_apps import dcra_bfs, dcra_spmv
+from repro.sparse.options import LaunchOptions
 
 mesh = make_mesh((8,), ('data',))
 g = datasets.rmat(8, edge_factor=16, seed=1)      # a quick-bench dataset
@@ -339,13 +344,13 @@ lc = autoconfigure(g, 'bfs')
 res['source'] = lc.source
 res['point_id'] = lc.point.point_id
 
-d, stats = dcra_bfs(g, 0, mesh, config='auto')
+d, stats = dcra_bfs(g, 0, mesh, options=LaunchOptions(config='auto'))
 res['bfs_err'] = float(np.max(np.abs(d - ref.bfs_ref(g, 0))))
 res['bfs_drops'] = stats.total_drops
 res['bfs_rounds'] = stats.rounds
 
 x = np.random.default_rng(0).random(g.n)
-y, drops = dcra_spmv(g, x, mesh, config='auto')
+y, drops = dcra_spmv(g, x, mesh, options=LaunchOptions(config='auto'))
 want = ref.spmv_ref(g, x)
 res['spmv_err'] = float(np.max(np.abs(np.asarray(y) - want))
                         / max(1.0, float(np.abs(want).max())))

@@ -25,16 +25,19 @@ from repro.sparse import datasets, ref
 from repro.sparse.jax_apps import (dcra_bfs, dcra_histogram, dcra_kcore,
                                    dcra_pagerank, dcra_spmv, dcra_sssp,
                                    dcra_wcc)
+from repro.sparse.options import LaunchOptions
 
 def run_six(g, mesh, tag, res):
     x = np.random.default_rng(0).random(g.n)
-    y, drops = dcra_spmv(g, x, mesh, capacity_factor=3.0)
+    y, drops = dcra_spmv(g, x, mesh,
+                         options=LaunchOptions(capacity_factor=3.0))
     res[f'{tag}/spmv'] = {
         'err': float(np.max(np.abs(np.asarray(y) - ref.spmv_ref(g, x)))
                      / max(1.0, float(np.abs(ref.spmv_ref(g, x)).max()))),
         'drops': int(drops), 'rounds': 1}
     els = datasets.histogram_data(1 << 12, 64, seed=4)
-    h, d = dcra_histogram(els, 64, mesh, capacity_factor=3.0)
+    h, d = dcra_histogram(els, 64, mesh,
+                          options=LaunchOptions(capacity_factor=3.0))
     res[f'{tag}/histogram'] = {
         'err': float(np.max(np.abs(np.asarray(h) -
                                    ref.histogram_ref(els, 64)))),

@@ -192,9 +192,11 @@ def test_ingest_is_deterministic_and_fabric_driven():
 def test_ingest_graph_runs_bfs():
     from repro.sparse.datasets import ingest_graph
     from repro.sparse.jax_apps import dcra_bfs
+    from repro.sparse.options import LaunchOptions
     from repro.core.fabric import Fabric
     g = ingest_graph(6, edge_factor=4, seed=3, n_chunks=4)
-    d, stats = dcra_bfs(g, 0, Fabric.fake(1), capacity_factor=8.0)
+    d, stats = dcra_bfs(g, 0, Fabric.fake(1),
+                        options=LaunchOptions(capacity_factor=8.0))
     assert d.shape == (64,) and stats.rounds > 0
 
 
@@ -275,6 +277,7 @@ from repro.core.compat import make_mesh
 from repro.core.fabric import Fabric
 from repro.sparse import datasets, program
 from repro.sparse.jax_apps import dcra_bfs, dcra_scatter
+from repro.sparse.options import LaunchOptions
 from repro.serve.engine import ProgramServer, Request
 
 res = {}
@@ -283,9 +286,11 @@ g = datasets.wiki_like(192, avg_degree=6, seed=7)
 # -- flat parity + shared cache entry at every device count -----------------
 for n_dev in (1, 2, 4, 8):
     mesh = make_mesh((n_dev,), ('data',))
-    d1, s1 = dcra_bfs(g, 0, mesh, capacity_factor=0.25)     # overflowing
+    d1, s1 = dcra_bfs(g, 0, mesh,                    # overflowing
+                      options=LaunchOptions(capacity_factor=0.25))
     c0 = program.cache_stats()
-    d2, s2 = dcra_bfs(g, 0, Fabric.fake(n_dev), capacity_factor=0.25)
+    d2, s2 = dcra_bfs(g, 0, Fabric.fake(n_dev),
+                      options=LaunchOptions(capacity_factor=0.25))
     c1 = program.cache_stats()
     res[f'flat{n_dev}'] = {
         'equal': bool(np.array_equal(d1, d2)),
@@ -298,9 +303,11 @@ for n_dev in (1, 2, 4, 8):
 # -- pod/portal parity ------------------------------------------------------
 hier_mesh = make_mesh((2, 4), ('pod', 'data'))
 hier_fab = Fabric.single((2, 4), ('pod', 'data'))
-d1, s1 = dcra_bfs(g, 0, hier_mesh, pod_axis='pod', capacity_factor=0.25)
+d1, s1 = dcra_bfs(g, 0, hier_mesh,
+                  options=LaunchOptions(pod_axis='pod', capacity_factor=0.25))
 c0 = program.cache_stats()
-d2, s2 = dcra_bfs(g, 0, hier_fab, pod_axis='pod', capacity_factor=0.25)
+d2, s2 = dcra_bfs(g, 0, hier_fab,
+                  options=LaunchOptions(pod_axis='pod', capacity_factor=0.25))
 c1 = program.cache_stats()
 res['hier'] = {
     'equal': bool(np.array_equal(d1, d2)),
@@ -314,9 +321,11 @@ res['hier'] = {
 dest = jnp.asarray(np.arange(64) % 16)
 vals = jnp.ones(64, jnp.float32)
 mesh8 = make_mesh((8,), ('data',))
-y1, dr1 = dcra_scatter(dest, vals, 16, mesh8, capacity_factor=2.0)
+y1, dr1 = dcra_scatter(dest, vals, 16, mesh8,
+                       options=LaunchOptions(capacity_factor=2.0))
 c0 = program.cache_stats()
-y2, dr2 = dcra_scatter(dest, vals, 16, Fabric.fake(8), capacity_factor=2.0)
+y2, dr2 = dcra_scatter(dest, vals, 16, Fabric.fake(8),
+                       options=LaunchOptions(capacity_factor=2.0))
 c1 = program.cache_stats()
 res['scatter'] = {'equal': bool(np.array_equal(np.asarray(y1),
                                                np.asarray(y2))),
@@ -439,10 +448,11 @@ assert fab.host_slice(8) in ((0, 4), (4, 8))
 
 from repro.sparse import datasets
 from repro.sparse.jax_apps import dcra_bfs
+from repro.sparse.options import LaunchOptions
 
 g = datasets.erdos_renyi(96, avg_degree=6, seed=5)
 res = {}
-d, st = dcra_bfs(g, 0, fab, capacity_factor=1.0)
+d, st = dcra_bfs(g, 0, fab, options=LaunchOptions(capacity_factor=1.0))
 res['flat'] = {'dist': np.asarray(d).tolist(),
                'messages': st.messages.tolist(),
                'drops': st.drops.tolist(), 'rounds': st.rounds}
@@ -451,7 +461,8 @@ res['flat'] = {'dist': np.asarray(d).tolist(),
 hier = Fabric.distributed((2, 2), ('portal', 'data'), portal_axis='portal')
 assert hier.dcn_axes() == ('portal',)  # only the portal hop crosses DCN
 assert hier.pod_axis == 'portal'
-d2, st2 = dcra_bfs(g, 0, hier, pod_axis='portal', capacity_factor=1.0)
+d2, st2 = dcra_bfs(g, 0, hier, options=LaunchOptions(pod_axis='portal',
+                                                     capacity_factor=1.0))
 res['hier'] = {'dist': np.asarray(d2).tolist(),
                'messages': st2.messages.tolist(),
                'drops': st2.drops.tolist(), 'rounds': st2.rounds}
@@ -468,15 +479,18 @@ import numpy as np
 from repro.core.fabric import Fabric
 from repro.sparse import datasets
 from repro.sparse.jax_apps import dcra_bfs
+from repro.sparse.options import LaunchOptions
 
 g = datasets.erdos_renyi(96, avg_degree=6, seed=5)
 res = {}
-d, st = dcra_bfs(g, 0, Fabric.fake(4), capacity_factor=1.0)
+d, st = dcra_bfs(g, 0, Fabric.fake(4),
+                 options=LaunchOptions(capacity_factor=1.0))
 res['flat'] = {'dist': np.asarray(d).tolist(),
                'messages': st.messages.tolist(),
                'drops': st.drops.tolist(), 'rounds': st.rounds}
 hier = Fabric.single((2, 2), ('portal', 'data'), portal_axis='portal')
-d2, st2 = dcra_bfs(g, 0, hier, pod_axis='portal', capacity_factor=1.0)
+d2, st2 = dcra_bfs(g, 0, hier, options=LaunchOptions(pod_axis='portal',
+                                                     capacity_factor=1.0))
 res['hier'] = {'dist': np.asarray(d2).tolist(),
                'messages': st2.messages.tolist(),
                'drops': st2.drops.tolist(), 'rounds': st2.rounds}

@@ -49,6 +49,7 @@ import jax, numpy as np
 from repro.core.compat import make_mesh, set_mesh
 from repro.sparse import datasets, ref
 from repro.sparse.jax_apps import dcra_histogram, dcra_spmv
+from repro.sparse.options import LaunchOptions
 
 mesh = make_mesh((8,), ('data',))
 g = datasets.rmat(9, edge_factor=8, seed=3)
@@ -64,7 +65,8 @@ with set_mesh(mesh):
         np.array_equal(np.asarray(h), ref.histogram_ref(els, 128)))
     res['hist_dropped'] = int(d2)
     # tight queues DO drop (the paper's overflow semantics)
-    _, d3 = dcra_histogram(els, 128, mesh, capacity_factor=0.2)
+    _, d3 = dcra_histogram(els, 128, mesh,
+                           options=LaunchOptions(capacity_factor=0.2))
     res['tight_queue_drops'] = int(d3)
 print('RESULT ' + json.dumps(res))
 """

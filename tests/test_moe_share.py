@@ -152,9 +152,9 @@ def test_slot_plan_of_a_share_is_what_moe_dcra_allocates(
     buckets, ffn_rows = [], []
     bucket, ffn = dispatch._bucket, dispatch._expert_ffn
 
-    def bucket_spy(x, dest, valid, aux, n_buckets, cap, impl=None):
+    def bucket_spy(x, dest, valid, aux, n_buckets, cap):
         buckets.append((dest.shape[0], n_buckets, cap))
-        return bucket(x, dest, valid, aux, n_buckets, cap, impl=impl)
+        return bucket(x, dest, valid, aux, n_buckets, cap)
 
     def ffn_spy(xe, *a):
         ffn_rows.append(xe.shape[0] * xe.shape[1])

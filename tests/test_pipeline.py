@@ -1,12 +1,11 @@
 """The pipelined round shape and the LaunchOptions launch surface.
 
-Part A — in-process (1 device): the ``resolve_options`` deprecation shim
-(legacy kwargs and ``options=`` resolve to THE SAME compile-cache entry,
-the warning fires once per process, conflicts raise), ``round_mode`` /
-``route_impl`` land in the compile-cache key, every entrypoint accepts
+Part A — in-process (1 device): ``LaunchOptions`` is the one launch
+surface (its conflicts raise, the old per-setting keywords are gone),
+``round_mode`` lands in the compile-cache key, every entrypoint accepts
 ``options=``, ``local_route_reduce`` is bit-identical to the two-pass
-``bucket`` + ``reduce_received`` shape, the round-level route_compare
-gate, and a pipelined ``ProgramServer`` serves identically. On a
+``bucket`` + ``reduce_received`` shape, and a pipelined
+``ProgramServer`` serves identically. On a
 one-device fabric both round modes run the local fold: the seven apps
 agree bitwise across modes and match the oracles, tight caps drop as the
 analytic twin says, and ``cache_stats()["local_fold_builds"]`` counts the
@@ -23,7 +22,6 @@ import json
 import os
 import subprocess
 import sys
-import warnings
 
 import numpy as np
 import pytest
@@ -47,35 +45,7 @@ def _mesh1():
     return make_mesh((1,), ("data",))
 
 
-def test_legacy_kwargs_and_options_share_one_cache_entry():
-    """The shim is an alias, not a fork: same key, same jitted callable,
-    bit-identical result."""
-    from repro.core import fabric as fab_mod
-    from repro.sparse import LaunchOptions, options as opt_mod, program
-    from repro.sparse.jax_apps import dcra_bfs
-    g, mesh = _tiny(), _mesh1()
-    program.clear_cache()
-    opt_mod._WARNED[0] = False
-    fab_mod._WARNED[0] = True   # isolate the kwarg shim from the mesh shim
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        d1, s1 = dcra_bfs(g, 0, mesh, capacity_factor=2.0)
-        d1b, _ = dcra_bfs(g, 0, mesh, capacity_factor=2.0)
-    legacy_warns = [x for x in w if issubclass(x.category,
-                                               DeprecationWarning)]
-    assert len(legacy_warns) == 1            # once per process, not per call
-    after_legacy = program.cache_stats()
-    d2, s2 = dcra_bfs(g, 0, mesh,
-                      options=LaunchOptions(capacity_factor=2.0))
-    after_options = program.cache_stats()
-    assert after_options["misses"] == after_legacy["misses"]   # same key
-    assert after_options["hits"] == after_legacy["hits"] + 1
-    assert np.array_equal(np.asarray(d1), np.asarray(d2))
-    assert np.array_equal(np.asarray(d1), np.asarray(d1b))
-    assert s1.rounds == s2.rounds and s1.total_drops == s2.total_drops
-
-
-def test_round_mode_and_route_impl_are_cache_key_dimensions():
+def test_round_mode_is_a_cache_key_dimension():
     from repro.sparse import LaunchOptions, program
     from repro.sparse.jax_apps import dcra_bfs
     g, mesh = _tiny(), _mesh1()
@@ -86,32 +56,32 @@ def test_round_mode_and_route_impl_are_cache_key_dimensions():
     assert program.cache_stats()["misses"] == 2
     dcra_bfs(g, 0, mesh, options=LaunchOptions(round_mode="pipelined"))
     assert program.cache_stats()["misses"] == 2    # pipelined entry reused
-    dcra_bfs(g, 0, mesh, options=LaunchOptions(route_impl="sort"))
-    assert program.cache_stats()["misses"] == 3
+    dcra_bfs(g, 0, mesh, options=LaunchOptions(round_mode="lockstep"))
+    assert program.cache_stats()["misses"] == 2    # lockstep is the default
 
 
 def test_option_conflicts_raise():
+    """``LaunchOptions.resolve`` is the one conflict check, and the
+    per-setting keywords of the old launch surface are gone."""
     from repro.sparse import LaunchOptions
     from repro.sparse.jax_apps import dcra_bfs, dcra_spmv
     g = _tiny()
     with pytest.raises(ValueError, match="conflicts"):
-        dcra_bfs(g, 0, mesh=None, cap=4, capacity_factor=2.0)
+        dcra_bfs(g, 0, mesh=None,
+                 options=LaunchOptions(cap=4, capacity_factor=2.0))
     with pytest.raises(ValueError, match="conflicts"):
-        dcra_spmv(g, np.ones(g.n), mesh=None, cap=4, config="auto")
-    with pytest.raises(ValueError, match="conflicts"):
-        dcra_bfs(g, 0, mesh=None, options=LaunchOptions(), cap=4)
+        dcra_spmv(g, np.ones(g.n), mesh=None,
+                  options=LaunchOptions(cap=4, config="auto"))
     with pytest.raises(ValueError, match="round_mode"):
-        dcra_bfs(g, 0, mesh=None, round_mode="warp")
-    with pytest.raises(ValueError, match="route_impl"):
-        LaunchOptions(route_impl="bogus").resolve()
-    with pytest.raises(TypeError, match="unknown"):
-        from repro.sparse.options import resolve_options
-        resolve_options(None, caps=4)
+        dcra_bfs(g, 0, mesh=None, options=LaunchOptions(round_mode="warp"))
+    with pytest.raises(TypeError):
+        dcra_bfs(g, 0, mesh=None, cap=4)
 
 
 def test_every_entrypoint_accepts_options():
     """All seven dcra_* apps + run_program + dcra_scatter take options=
-    and agree bitwise with their legacy-kwarg spelling."""
+    and agree bitwise with the default launch (one device drops nothing
+    at either capacity factor)."""
     from repro.sparse import LaunchOptions, jax_apps
     from repro.sparse import datasets
     from repro.sparse.jax_apps import PROGRAMS, dcra_scatter, run_program
@@ -134,18 +104,19 @@ def test_every_entrypoint_accepts_options():
     assert set(calls) == set(PROGRAMS)
     for app, call in calls.items():
         got, _ = call(options=opts)
-        want, _ = call(capacity_factor=2.0)
+        want, _ = call()
         assert np.array_equal(np.asarray(got), np.asarray(want)), app
-    r1, _ = run_program(PROGRAMS["bfs"], g, mesh, options=opts,
-                        params={"root": 0})
-    r2, _ = run_program(PROGRAMS["bfs"], g, mesh, capacity_factor=2.0,
-                        params={"root": 0})
+    r1, s1 = run_program(PROGRAMS["bfs"], g, mesh, options=opts,
+                         params={"root": 0})
+    r2, _ = run_program(PROGRAMS["bfs"], g, mesh, params={"root": 0})
     assert np.array_equal(np.asarray(r1), np.asarray(r2))
+    assert s1.total_drops == 0
     dest = jnp.asarray(np.arange(32) % 8)
     vals = jnp.ones(32, jnp.float32)
-    y1, _ = dcra_scatter(dest, vals, 8, mesh, options=opts)
-    y2, _ = dcra_scatter(dest, vals, 8, mesh, capacity_factor=2.0)
+    y1, d1 = dcra_scatter(dest, vals, 8, mesh, options=opts)
+    y2, _ = dcra_scatter(dest, vals, 8, mesh)
     assert np.array_equal(np.asarray(y1), np.asarray(y2))
+    assert int(d1) == 0
 
 
 @pytest.mark.parametrize("op,s", [("min", 8), ("store", 8), ("min", 1),
@@ -183,28 +154,6 @@ def test_local_route_reduce_refuses_add_over_several_buckets():
         local_route_reduce(ones, dest, dest, ones > 0, 4, 8, 4, "add")
 
 
-def test_route_compare_gates_round_cells():
-    from repro.dse.route_compare import compare
-    rcell = {"n": 131072, "s": 128, "cap": 2048, "rounds": 6,
-             "round_speedup": {"onehot": 1.2, "sort": 1.5, "pallas": 2.3}}
-    old = {"schema": "dcra-route-bench/v2", "cells": [
-        {"n": 1, "s": 1, "speedup_vs_onehot": {"onehot": 1.0}}],
-        "round_cells": [rcell]}
-    f, _ = compare(old, old)
-    assert not f
-    worse = json.loads(json.dumps(old))
-    worse["round_cells"][0]["round_speedup"]["pallas"] = 1.0   # -57%
-    f, _ = compare(old, worse)
-    assert any("round" in x and "REGRESSED" in x for x in f)
-    gone = json.loads(json.dumps(old))
-    gone["round_cells"] = []
-    f, _ = compare(old, gone)
-    assert any("round_cells" in x for x in f)
-    v1 = {"schema": "dcra-route-bench/v1", "cells": old["cells"]}
-    f, notes = compare(v1, old)                # v1 baseline: report, no gate
-    assert not f and any("not gated" in x for x in notes)
-
-
 def test_pipelined_program_server_serves_identically():
     from repro.serve import LaunchOptions, ProgramServer, Request
     g, mesh = _tiny(), _mesh1()
@@ -219,7 +168,7 @@ def test_pipelined_program_server_serves_identically():
     for a, b in zip(base, pipe):
         assert a.status == b.status and a.rounds == b.rounds
         assert np.array_equal(np.asarray(a.result), np.asarray(b.result))
-    with pytest.raises(ValueError, match="conflicts"):
+    with pytest.raises(TypeError):
         ProgramServer(mesh, {"g": g}, axis="model",
                       options=LaunchOptions())
 
@@ -339,7 +288,7 @@ import json
 import jax
 import numpy as np
 from repro.core.compat import make_mesh
-from repro.sparse import datasets
+from repro.sparse import LaunchOptions, datasets
 from repro.sparse.jax_apps import PROGRAMS
 from repro.sparse.program import program_app_stats, run_program
 
@@ -350,9 +299,11 @@ ITER = tuple(PARAMS)
 
 def pair(app, mesh, n_dev, tag, twin_kw, **kw):
     r_l, s_l = run_program(PROGRAMS[app], g, mesh, params=PARAMS[app],
-                           round_mode='lockstep', **kw)
+                           options=LaunchOptions(round_mode='lockstep',
+                                                 **kw))
     r_p, s_p = run_program(PROGRAMS[app], g, mesh, params=PARAMS[app],
-                           round_mode='pipelined', **kw)
+                           options=LaunchOptions(round_mode='pipelined',
+                                                 **kw))
     leaves = zip(jax.tree_util.tree_leaves(r_l),
                  jax.tree_util.tree_leaves(r_p))
     twin = program_app_stats(PROGRAMS[app], g, n_dev, params=PARAMS[app],

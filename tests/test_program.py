@@ -98,6 +98,7 @@ from repro.core.compat import make_mesh
 from repro.sparse import datasets, program, ref
 from repro.sparse.jax_apps import PROGRAMS, dcra_bfs, dcra_kcore
 from repro.sparse.program import program_app_stats, run_program
+from repro.sparse.options import LaunchOptions
 
 g = datasets.wiki_like(256, avg_degree=8, seed=7)
 x = np.random.default_rng(0).random(g.n)
@@ -124,7 +125,8 @@ for n_dev in (1, 2, 4, 8):
         data = DATA.get(app, g)
         caps = (2, 96) if n_dev in (1, 8) else (2,)
         for cap in caps:
-            _, stats = run_program(prog, data, mesh, cap=cap,
+            _, stats = run_program(prog, data, mesh,
+                                   options=LaunchOptions(cap=cap),
                                    params=PARAMS[app])
             twin = program_app_stats(prog, data, n_dev, cap=cap,
                                      params=PARAMS[app])
@@ -135,8 +137,10 @@ hier = make_mesh((2, 4), ('pod', 'data'))
 for app, prog in PROGRAMS.items():
     data = DATA.get(app, g)
     for cf in (0.25, 4.0):
-        _, stats = run_program(prog, data, hier, pod_axis='pod',
-                               capacity_factor=cf, params=PARAMS[app])
+        _, stats = run_program(prog, data, hier,
+                               options=LaunchOptions(pod_axis='pod',
+                                                     capacity_factor=cf),
+                               params=PARAMS[app])
         twin = program_app_stats(prog, data, 8, capacity_factor=cf,
                                  params=PARAMS[app], pods=(4, 2))
         res['pod'].append(parity_case(app, 8, f'cf{cf}', stats, twin))
@@ -149,9 +153,9 @@ res['results']['kcore'] = {
     'err': int(np.abs(k_ - want).max()),
     'drops': st.total_drops, 'rounds': st.rounds,
     'partial_peel': bool(0 < int((k_ >= 0).sum()) < g.n)}
-k2, _ = dcra_kcore(g, 8, hier, pod_axis='pod')
+k2, _ = dcra_kcore(g, 8, hier, options=LaunchOptions(pod_axis='pod'))
 res['results']['kcore_pod_err'] = int(np.abs(k2 - want).max())
-d_, st = dcra_bfs(g, 0, hier, pod_axis='pod')
+d_, st = dcra_bfs(g, 0, hier, options=LaunchOptions(pod_axis='pod'))
 res['results']['bfs_pod'] = {
     'err': int(np.abs(d_ - ref.bfs_ref(g, 0)).max()),
     'drops': st.total_drops}
@@ -249,6 +253,7 @@ from repro.sparse.csr import CSR
 from repro.sparse.jax_apps import BFS, PAGERANK, SSSP, WCC
 from repro.sparse.program import (launch_program, program_app_stats,
                                   run_program)
+from repro.sparse.options import LaunchOptions
 
 g = datasets.rmat(8, edge_factor=8, seed=5)
 FABRICS = {'one': Fabric.single((1,), ('data',)), 'fake4': Fabric.fake(4)}
@@ -307,7 +312,7 @@ rp['same'] = packs(lambda: run_program(BFS, h, one, params={'root': 1}))[0]
 rp['new_csr'] = packs(lambda: run_program(
     BFS, CSR(h.row_ptr, h.col_idx, h.values), one, params={'root': 0}))[0]
 rp['seed'] = packs(lambda: run_program(BFS, h, one, params={'root': 0},
-                                       seed=1))[0]
+                                       options=LaunchOptions(seed=1)))[0]
 rp['undirected'] = packs(lambda: run_program(WCC, h, one))[0]
 rp['fabric'] = packs(lambda: run_program(BFS, h, FABRICS['fake4'],
                                          params={'root': 0}))[0]
@@ -356,7 +361,7 @@ for app, (prog, plist) in APPS.items():
     program.clear_cache()
     cold = program_app_stats(prog, g, 4, cap=2, params=p)
     program.clear_cache()
-    _, st = run_program(prog, g, fab4, cap=2, params=p)
+    _, st = run_program(prog, g, fab4, options=LaunchOptions(cap=2), params=p)
     c0 = program.cache_stats()
     twin = program_app_stats(prog, g, 4, cap=2, params=p)
     c1 = program.cache_stats()

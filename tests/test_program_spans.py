@@ -113,7 +113,7 @@ def scopes(text):
 FOLDS = {}
 
 
-def graph(app, round_mode, route_impl, shape, names, pod_axis):
+def graph(app, round_mode, shape, names, pod_axis):
     texts, build = [], program._build_graph_fn
     folds0 = program.cache_stats()["local_fold_builds"]
 
@@ -133,10 +133,10 @@ def graph(app, round_mode, route_impl, shape, names, pod_axis):
         prog, datasets.rmat(7, edge_factor=4, seed=3),
         Fabric.single(shape, names), params=params,
         options=LaunchOptions(axis="data", pod_axis=pod_axis,
-                              round_mode=round_mode, route_impl=route_impl))
+                              round_mode=round_mode))
     program._build_graph_fn = build
     (text,) = texts
-    FOLDS[(app, round_mode, route_impl, shape)] = (
+    FOLDS[(app, round_mode, shape)] = (
         program.cache_stats()["local_fold_builds"] - folds0)
     return scopes(text)
 
@@ -161,16 +161,15 @@ one = ((1,), ("data",), None)
 moe_mesh = ((1, 4, 1), ("data", "expert", "tp"), None)
 moe_pods = ((2, 1, 2, 1), ("pod", "data", "expert", "tp"), "pod")
 res = {
-    "graph-bfs-lockstep": graph("bfs", "lockstep", None, *flat),
-    "graph-bfs-lockstep-sort": graph("bfs", "lockstep", "sort", *flat),
-    "graph-bfs-lockstep-pods": graph("bfs", "lockstep", None, *pods),
-    "graph-bfs-pipelined": graph("bfs", "pipelined", None, *flat),
-    "graph-bfs-pipelined-pods": graph("bfs", "pipelined", None, *pods),
-    "graph-bfs-pipelined-one-device": graph("bfs", "pipelined", None, *one),
-    "graph-bfs-lockstep-one-device": graph("bfs", "lockstep", None, *one),
-    "graph-pagerank-lockstep": graph("pagerank", "lockstep", None, *flat),
-    "graph-pagerank-pipelined": graph("pagerank", "pipelined", None, *flat),
-    "graph-pagerank-lockstep-one-device": graph("pagerank", "lockstep", None,
+    "graph-bfs-lockstep": graph("bfs", "lockstep", *flat),
+    "graph-bfs-lockstep-pods": graph("bfs", "lockstep", *pods),
+    "graph-bfs-pipelined": graph("bfs", "pipelined", *flat),
+    "graph-bfs-pipelined-pods": graph("bfs", "pipelined", *pods),
+    "graph-bfs-pipelined-one-device": graph("bfs", "pipelined", *one),
+    "graph-bfs-lockstep-one-device": graph("bfs", "lockstep", *one),
+    "graph-pagerank-lockstep": graph("pagerank", "lockstep", *flat),
+    "graph-pagerank-pipelined": graph("pagerank", "pipelined", *flat),
+    "graph-pagerank-lockstep-one-device": graph("pagerank", "lockstep",
                                                 *one),
     "moe": moe(8, *moe_mesh),
     "moe-one-expert-per-shard": moe(4, *moe_mesh),
@@ -179,7 +178,7 @@ res = {
                                    scoring="sigmoid", n_group=4, topk_group=2,
                                    n_shared=1, d_shared=32),
 }
-res["local_fold_builds"] = [[list(k[:3]) + [list(k[3])], v]
+res["local_fold_builds"] = [[list(k[:2]) + [list(k[2])], v]
                             for k, v in FOLDS.items()]
 print("RESULT " + json.dumps(res))
 """
@@ -188,7 +187,6 @@ EVERY_GRAPH = GRAPH_PHASES | ROUTE_STEPS
 EVERY_MOE = MOE_PHASES | ROUTE_STEPS
 EXPECTED_SCOPES = {
     "graph-bfs-lockstep": EVERY_GRAPH,
-    "graph-bfs-lockstep-sort": EVERY_GRAPH,
     "graph-bfs-lockstep-pods": EVERY_GRAPH,
     "graph-bfs-pipelined": EVERY_GRAPH,
     "graph-bfs-pipelined-pods": EVERY_GRAPH,
@@ -229,10 +227,10 @@ def test_local_fold_builds_only_on_one_device(hlo_scopes):
     """Each graph callable built on the one-device fabric takes the fold;
     none built on the four-device flat or 2x2 pod fabric does."""
     builds = hlo_scopes["local_fold_builds"]
-    assert len(builds) == 10
+    assert len(builds) == 9
     assert sum(n for (*_, shape), n in builds if shape == [1]) == 3
-    for (app, mode, impl, shape), n in builds:
-        assert n == (1 if shape == [1] else 0), (app, mode, impl, shape)
+    for (app, mode, shape), n in builds:
+        assert n == (1 if shape == [1] else 0), (app, mode, shape)
 
 
 def _moe(capacity_factor=1.25, num_experts=4, top_k=2):
@@ -266,9 +264,9 @@ def test_slot_plan_is_what_moe_dcra_allocates(batch, seq, capacity_factor,
     buckets, ffn_rows = [], []
     bucket, ffn = dispatch._bucket, dispatch._expert_ffn
 
-    def bucket_spy(x, dest, valid, aux, n_buckets, cap, impl=None):
+    def bucket_spy(x, dest, valid, aux, n_buckets, cap):
         buckets.append((n_buckets, cap))
-        return bucket(x, dest, valid, aux, n_buckets, cap, impl=impl)
+        return bucket(x, dest, valid, aux, n_buckets, cap)
 
     def ffn_spy(xe, *a):
         ffn_rows.append(xe.shape[0] * xe.shape[1])

@@ -89,6 +89,7 @@ import jax
 from repro.core import EngineConfig, QueueConfig, TaskEngine, TileGrid
 from repro.core.compat import make_mesh
 from repro.sparse.jax_apps import dcra_scatter, from_owner_layout
+from repro.sparse.options import LaunchOptions
 
 def oracle(dest, vals, n, n_dev, cap, op):
     '''First-cap-per-(source shard, owner) keep rule + reduction.'''
@@ -128,8 +129,8 @@ for n_dev in (1, 2, 4, 8):
             cap = max(8, -(-int(e_local * cf / n_dev) // 8) * 8)
             y_sh, dropped = dcra_scatter(
                 jax.numpy.asarray(dest, jax.numpy.int32),
-                jax.numpy.asarray(vals), n, mesh, 'data', op=op,
-                capacity_factor=cf)
+                jax.numpy.asarray(vals), n, mesh, op=op,
+                options=LaunchOptions(capacity_factor=cf))
             y = np.asarray(from_owner_layout(y_sh, n, n_dev), np.float64)
             want, want_drops = oracle(dest, vals, n, n_dev, cap, op)
             # analytic twin: same stream through TaskEngine.route, the

@@ -367,6 +367,7 @@ from repro.core.queues import QueueConfig
 from repro.sparse import datasets, program
 from repro.sparse.jax_apps import BFS, SSSP
 from repro.sparse.program import run_program
+from repro.sparse.options import LaunchOptions
 from repro.serve import (MoEService, ProgramServer, Request,
                          STATUS_OK, STATUS_REJECTED)
 
@@ -517,7 +518,8 @@ res['admission'] = {
 
 # ---- undersized LAUNCH queues: drops are attributed, never silent ------
 srv3 = ProgramServer(mesh, {'wiki': g}, batch_width=WIDTH,
-                     launch_queues=QueueConfig.from_cap(2, 'T3'))
+                     options=LaunchOptions(
+                         queues=QueueConfig.from_cap(2, 'T3')))
 resp3 = srv3.run([Request(i, f't{i}', 'bfs', 'wiki', root=i)
                   for i in range(2)])
 srv3.stats.verify()
