@@ -17,8 +17,12 @@ The bucketing / fused-payload all_to_all / pod-portal machinery lives in
 :mod:`repro.core.routing` (shared with the distributed graph apps in
 :mod:`repro.sparse.jax_apps`); this module keeps only what is MoE-specific:
 the dispatch plan, the expert FFN, gating, and the return/combine path.
-Everything is built from ``segment_sum`` scatter/gather (differentiable) and
-one fused ``all_to_all`` per NoC stage under ``shard_map``.
+Rows enter each bucket by a gather through its slot ints. The expert and
+pod-portal buckets give them back by a gather through each task's slot
+(the bucket's own inverse map), so no row is scattered on the way back;
+the combine at the source sums the returned rows per token with
+``segment_sum``. All of it is differentiable, with one fused
+``all_to_all`` per NoC stage under ``shard_map``.
 """
 from __future__ import annotations
 
@@ -256,8 +260,9 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
 
     The layer's device work runs under six scopes: ``dcra.moe.router``
     (logits, top-k, gates), ``dcra.moe.dispatch`` (stage buckets, token
-    gather, collectives), ``dcra.moe.expert_pad`` (rows into and out of
-    the per-expert buckets), ``dcra.moe.expert_ffn``,
+    gather, collectives), ``dcra.moe.expert_pad`` (the per-expert
+    bucket: a row gather into it, and a row gather back out through each
+    received row's slot), ``dcra.moe.expert_ffn``,
     ``dcra.moe.combine`` (return path, gate-weighted sum, aux loss) and
     ``dcra.moe.shared``.
     """
@@ -371,7 +376,7 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
                 n1 = xs1.shape[0]
                 # ---- stage 2 over pod axis (die-NoC portal) ------------
                 valid1 = pcs >= 0
-                _, (eid2, slot1_of_s2), _, _ = _bucket(
+                _, (eid2, slot1_of_s2), slot2_of_s1, _ = _bucket(
                     pcs[:, None] * 0, jnp.maximum(pcs, 0), valid1,
                     [eids1, jnp.arange(n1, dtype=jnp.int32)], n_pod,
                     plan.cap2)
@@ -390,7 +395,7 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
             cap_e = plan.cap_e
             with jax.named_scope("dcra.moe.expert_pad"):
                 # second-level IQ: bucket received tasks by local expert
-                _, (srce,), _, _ = _bucket(
+                _, (srce,), slot_of_r, _ = _bucket(
                     validr[:, None].astype(jnp.int32) * 0,
                     jnp.maximum(eidr, 0), validr,
                     [jnp.arange(N_r, dtype=jnp.int32)], E_local, cap_e)
@@ -399,8 +404,8 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
                 ye_b = _expert_ffn(xe.astype(xb.dtype), wg, wu, wd,
                                    info.tp_axis, n_tp)
             with jax.named_scope("dcra.moe.expert_pad"):
-                ye = _slot_scatter(ye_b.reshape(E_local * cap_e, D),
-                                   jnp.maximum(srce, 0), srce >= 0, N_r)
+                # back to received order: each row reads its own slot
+                ye = gather_rows(ye_b.reshape(E_local * cap_e, D), slot_of_r)
 
         with jax.named_scope("dcra.moe.combine"):
             # --- return path (retrace the NoC route) --------------------
@@ -408,8 +413,7 @@ def moe_dcra(params, x, cfg, info: MeshInfo,
                 yb1 = _a2a(ye, group)
             else:
                 y2 = _a2a(ye, info.pod_axis)                # back to portal
-                y1 = _slot_scatter(y2, jnp.maximum(slot1_of_s2, 0),
-                                   slot1_of_s2 >= 0, n1)
+                y1 = gather_rows(y2, slot2_of_s1)
                 yb1 = _a2a(y1, group)                # back to source
 
             # combine at the source, weighted by gate: read the fewer of

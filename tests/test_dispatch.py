@@ -79,6 +79,52 @@ with jax.default_matmul_precision('highest'):
                 parts = parts + jax.jit(lambda p, x: moe_dcra(
                     p, x, routed_only, info_s))(ps, x)[0]
         res[case] = float(jnp.max(jnp.abs(parts - whole)))
+
+# the return out of the buckets against a row scatter: the oracle
+# rebuilds each slot's task from the task slots (as the bucket's slot
+# ints are built) and scatters the rows back through it
+from unittest import mock
+import repro.core.dispatch as dispatch
+from repro.core.routing import bucket, gather_rows, slot_scatter
+
+
+def scatter_return(layer):
+    task_slots, n_returns = [], [0]
+
+    def bucket_kept(*args):
+        out = bucket(*args)
+        task_slots.append(out[2])
+        return out
+
+    def gather_or_scatter(table, ids):
+        if not any(ids is s for s in task_slots):
+            return gather_rows(table, ids)
+        n_returns[0] += 1
+        n = ids.shape[0]
+        task = slot_scatter(jnp.arange(1, n + 1, dtype=jnp.int32),
+                            jnp.maximum(ids, 0), ids >= 0, table.shape[0]) - 1
+        return slot_scatter(table, jnp.maximum(task, 0), task >= 0, n)
+
+    with mock.patch.object(dispatch, '_bucket', bucket_kept), \
+            mock.patch.object(dispatch, 'gather_rows', gather_or_scatter):
+        return layer(), n_returns[0]
+
+
+cfg16 = dataclasses.replace(cfg, moe=dataclasses.replace(
+    cfg.moe, num_experts=16, top_k=4, capacity_factor=1.0))
+params16 = init_moe(jax.random.key(6), cfg16)
+for case, m, info_r in [
+        ('return_fused', mesh, MeshInfo(mesh, pod_axis=None)),
+        ('return_tp_ffn', mesh, MeshInfo(mesh, pod_axis=None, fuse_tp=False)),
+        ('return_pods', mesh2, MeshInfo(mesh2, pod_axis='pod'))]:
+    def layer():
+        return jax.jit(lambda p, x: moe_dcra(p, x, cfg16, info_r)[0])(
+            params16, x)
+    with set_mesh(m):
+        new = layer()
+        old, n_returns = scatter_return(layer)
+    res[case] = {'max_diff': float(jnp.max(jnp.abs(new - old))),
+                 'returns': n_returns}
 print('RESULT ' + json.dumps(res))
 """
 
@@ -113,3 +159,48 @@ def test_gradients_flow(results):
 @pytest.mark.parametrize("case", ["share_flat", "share_pods"])
 def test_expert_shares_add_up_to_the_whole_layer(results, case):
     assert results[case] < 1e-4
+
+
+@pytest.mark.parametrize("case,returns", [("return_fused", 1),
+                                          ("return_tp_ffn", 1),
+                                          ("return_pods", 2)])
+def test_bucket_return_by_gather_equals_the_row_scatter(results, case,
+                                                        returns):
+    """Each packaging with 16 experts (2-8 per shard) gives the layer that
+    a row scatter through each slot's task gives: one return out of the
+    expert buckets, and on pods one more out of the stage-2 portal
+    bucket."""
+    assert results[case]["returns"] == returns
+    assert results[case]["max_diff"] == 0.0
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("fit", ["below", "at", "above"])
+@pytest.mark.parametrize("n_buckets", [8, 64])
+def test_task_slot_gather_inverts_the_bucket(n_buckets, fit, dtype):
+    """Reading bucket rows through each task's slot equals scattering
+    them through each slot's task, with capacity below demand (drops),
+    at the fullest bucket's demand, and above it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.core.routing import bucket, gather_rows, slot_scatter
+
+    rng = np.random.default_rng(n_buckets)
+    n = 32 * n_buckets
+    dest = rng.integers(0, n_buckets, n)
+    valid = rng.random(n) < 0.9
+    demand = int(np.bincount(dest[valid], minlength=n_buckets).max())
+    cap = {"below": demand // 2, "at": demand, "above": demand + 3}[fit]
+    _, (task_of_slot,), task_slot, n_drop = bucket(
+        jnp.zeros((n, 1), jnp.int32), jnp.asarray(dest), jnp.asarray(valid),
+        [jnp.arange(n, dtype=jnp.int32)], n_buckets, cap)
+    assert (int(n_drop) > 0) == (fit == "below")
+    rows = jax.random.normal(jax.random.key(n_buckets),
+                             (n_buckets * cap, 24), jnp.dtype(dtype))
+    got = gather_rows(rows, task_slot)
+    want = slot_scatter(rows, jnp.maximum(task_of_slot, 0),
+                        task_of_slot >= 0, n)
+    assert got.dtype == want.dtype == rows.dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))

@@ -94,6 +94,88 @@ def _run_current():
     return json.loads(line[len("RESULT "):])
 
 
+# Row-wide scatters under the expert-pad scope, in the layer lowered at the
+# widths of both MoE cells on one device: OLMoE's, and the DeepSeek share
+# layer as its benchmark driver builds it.
+ROW_SCATTER_SCRIPT = r"""
+import dataclasses, json, math, re
+import jax, jax.numpy as jnp
+from repro.configs import get_config
+from repro.core.compat import make_mesh
+from repro.core.dispatch import MeshInfo, moe_dcra
+from repro.models.moe import init_moe
+from bench.drivers.moe_share_stack import _arch
+from bench.ref.moe_share import init_params
+
+SCATTER = re.compile(r'= \w+\[([\d,]*)\]\S* scatter\(.*'
+                     r'update_window_dims=\{([\d,]*)\}.*op_name="([^"]*)"')
+
+
+def expert_pad_row_scatters(cfg, info, params, x_shape):
+    x = jax.ShapeDtypeStruct(x_shape, jnp.bfloat16)
+
+    def moe_layer(p, x):
+        with jax.default_matmul_precision('highest'):
+            return moe_dcra(p, x, cfg, info)[0]
+
+    text = jax.jit(moe_layer).lower(params, x).as_text(dialect='hlo',
+                                                       debug_info=True)
+    found = []
+    for dims, window, name in SCATTER.findall(text):
+        row = [int(d) for d in dims.split(',')][1:]
+        if 'dcra.moe.expert_pad' in name and window and math.prod(row) > 1:
+            found.append(dims + ' ' + name)
+    return found
+
+
+one = MeshInfo(make_mesh((1, 1, 1), ('data', 'expert', 'tp')), pod_axis=None)
+res = {}
+olmoe = get_config('olmoe-1b-7b')
+olmoe = dataclasses.replace(olmoe, moe=dataclasses.replace(
+    olmoe.moe, capacity_factor=2.0))
+shapes = jax.eval_shape(lambda: init_moe(jax.random.key(0), olmoe))
+params = {k: jax.ShapeDtypeStruct(
+    v.shape, jnp.bfloat16 if k in ('wg', 'wu', 'wd') else jnp.float32)
+    for k, v in shapes.items()}
+res['olmoe-layer-fwd'] = expert_pad_row_scatters(olmoe, one, params,
+                                                 (8, 512, 2048))
+
+with open('bench/configs/deepseek-v3-moe-ep32.json') as f:
+    ds = json.load(f)
+first, held = ds['first_expert_held'], ds['n_routed_experts']
+params = jax.eval_shape(lambda: init_params(
+    jax.random.key(0), d_model=ds['hidden_size'],
+    n_experts=ds['router_experts'], held=held,
+    d_expert=ds['moe_intermediate_size'],
+    d_shared=ds['n_shared_experts'] * ds['moe_intermediate_size'],
+    bias_std=ds['e_score_correction_bias_std']))
+share = dataclasses.replace(one, expert_share=(first, held))
+res['deepseek-v3-moe-prefill'] = expert_pad_row_scatters(
+    _arch(ds), share, params, (16, 4096, ds['hidden_size']))
+print('RESULT ' + json.dumps(res))
+"""
+
+
+@pytest.fixture(scope="module")
+def row_scatters():
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join([os.path.join(root, "src"), root])
+    out = subprocess.run([sys.executable, "-c", ROW_SCATTER_SCRIPT], env=env,
+                         cwd=root, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    line = [ln for ln in out.stdout.splitlines() if ln.startswith("RESULT ")][0]
+    return json.loads(line[len("RESULT "):])
+
+
+@pytest.mark.parametrize("cell", ["olmoe-layer-fwd", "deepseek-v3-moe-prefill"])
+def test_expert_buckets_return_rows_without_a_row_scatter(row_scatters, cell):
+    """Rows leave the expert buckets by a gather through each received
+    row's slot: no scatter under ``dcra.moe.expert_pad`` writes whole
+    rows (the bucket's own scatters of slot ints are scalar)."""
+    assert row_scatters[cell] == []
+
+
 @pytest.fixture(scope="module")
 def current():
     return _run_current()
