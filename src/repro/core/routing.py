@@ -35,6 +35,7 @@ declared as ``('pod', ..., intra_axis)``.
 """
 from __future__ import annotations
 
+import functools
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -250,9 +251,43 @@ def fused_all_to_all(vals: Optional[jax.Array], int_cols: Sequence[jax.Array],
     return unpack_wire(recv, meta)
 
 
+@functools.lru_cache(maxsize=None)
+def wire_words(n_int_cols: int) -> int:
+    """int32 columns of the wire :func:`pack_wire` makes from one float32
+    value column and ``n_int_cols`` int32 columns (read off its output
+    shape, so it follows the packing)."""
+    packed = jax.eval_shape(
+        lambda v, *c: pack_wire(v, c)[0],
+        jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        *[jax.ShapeDtypeStruct((1,), jnp.int32)] * n_int_cols)
+    assert packed.dtype == jnp.int32
+    return int(packed.shape[1])
+
+
 # ---------------------------------------------------------------------------
 # owner-routed rounds (bucket + fused a2a), flat and hierarchical
 # ---------------------------------------------------------------------------
+
+def route_wire(n_shards: int, caps: Sequence[int],
+               pods: Optional[Tuple[int, int]] = None,
+               signal: bool = False) -> Tuple[Tuple[int, int], ...]:
+    """``(rows, int32 words per row)`` of each all_to_all operand that one
+    shard sends in one owner-routed round of a one-column float32 payload:
+    :func:`owner_route` (one stage of ``n_shards`` buckets of ``caps[0]``
+    slots, carrying the destination slot) or, with ``pods = (n_intra,
+    n_pods)``, :func:`owner_route_hier` (``n_intra`` buckets of
+    ``caps[0]`` carrying pod coordinate and slot, then ``n_pods`` of
+    ``caps[1]`` carrying the slot). ``signal=True`` is the split-phase
+    form (:func:`owner_route_start` / :func:`owner_route_hier_start`),
+    whose collective carries one more row per destination bucket."""
+    extra = 1 if signal else 0
+    if pods is None:
+        (cap,) = caps
+        return ((n_shards * (cap + extra), wire_words(1)),)
+    (n_intra, n_pods), (cap1, cap2) = pods, caps
+    return ((n_intra * (cap1 + extra), wire_words(2)),
+            (n_pods * (cap2 + extra), wire_words(1)))
+
 
 def owner_route(vals, slot_ids, owner, valid, n_shards, cap, axis):
     """One flat NoC round: route ``(slot_ids, vals)`` tasks to ``owner``.

@@ -38,7 +38,7 @@ from __future__ import annotations
 import itertools
 import weakref
 from dataclasses import dataclass
-from typing import Callable, Dict, Mapping, Optional, Tuple
+from typing import Callable, Dict, Mapping, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,7 +54,7 @@ from ..core.routing import (local_route, owner_route,
                             owner_route_finish, owner_route_hier,
                             owner_route_hier_start, owner_route_start,
                             reduce_received, resolve_caps,
-                            resolve_flat_cap, resolve_hier_caps)
+                            resolve_flat_cap, resolve_hier_caps, route_wire)
 from .options import LaunchOptions
 from ..core.task_engine import (EngineConfig, RoundStats, RunStats,
                                 TaskEngine)
@@ -277,13 +277,8 @@ class PackedGraph:
         key = (fab.fabric_key(), spec)
         got = self._placed.get(key)
         if got is None:
-            if fab.is_multiprocess:
-                got = tuple(_to_global(fab, spec, a)
-                            for a in (self.src_slot, self.dst, self.w))
-            else:
-                sharding = NamedSharding(fab.mesh, spec)
-                got = tuple(jax.device_put(a, sharding)
-                            for a in (self.src_slot, self.dst, self.w))
+            got = tuple(_to_global(fab, spec, a)
+                        for a in (self.src_slot, self.dst, self.w))
             self._placed[key] = got
         return got
 
@@ -381,21 +376,24 @@ def _graph_caps(queues: QueueConfig, task: str,  # noqa: PLR0917
 # ---------------------------------------------------------------------------
 
 def _to_global(fab: Fabric, spec, arr):
-    """Lay a host-global array out on the fabric's mesh.
+    """Lay a host-global array out on the fabric's mesh under ``spec``,
+    each device receiving only its own shard.
 
-    Single-process fabrics feed jit with plain (jnp-converted) arrays —
-    unchanged, byte-identical path. On a multi-process fabric a host
-    numpy array cannot feed a global-mesh jit directly, so wrap it with
-    ``make_array_from_callback``: every process holds the same global
-    values (the packed inputs are deterministic from the seed), and each
-    callback slices out the shards this process owns.
+    On one process ``jax.device_put`` onto ``NamedSharding(fab.mesh,
+    spec)`` uploads every shard straight to its device, so the jitted call
+    is handed its inputs already laid out as its ``in_specs`` say (not
+    staged whole on the first device for jit to reshard). On a
+    multi-process fabric a host numpy array cannot feed a global-mesh jit
+    directly, so it is wrapped with ``make_array_from_callback``: every
+    process holds the same global values (the packed inputs are
+    deterministic from the seed), and each callback slices out the shards
+    this process owns.
     """
+    sharding = NamedSharding(fab.mesh, spec)
     if not fab.is_multiprocess:
-        return jnp.asarray(arr)
-    from jax import make_array_from_callback
+        return jax.device_put(arr, sharding)
     a = np.asarray(arr)
-    return make_array_from_callback(
-        a.shape, NamedSharding(fab.mesh, spec), lambda idx: a[idx])
+    return jax.make_array_from_callback(a.shape, sharding, lambda idx: a[idx])
 
 
 def _host_gather(fab: Fabric, x):
@@ -758,6 +756,71 @@ def run_program(prog: TaskProgram, data, fabric, *,
 _LAUNCHES = itertools.count()
 
 
+def _resolve_graph(prog: TaskProgram, g, fab: Fabric, opts: LaunchOptions,
+                   *, dataset=None):
+    """What a graph launch on ``fab`` resolves before it packs any state:
+    ``(packed_graph, pod_axis, caps, pods)``. The one resolution both
+    :func:`_launch_graph` and :func:`wire_plan` read."""
+    pod_axis, queues = opts.pod_axis, opts.queues
+    n_dev = fab.n_devices
+    lc = resolve_launch(opts.config, g if dataset is None else dataset,
+                        prog.name, opts.objective)
+    pg = packed_graph(g, n_dev, undirected=prog.undirected, seed=opts.seed)
+    if lc is not None:
+        pod_axis = (pod_axis if pod_axis is not None
+                    else lc.pod_axis_for(fab))
+        queues = lc.device_queues(n_dev, pg.E_max,
+                                  pod=pod_axis is not None)
+    if queues is None:
+        queues = _resolve_queues(prog, None, opts.cap, opts.capacity_factor)
+    caps, pods = resolve_caps(fab, queues, prog.task, pg.E_max, opts.axis,
+                              pod_axis, clamp=True)
+    return pg, pod_axis, caps, pods
+
+
+def _folds_local(n_dev: int, pod_axis) -> bool:
+    """Whether a graph round runs on one shard with a local communication
+    edge (see :func:`_build_graph_fn`): no bucket, wire or collective."""
+    return n_dev == 1 and pod_axis is None
+
+
+class WirePlan(NamedTuple):
+    """The wire one graph launch ships each round, per chip.
+
+    ``slots_per_round[i]`` is the row count of the i-th all_to_all a
+    round issues on one chip (stage 1, then stage 2 on a pod fabric):
+    every destination bucket's ``cap`` slots, full or not, plus the
+    pipelined shape's signal row per bucket. ``words_per_slot[i]`` is
+    :func:`~repro.core.routing.pack_wire`'s int32 columns for that
+    stage. ``bytes_per_round`` sums rows times words times 4 over the
+    stages. A one-device launch folds its round and ships nothing: no
+    stages, 0 bytes.
+    """
+    n_dev: int
+    caps: Tuple[int, ...]
+    slots_per_round: Tuple[int, ...]
+    words_per_slot: Tuple[int, ...]
+    bytes_per_round: int
+
+
+def wire_plan(prog: TaskProgram, data, fabric,
+              options: Optional[LaunchOptions] = None) -> WirePlan:
+    """The :class:`WirePlan` of ``launch_program(prog, data, fabric,
+    options=options)``, from the same resolution the launch makes (so it
+    packs the graph on first use, as the launch would) and the wire
+    layout of :func:`~repro.core.routing.route_wire`."""
+    opts = (options or LaunchOptions()).resolve()
+    fab = as_fabric(fabric)
+    n_dev = fab.n_devices
+    _, pod_axis, caps, pods = _resolve_graph(prog, data, fab, opts)
+    stages = () if _folds_local(n_dev, pod_axis) else route_wire(
+        n_dev, caps, pods, signal=opts.round_mode == "pipelined")
+    return WirePlan(n_dev=n_dev, caps=tuple(caps),
+                    slots_per_round=tuple(r for r, _ in stages),
+                    words_per_slot=tuple(w for _, w in stages),
+                    bytes_per_round=sum(4 * r * w for r, w in stages))
+
+
 def _launch_graph(prog: TaskProgram, g, fab: Fabric,  # noqa: PLR0917
                   opts: LaunchOptions, params, max_rounds,
                   dataset=None, donate_states: bool = False
@@ -779,26 +842,14 @@ def _launch_graph(prog: TaskProgram, g, fab: Fabric,  # noqa: PLR0917
     states onto the fabric) and ``dcra.graph.dispatch`` (compile-cache
     lookup and the jitted call, which returns once the computation is
     enqueued)."""
-    axis, pod_axis, queues = opts.axis, opts.pod_axis, opts.queues
-    cap, capacity_factor = opts.cap, opts.capacity_factor
-    seed, round_mode = opts.seed, opts.round_mode
+    axis, round_mode = opts.axis, opts.round_mode
     launch = next(_LAUNCHES)
     n_dev = fab.n_devices
     n = g.n
     with TraceAnnotation("dcra.graph.pack", launch=launch):
-        lc = resolve_launch(opts.config, g if dataset is None else dataset,
-                            prog.name, opts.objective)
-        pg = packed_graph(g, n_dev, undirected=prog.undirected, seed=seed)
+        pg, pod_axis, caps, pods = _resolve_graph(prog, g, fab, opts,
+                                                  dataset=dataset)
         n_local, E_max = pg.n_local, pg.E_max
-        if lc is not None:
-            pod_axis = (pod_axis if pod_axis is not None
-                        else lc.pod_axis_for(fab))
-            queues = lc.device_queues(n_dev, E_max,
-                                      pod=pod_axis is not None)
-        if queues is None:
-            queues = _resolve_queues(prog, None, cap, capacity_factor)
-        caps, pods = resolve_caps(fab, queues, prog.task, E_max, axis,
-                                  pod_axis, clamp=True)
         states0, fills = prog.init(g, params)
         packed = tuple(np.asarray(_owner_pack_np(s, n_dev, f)[0],
                                   np.float32)
@@ -880,16 +931,25 @@ def _build_graph_fn(prog, mesh, axis, pod_axis, pods,  # noqa: PLR0917
     (active edges and their values), ``dcra.graph.route`` (bucket and
     collective, or the local rank), ``dcra.graph.reduce``
     (receive-reduce) and ``dcra.graph.update`` (state update, counter
-    psums and per-round commits).
+    psums and per-round commits). On more than one device every scalar
+    psum (``gsum``: the message, drop and convergence counts, the
+    pipelined shape's post-loop sums, and a rule's own, such as
+    PageRank's dangling mass) runs under ``dcra.graph.sync`` inside the
+    scope it is called from; a folded round's psums over one device have
+    no scope of their own.
     """
     spec = P((pod_axis, axis)) if pod_axis else P(axis)
     axes = (pod_axis, axis) if pod_axis else axis
 
+    fold_local = _folds_local(n_dev, pod_axis)
+
     def gsum(x):
-        return jax.lax.psum(x, axes)
+        if fold_local:
+            return jax.lax.psum(x, axes)
+        with jax.named_scope("dcra.graph.sync"):
+            return jax.lax.psum(x, axes)
 
     ctx = Ctx(xp=jnp, n=n, n_dev=n_dev, params=params, gsum=gsum)
-    fold_local = n_dev == 1 and pod_axis is None
     pipelined = round_mode == "pipelined" and not fold_local
     if fold_local:
         CACHE_STATS["local_fold_builds"] += 1

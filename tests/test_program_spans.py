@@ -8,7 +8,9 @@
   names every device scope its ops run under, so a profile can split the
   time by phase; a refactor that drops one fails here. One-device graph
   rounds, in either round mode, carry no scatter or collective scope and
-  are the only ones counted in ``local_fold_builds``.
+  are the only ones counted in ``local_fold_builds``; every multi-device
+  round shape runs its scalar psums under ``dcra.graph.sync``, inside
+  ``dcra.graph.update``.
 * ``slot_plan`` gives the bucket sizes ``moe_dcra`` allocates.
 """
 import dataclasses
@@ -111,6 +113,7 @@ def scopes(text):
 
 
 FOLDS = {}
+SYNC_PARENTS = {}
 
 
 def graph(app, round_mode, shape, names, pod_axis):
@@ -138,6 +141,11 @@ def graph(app, round_mode, shape, names, pod_axis):
     (text,) = texts
     FOLDS[(app, round_mode, shape)] = (
         program.cache_stats()["local_fold_builds"] - folds0)
+    SYNC_PARENTS[f"{app}-{round_mode}-{list(shape)}"] = sorted(
+        {ps[ps.index("dcra.graph.sync") - 1]
+         for st in re.findall(r'op_name="([^"]*)"', text)
+         for ps in [[p for p in st.split("/") if p.startswith("dcra.")]]
+         if "dcra.graph.sync" in ps})
     return scopes(text)
 
 
@@ -180,10 +188,12 @@ res = {
 }
 res["local_fold_builds"] = [[list(k[:2]) + [list(k[2])], v]
                             for k, v in FOLDS.items()]
+res["sync_parents"] = SYNC_PARENTS
 print("RESULT " + json.dumps(res))
 """
 
-EVERY_GRAPH = GRAPH_PHASES | ROUTE_STEPS
+# several devices: the round's scalar psums run under their own scope
+EVERY_GRAPH = GRAPH_PHASES | ROUTE_STEPS | {"dcra.graph.sync"}
 EVERY_MOE = MOE_PHASES | ROUTE_STEPS
 EXPECTED_SCOPES = {
     "graph-bfs-lockstep": EVERY_GRAPH,
@@ -191,8 +201,8 @@ EXPECTED_SCOPES = {
     "graph-bfs-pipelined": EVERY_GRAPH,
     "graph-bfs-pipelined-pods": EVERY_GRAPH,
     # one device, any round mode or reduce op: the route only ranks, the
-    # reduce reads the edge stream, and nothing is scattered or crosses a
-    # wire
+    # reduce reads the edge stream, nothing is scattered or crosses a
+    # wire, and the psums over one device have no sync scope
     "graph-bfs-pipelined-one-device": GRAPH_PHASES | {"dcra.route.rank"},
     "graph-bfs-lockstep-one-device": GRAPH_PHASES | {"dcra.route.rank"},
     "graph-pagerank-lockstep": EVERY_GRAPH,
@@ -221,6 +231,17 @@ def hlo_scopes():
 @pytest.mark.parametrize("case", sorted(EXPECTED_SCOPES))
 def test_layer_hlo_carries_every_scope(case, hlo_scopes):
     assert set(hlo_scopes[case]) == EXPECTED_SCOPES[case]
+
+
+def test_sync_scope_nests_in_update_on_several_devices(hlo_scopes):
+    """``dcra.graph.sync`` holds the scalar psums of every multi-device
+    round shape, directly inside ``dcra.graph.update``, and appears in no
+    one-device round."""
+    parents = hlo_scopes["sync_parents"]
+    assert len(parents) == 9
+    for case, scopes in parents.items():
+        assert scopes == ([] if case.endswith("[1]") else
+                          ["dcra.graph.update"]), case
 
 
 def test_local_fold_builds_only_on_one_device(hlo_scopes):
