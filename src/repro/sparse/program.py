@@ -49,7 +49,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.compat import shard_map_unchecked
 from ..core.fabric import Fabric, as_fabric
-from ..core.queues import QueueConfig
+from ..core.queues import QueueConfig, round8
 from ..core.routing import (local_route, owner_route,
                             owner_route_finish, owner_route_hier,
                             owner_route_hier_start, owner_route_start,
@@ -250,6 +250,15 @@ def _graph_setup(g, n_dev, undirected=False, seed=0):
     return n_local, src_slot, dst, w, E_max
 
 
+def _owner_bound(dst, n_dev):
+    """The most valid edges (``dst >= 0``) that one device's block of the
+    packed ``dst`` holds for one owner ``dst % n_dev``: the most tasks
+    that one (sender, owner) bucket of a flat round can ever be offered."""
+    blocks = dst.reshape(n_dev, -1)
+    return max(int(np.bincount(b[b >= 0] % n_dev, minlength=n_dev).max())
+               for b in blocks)
+
+
 # ---------------------------------------------------------------------------
 # resident packed graphs: one pack per (graph, device count), kept
 # ---------------------------------------------------------------------------
@@ -260,16 +269,20 @@ class PackedGraph:
 
     The host part is the :func:`_graph_setup` result (``n_local``,
     ``src_slot`` / ``dst`` / ``w``, ``E_max``), which the analytic twin
-    reads. The device part is the same three arrays placed on a fabric's
-    mesh, once per (fabric, partition spec): every launch hands them to
-    the jitted call as its edge arguments, which are never donated. Every
-    caller shares the host arrays, so they are read-only.
+    reads, and ``owner_bound`` (:func:`_owner_bound`), which caps a flat
+    round's buckets (:func:`_owner_caps`). The device part is the same
+    three arrays placed on a fabric's mesh, once per (fabric, partition
+    spec): every launch hands them to the jitted call as its edge
+    arguments, which are never donated. Every caller shares the host
+    arrays, so they are read-only.
     """
 
     def __init__(self, setup):
         self.n_local, self.src_slot, self.dst, self.w, self.E_max = setup
         for a in (self.src_slot, self.dst, self.w):
             a.setflags(write=False)
+        self.owner_bound = _owner_bound(self.dst,
+                                        len(self.dst) // self.E_max)
         self._placed: Dict[tuple, tuple] = {}
 
     def edges_on(self, fab: Fabric, spec) -> tuple:
@@ -371,6 +384,22 @@ def _graph_caps(queues: QueueConfig, task: str,  # noqa: PLR0917
     return resolve_hier_caps(queues, task, e_local, n_intra, n_pods)
 
 
+def _owner_caps(pg: PackedGraph, caps: Tuple[int, ...],
+                pods: Optional[Tuple[int, int]]) -> Tuple[int, ...]:
+    """A flat round's cap clamped at ``round8(pg.owner_bound)``.
+
+    In a round each active edge sends one task to its destination's owner,
+    so the bucket from device c to owner d is never offered more tasks
+    than c's block has edges to d. A cap at or above that bound admits
+    every task, so the clamp shrinks the allocation (the wire and the
+    receive buffer) and never the admission: drop counts, and the analytic
+    twin, are the same either way. The pod/portal caps pass unchanged.
+    """
+    if pods is not None:
+        return caps
+    return (min(caps[0], round8(pg.owner_bound)),)
+
+
 # ---------------------------------------------------------------------------
 # multi-process I/O adapters (no-ops on every single-process fabric)
 # ---------------------------------------------------------------------------
@@ -412,8 +441,8 @@ def _host_gather(fab: Fabric, x):
 
 _CACHE: Dict[tuple, Callable] = {}
 CACHE_STATS = {"hits": 0, "misses": 0, "kernel_traces": 0,
-               "local_fold_builds": 0, "graph_packs": 0,
-               "graph_pack_hits": 0}
+               "local_fold_builds": 0, "owner_capped_builds": 0,
+               "graph_packs": 0, "graph_pack_hits": 0}
 
 
 def cache_stats() -> Dict[str, int]:
@@ -421,7 +450,9 @@ def cache_stats() -> Dict[str, int]:
     same-shape launch must be a ``hits`` increment with ``kernel_traces``
     unchanged — no re-trace). ``local_fold_builds`` counts the graph
     callables built with the one-device local fold (see
-    :func:`_build_graph_fn`). ``graph_packs`` counts the resident
+    :func:`_build_graph_fn`), ``owner_capped_builds`` those built with a
+    flat cap that the graph's owner bound lowered (see
+    :func:`_owner_caps`). ``graph_packs`` counts the resident
     :class:`PackedGraph` handles built and ``graph_pack_hits`` the
     lookups that found one (see :func:`packed_graph`): N jobs on one
     graph read 1 and N - 1."""
@@ -759,8 +790,10 @@ _LAUNCHES = itertools.count()
 def _resolve_graph(prog: TaskProgram, g, fab: Fabric, opts: LaunchOptions,
                    *, dataset=None):
     """What a graph launch on ``fab`` resolves before it packs any state:
-    ``(packed_graph, pod_axis, caps, pods)``. The one resolution both
-    :func:`_launch_graph` and :func:`wire_plan` read."""
+    ``(packed_graph, pod_axis, caps, pods, owner_capped)``, the last true
+    where the graph's owner bound lowered the flat cap
+    (:func:`_owner_caps`). The one resolution both :func:`_launch_graph`
+    and :func:`wire_plan` read."""
     pod_axis, queues = opts.pod_axis, opts.queues
     n_dev = fab.n_devices
     lc = resolve_launch(opts.config, g if dataset is None else dataset,
@@ -775,7 +808,8 @@ def _resolve_graph(prog: TaskProgram, g, fab: Fabric, opts: LaunchOptions,
         queues = _resolve_queues(prog, None, opts.cap, opts.capacity_factor)
     caps, pods = resolve_caps(fab, queues, prog.task, pg.E_max, opts.axis,
                               pod_axis, clamp=True)
-    return pg, pod_axis, caps, pods
+    capped = _owner_caps(pg, caps, pods)
+    return pg, pod_axis, capped, pods, capped != caps
 
 
 def _folds_local(n_dev: int, pod_axis) -> bool:
@@ -812,7 +846,7 @@ def wire_plan(prog: TaskProgram, data, fabric,
     opts = (options or LaunchOptions()).resolve()
     fab = as_fabric(fabric)
     n_dev = fab.n_devices
-    _, pod_axis, caps, pods = _resolve_graph(prog, data, fab, opts)
+    _, pod_axis, caps, pods, _ = _resolve_graph(prog, data, fab, opts)
     stages = () if _folds_local(n_dev, pod_axis) else route_wire(
         n_dev, caps, pods, signal=opts.round_mode == "pipelined")
     return WirePlan(n_dev=n_dev, caps=tuple(caps),
@@ -847,8 +881,8 @@ def _launch_graph(prog: TaskProgram, g, fab: Fabric,  # noqa: PLR0917
     n_dev = fab.n_devices
     n = g.n
     with TraceAnnotation("dcra.graph.pack", launch=launch):
-        pg, pod_axis, caps, pods = _resolve_graph(prog, g, fab, opts,
-                                                  dataset=dataset)
+        pg, pod_axis, caps, pods, owner_capped = _resolve_graph(
+            prog, g, fab, opts, dataset=dataset)
         n_local, E_max = pg.n_local, pg.E_max
         states0, fills = prog.init(g, params)
         packed = tuple(np.asarray(_owner_pack_np(s, n_dev, f)[0],
@@ -878,11 +912,17 @@ def _launch_graph(prog: TaskProgram, g, fab: Fabric,  # noqa: PLR0917
     with TraceAnnotation("dcra.graph.upload", launch=launch):
         args = [*pg.edges_on(fab, spec),
                 *(_to_global(fab, spec, a) for a in packed)]
-    with TraceAnnotation("dcra.graph.dispatch", launch=launch):
-        fn = _cached(key, lambda: _build_graph_fn(
+
+    def build():
+        if owner_capped:
+            CACHE_STATS["owner_capped_builds"] += 1
+        return _build_graph_fn(
             prog, fab.mesh, axis, pod_axis, pods, n_dev, n_local, n, caps,
             kparams, rounds, len(packed), round_mode=round_mode,
-            donate_states=donate_states))
+            donate_states=donate_states)
+
+    with TraceAnnotation("dcra.graph.dispatch", launch=launch):
+        fn = _cached(key, build)
         out = fn(*args)
     return ProgramLaunch(fab, tuple(out), n, n_dev, len(packed), launch)
 
@@ -1293,7 +1333,8 @@ def program_app_stats(prog: TaskProgram, data, n_dev, *,
     # graph program: mirror the rounds, replay flat rounds through route()
     packed = packed_graph(data, n_dev, undirected=prog.undirected,
                           seed=seed)
-    caps = _graph_caps(queues, prog.task, packed.E_max, n_dev, pods)
+    caps = _owner_caps(packed, _graph_caps(queues, prog.task, packed.E_max,
+                                           n_dev, pods), pods)
     msgs, drops = [], []
     engine = None
     if pods is None:
